@@ -150,8 +150,10 @@ def main() -> None:
         ap.error(f"unknown suite {args.only!r}; "
                  f"available: {', '.join(SUITE_NAMES)}")
 
+    from repro.launch.process import enable_compile_cache
     from repro.runtime import Runtime, RuntimeConfig
 
+    enable_compile_cache()
     runtime = Runtime(RuntimeConfig.from_env())
     failed = run_suites(runtime, only=args.only)
     if failed:

@@ -182,6 +182,8 @@ def _sharded_row(static_out: np.ndarray) -> dict:
                         f" --xla_force_host_platform_device_count="
                         f"{SHARD_DEVICES}").strip()
     src = str(Path(__file__).resolve().parent.parent / "src")
+    # a CPU mesh rehearsal: the child must never reach for the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p)
     proc = subprocess.run(

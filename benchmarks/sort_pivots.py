@@ -26,7 +26,8 @@ BIG_NS = (100_000, 1_000_000)
 _SUBPROC = r"""
 import jax, jax.numpy as jnp, numpy as np, json
 from repro.core.sort import distributed_sort, PIVOT_STRATEGIES
-mesh = jax.make_mesh((8,), ("data",))
+from repro.launch.mesh import make_mesh
+mesh = make_mesh((8,), ("data",))
 out = {}
 for n in %NS%:
     x = jax.random.normal(jax.random.PRNGKey(1), (n,))
@@ -66,6 +67,8 @@ def run(csv=True, runtime=None):
     # parallel imbalance per pivot strategy (subprocess, 8 devices)
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # a CPU mesh rehearsal: the child must never reach for the chip
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = str(Path(__file__).resolve().parent.parent / "src")
     code = _SUBPROC.replace("%NS%", str(list(PAPER_NS)))
     proc = subprocess.run([sys.executable, "-c", code], env=env,

@@ -19,7 +19,8 @@ from repro.core.sort import PIVOT_STRATEGIES, distributed_sort  # noqa: E402
 
 
 def main():
-    mesh = jax.make_mesh((8,), ("data",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((8,), ("data",))
     om = OverheadModel()
     print(f"devices: {len(jax.devices())}; "
           f"v5e sort crossover @8 chips: n >= {om.sort_crossover_n(8)}")

@@ -169,10 +169,13 @@ def _measure_ipc(small_reps: int = 200, large_reps: int = 5,
         return _IPC_PROBE_CACHE
     import multiprocessing as mp
 
+    from repro.launch.process import cpu_only_children
+
     ctx = mp.get_context("spawn")
     parent, child = ctx.Pipe()
     proc = ctx.Process(target=_ipc_echo_child, args=(child,), daemon=True)
-    proc.start()
+    with cpu_only_children():  # the echo child never holds the chip
+        proc.start()
     try:
         def round_trip(payload):
             parent.send(payload)
@@ -203,13 +206,13 @@ def _measure_collective_base(reps: int = 20) -> Optional[float]:
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
 
     n = jax.device_count()
     if n < 2:
         return None
-    mesh = jax.make_mesh((n,), ("cal",))
-    f = jax.jit(shard_map(
+    mesh = make_mesh((n,), ("cal",))
+    f = jax.jit(jax.shard_map(
         lambda x: jax.lax.psum(x, "cal"), mesh=mesh,
         in_specs=P("cal"), out_specs=P(),
     ))
@@ -230,13 +233,13 @@ def _measure_interconnect_bw(nbytes: int = 1 << 22, reps: int = 5,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from repro.launch.mesh import make_mesh
 
     c = jax.device_count()
     if c < 2:
         return None
-    mesh = jax.make_mesh((c,), ("cal",))
-    f = jax.jit(shard_map(
+    mesh = make_mesh((c,), ("cal",))
+    f = jax.jit(jax.shard_map(
         lambda x: jax.lax.psum(x, "cal"), mesh=mesh,
         in_specs=P("cal"), out_specs=P(),
     ))

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.costs import CostBreakdown, CostEngine, Decision, OverheadModel
 from repro.core.costs import resolve_engine
 
@@ -113,14 +112,14 @@ def adaptive_matmul(
 
     if strategy == "shard_m":
         ap, pad = _pad_to(a, 0, chips)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda al, bl: al @ bl, mesh=mesh,
             in_specs=(P(axis, None), P(None, None)), out_specs=P(axis, None),
         )
         out = fn(ap, b)[: m]
     elif strategy == "shard_n":
         bp, pad = _pad_to(b, 1, chips)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda al, bl: al @ bl, mesh=mesh,
             in_specs=(P(None, None), P(None, axis)), out_specs=P(None, axis),
         )
@@ -128,14 +127,14 @@ def adaptive_matmul(
     elif strategy == "shard_k":
         ap, _ = _pad_to(a, 1, chips)
         bp, _ = _pad_to(b, 0, chips)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda al, bl: jax.lax.psum(al @ bl, axis), mesh=mesh,
             in_specs=(P(None, axis), P(axis, None)), out_specs=P(None, None),
         )
         out = fn(ap, bp)
     else:  # shard_mn — needs two axes; fall back to shard_m on one axis
         ap, _ = _pad_to(a, 0, chips)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda al, bl: al @ bl, mesh=mesh,
             in_specs=(P(axis, None), P(None, None)), out_specs=P(axis, None),
         )

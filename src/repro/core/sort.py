@@ -35,7 +35,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core.costs import CostEngine, OverheadModel, resolve_engine
 
 PIVOT_STRATEGIES = ("left", "right", "mean", "random", "sampled")
@@ -151,7 +150,7 @@ def distributed_sort(
         count = jnp.sum(mine < _INF).astype(jnp.int32)  # inputs must be finite
         return mine[None], count[None]
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=P(axis), out_specs=(P(axis, None), P(axis)),
     )
     segments, counts = fn(xp)  # (chips, chips*n_local), (chips,)

@@ -21,7 +21,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
@@ -90,7 +89,7 @@ def gpipe(
 
     spec_params = jax.tree.map(lambda _: P(axis), stage_params)
     other_axes = [a for a in mesh.axis_names if a != axis]
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(spec_params, P(*([None] * x.ndim))),
         out_specs=P(*([None] * x.ndim)),

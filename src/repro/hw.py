@@ -48,6 +48,15 @@ class HardwareSpec:
     lane_dim: int = 128  # VPU lane count
     sublane_dim: int = 8  # f32 sublanes
 
+    @property
+    def vmem_limit_bytes(self) -> int:
+        """Scoped VMEM every Pallas kernel asks the compiler for
+        (``vmem_limit_bytes``) and the bound the tuner holds each
+        candidate's working-set estimate to.  Three quarters of physical
+        VMEM: the rest is the compiler's internal scratch.  Without an
+        explicit limit Mosaic applies a much smaller default scope."""
+        return int(self.vmem_bytes * 0.75)
+
     def to_dict(self) -> dict:
         return dataclasses.asdict(self)
 
@@ -58,6 +67,33 @@ class HardwareSpec:
 
 
 V5E = HardwareSpec()
+
+# Specs of the chips this repo has run on, keyed by ``jax.Device.device_kind``.
+# Source: Google Cloud TPU documentation, "TPU v5e" (197 TFLOP/s bf16, 16 GB
+# HBM at 819 GB/s, 1,600 Gbit/s ICI per chip); "TPU v5 lite" is the kind a
+# v5e reports.  A kind that is not here is an error, not a default.
+DEVICE_SPECS = {"TPU v5 lite": V5E}
+
+
+def spec_for_device_kind(kind: str) -> HardwareSpec:
+    """The row of ``DEVICE_SPECS`` for ``kind``; unknown kinds raise."""
+    try:
+        return DEVICE_SPECS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no HardwareSpec for device kind {kind!r} (known: "
+            f"{sorted(DEVICE_SPECS)}); add its datasheet row to "
+            f"repro.hw.DEVICE_SPECS") from None
+
+
+def running_spec() -> HardwareSpec:
+    """The spec of the attached chip on a TPU backend; on any other backend
+    ``V5E``, the named target the analytic model prices for."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        return V5E
+    return spec_for_device_kind(jax.devices()[0].device_kind)
 
 # Which HardwareSpec fields dominate each CostQuery site's prediction.
 # This is the dispatch table for TARGETED recalibration (DESIGN.md §10):

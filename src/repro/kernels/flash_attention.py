@@ -17,18 +17,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from repro.hw import V5E
 
 NEG_INF = -1e30
 
 
 def flash_working_set_bytes(block_q: int, block_kv: int, hd: int,
                             dtype_bytes: int) -> int:
-    """Per-grid-step VMEM residency: q/k/v/out blocks plus the (m, l, acc)
-    fp32 online-softmax scratch (the tuner's VMEM-filter estimate)."""
-    io = (block_q * hd * 2 + block_kv * hd * 2) * dtype_bytes
+    """Per-grid-step VMEM the compiler allocates: q/k/v/out blocks, each
+    double-buffered by the pipeline, the (m, l, acc) fp32 online-softmax
+    scratch, and the fp32 (bq, bkv) logits and probabilities (the tuner's
+    VMEM-filter estimate, held to ``HardwareSpec.vmem_limit_bytes``)."""
+    io = 2 * (block_q * hd * 2 + block_kv * hd * 2) * dtype_bytes
     scratch = (block_q * 128 * 2 + block_q * hd) * 4
-    scores = block_q * block_kv * 4  # the (bq, bkv) logits intermediate
+    scores = 2 * block_q * block_kv * 4
     return io + scratch + scores
 
 
@@ -126,8 +128,9 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 128), jnp.float32),  # l
             pltpu.VMEM((block_q, hd), jnp.float32),  # acc
         ],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=V5E.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(q, k, v)

@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
-
 from repro.hw import V5E
 
 EPILOGUE_ACTIVATIONS = ("relu", "gelu", "silu", "tanh")
@@ -36,10 +34,13 @@ EPILOGUE_ACTIVATIONS = ("relu", "gelu", "silu", "tanh")
 
 def matmul_working_set_bytes(bm: int, bn: int, bk: int, dtype_bytes: int,
                              out_bytes: Optional[int] = None) -> int:
-    """Per-grid-step VMEM residency: A and B blocks, the fp32 accumulator,
-    and the output block (the tuner's VMEM-filter estimate)."""
-    return ((bm * bk + bk * bn) * dtype_bytes
-            + bm * bn * (4 + (out_bytes or dtype_bytes)))
+    """Per-grid-step VMEM the compiler allocates: the A, B and output
+    blocks, each double-buffered by the pipeline, the fp32 accumulator
+    scratch, and the fp32 product of one K step (the tuner's VMEM-filter
+    estimate, held to ``HardwareSpec.vmem_limit_bytes``)."""
+    out_bytes = out_bytes or dtype_bytes
+    return (2 * (bm * bk + bk * bn) * dtype_bytes
+            + 2 * bm * bn * out_bytes + 2 * bm * bn * 4)
 
 
 def pick_block_shape(m: int, n: int, k: int, dtype_bytes: int = 4,
@@ -49,12 +50,12 @@ def pick_block_shape(m: int, n: int, k: int, dtype_bytes: int = 4,
     This is the analytic heuristic, kept as the autotuner's zero-measurement
     PRIOR (kernels/tuning.py validates it against the divisor/VMEM filters
     and measures alternatives around it)."""
-    budget = vmem_budget or (V5E.vmem_bytes * 0.5)
+    budget = vmem_budget or V5E.vmem_limit_bytes
     bm = min(512, max(128, m))
     bn = min(512, max(128, n))
     bk = min(2048, max(128, k))
     def fits(bm, bn, bk):
-        return (bm * bk + bk * bn) * dtype_bytes + bm * bn * 4 <= budget
+        return matmul_working_set_bytes(bm, bn, bk, dtype_bytes) <= budget
     while not fits(bm, bn, bk) and bk > 128:
         bk //= 2
     while not fits(bm, bn, bk) and (bm > 128 or bn > 128):
@@ -144,8 +145,9 @@ def matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=V5E.vmem_limit_bytes,
         ),
         interpret=interpret,
     )(*args)

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import tuning
-from repro.kernels.bitonic_sort import bitonic_sort_pallas
+from repro.kernels.bitonic_sort import MIN_N, bitonic_sort_pallas
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.matmul import matmul_pallas
 from repro.kernels.wkv import wkv_pallas
@@ -92,20 +92,23 @@ def matmul(a, b, *, block_shape: Optional[Tuple[int, int, int]] = None,
 def _sort_npad(n: int) -> int:
     """Power-of-two padded row length the bitonic kernel executes on — the
     single source the tuner's VMEM filter and the kernel padding share."""
-    return 1 << max((n - 1).bit_length(), 3)
+    return max(1 << (n - 1).bit_length(), MIN_N)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def _sort_impl(x, *, block_rows, interpret):
     rows, n = x.shape
     n_pad = _sort_npad(n)
-    info = (jnp.finfo if jnp.issubdtype(x.dtype, jnp.floating)
-            else jnp.iinfo)(x.dtype)
-    big = jnp.asarray(info.max, x.dtype)
-    xp = (jnp.pad(x, ((0, 0), (0, n_pad - n)), constant_values=big)
-          if n_pad != n else x)
-    return bitonic_sort_pallas(xp, block_rows=block_rows,
-                               interpret=interpret)[:, :n]
+    floating = jnp.issubdtype(x.dtype, jnp.floating)
+    # the kernel sorts 32-bit keys; narrower ones widen exactly and back
+    xw = x.astype(jnp.float32 if floating else jnp.int32) \
+        if x.dtype.itemsize < 4 else x
+    big = jnp.asarray((jnp.finfo if floating else jnp.iinfo)(xw.dtype).max,
+                      xw.dtype)
+    xp = (jnp.pad(xw, ((0, 0), (0, n_pad - n)), constant_values=big)
+          if n_pad != n else xw)
+    out = bitonic_sort_pallas(xp, block_rows=block_rows, interpret=interpret)
+    return out[:, :n].astype(x.dtype)
 
 
 def sort(x, *, block_rows: Optional[int] = None,
@@ -121,8 +124,10 @@ def sort(x, *, block_rows: Optional[int] = None,
         x = x[None]
     rows, n = x.shape
     if block_rows is None:
-        block_rows = tuning.sort_block_rows(rows, _sort_npad(n), x.dtype,
-                                            interpret=interpret, tuner=tuner)
+        block_rows = tuning.sort_block_rows(
+            rows, _sort_npad(n),
+            x.dtype if x.dtype.itemsize >= 4 else jnp.float32,
+            interpret=interpret, tuner=tuner)
     out = _sort_impl(x, block_rows=int(block_rows), interpret=interpret)
     return out[0] if squeeze else out
 

@@ -31,12 +31,13 @@ import jax.numpy as jnp
 from repro.core.costs.autotune import Autotuner, Candidate, TuneResult, TuneSpec
 from repro.hw import V5E, HardwareSpec
 
-_BUDGET_FRACTION = 0.5  # leave headroom for the compiler's own buffers
 _GRID_STEP_S = 5e-8  # per-grid-step sequencing overhead (analytic prior only)
 
 
 def vmem_budget(hw: HardwareSpec = V5E) -> int:
-    return int(hw.vmem_bytes * _BUDGET_FRACTION)
+    """The scoped VMEM limit each kernel passes to the compiler: a
+    candidate whose working-set estimate exceeds it is never admitted."""
+    return hw.vmem_limit_bytes
 
 
 def _resolve(tuner: Optional[Autotuner]) -> Autotuner:

@@ -4,7 +4,8 @@ Builds a request trace (all-at-once, staggered, or Poisson arrivals) with
 ``repro.synthetic_trace``, runs it through the chosen engine(s), and reports
 per-request latency, aggregate throughput, and the ``site=serve`` slice of
 the Runtime's overhead ledger (every admission / prefill-chunk /
-decode-composition decision, predicted vs measured).
+decode-composition decision, predicted vs measured).  The exit status is 1
+when any request ends FAILED; the report is printed first.
 
 Usage:
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --reduced \
@@ -26,16 +27,22 @@ import dataclasses
 import signal
 import sys
 import threading
+from typing import List, Tuple
 
 import jax
 
 from repro.configs import get_config
+from repro.launch.process import enable_compile_cache
 from repro.models import build_model
-from repro.runtime import Runtime, RuntimeConfig, synthetic_trace
+from repro.runtime import Runtime, RuntimeConfig, ServeResult, synthetic_trace
+from repro.serving import Request
+from repro.serving.scheduler import RequestState
 from repro.serving.engine import emitted_count  # noqa: F401  (re-export)
 
 
-def main(argv=None):
+def run(argv=None) -> Tuple[Runtime, List[ServeResult]]:
+    """Parse ``argv``, serve the trace through each chosen engine, print the
+    report, and return the session and one result per engine run."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -69,7 +76,10 @@ def main(argv=None):
                     help="shard-vs-replicate over the mesh model axis: "
                          "'auto' asks the CostEngine (the serve_shard "
                          "decision site), the others force a verdict")
-    ap.add_argument("--eos-id", type=int, default=0)
+    ap.add_argument("--eos-id", type=int, default=0,
+                    help="end-of-sequence token; -1 for none, so every "
+                         "request generates --max-new tokens (idle slots "
+                         "are then fed token 0)")
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--deadline-ms", type=float, default=None,
                     help="per-request total-latency budget from arrival; "
@@ -154,9 +164,9 @@ def main(argv=None):
                      f"{args.prompt_len}), got {args.prefix_len}")
     frontend = None
     if args.workers is not None:
-        if args.engine != "continuous":
-            ap.error("--workers needs --engine continuous (the front end "
-                     "feeds the continuous engine's request lifecycle)")
+        if args.engine == "static":
+            ap.error("--workers needs the continuous engine (the front end "
+                     "feeds its request lifecycle)")
         if args.workers == "auto":
             frontend = "auto"
         else:
@@ -186,6 +196,7 @@ def main(argv=None):
         ap.error(f"--max-len {args.max_len} cannot hold prompt_len "
                  f"{args.prompt_len} + max_new {args.max_new} = {need}")
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -195,7 +206,7 @@ def main(argv=None):
     rt = Runtime(rt_cfg)
     # one model + params shared by both engines (same weights, fair compare)
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = model.init(jax.random.PRNGKey(args.seed))
 
     def trace():
         return synthetic_trace(
@@ -236,6 +247,7 @@ def main(argv=None):
             results.append(rt.serve(
                 cfg, trace(), mode=mode, model=model, params=params,
                 slots=args.slots, max_len=args.max_len, eos_id=args.eos_id,
+                pad_id=0 if args.eos_id < 0 else None,
                 prefill_chunk=args.prefill_chunk, macro_step=args.macro_step,
                 mesh_shape=mesh_shape if mode == "continuous" else None,
                 shard_params=args.serve_shard,
@@ -330,6 +342,24 @@ def main(argv=None):
         facts = ", ".join(f"{s} x{corr.factor(s):.2f}"
                           for s in sorted(corr.sites()))
         print(f"corrections: {facts}")
+    return rt, results
+
+
+def failed_requests(results: List[ServeResult]) -> List[Request]:
+    """Every request that ended FAILED, across the engine runs."""
+    return [r for res in results if res.report is not None
+            for r in res.report.requests if r.state is RequestState.FAILED]
+
+
+def main(argv=None) -> int:
+    """The CLI: the report, then exit status 1 if any request FAILED."""
+    _, results = run(argv)
+    failed = failed_requests(results)
+    if failed:
+        print(f"{len(failed)} request(s) FAILED: "
+              + ", ".join(f"{r.rid} [{r.reason}]" for r in failed),
+              file=sys.stderr)
+        return 1
     return 0
 
 

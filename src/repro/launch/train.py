@@ -24,6 +24,7 @@ import signal
 import sys
 
 from repro.configs import get_config
+from repro.launch.process import enable_compile_cache
 from repro.optim.adamw import AdamWConfig
 from repro.runtime import Runtime, RuntimeConfig
 from repro.training import TrainLoopConfig
@@ -56,6 +57,7 @@ def main(argv=None):
                     help="write the CostEngine ledger JSON here at exit")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -93,6 +95,8 @@ def main(argv=None):
             rt.ledger.to_json(args.ledger_out)
             print(f"wrote ledger to {args.ledger_out}")
     if res.diverged:
+        print(f"diverged: loss {res.final_loss} after {res.steps_run} steps",
+              file=sys.stderr)
         return 1
     if not res.interrupted:
         print(f"done: {res.steps_run} steps in {res.wall_s:.1f}s")

@@ -185,7 +185,6 @@ def moe_ep(
     capacity_factor: float = 2.0,
 ):
     """Expert-parallel MoE over ``mesh``; see module docstring."""
-    from repro.compat import shard_map
 
     b, s, d = x.shape
     n_experts = params["router"].shape[1]
@@ -219,7 +218,7 @@ def moe_ep(
     )
     args = (x, params["router"], params["w_in"],
             params.get("w_gate", params["w_in"]), params["w_out"])
-    y = shard_map(
+    y = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,

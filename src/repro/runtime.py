@@ -44,7 +44,7 @@ from repro.core.costs.autotune import Autotuner
 from repro.core.costs.corrections import CorrectionState
 from repro.core.costs.engine import CostEngine
 from repro.core.costs.ledger import OverheadLedger
-from repro.hw import V5E, HardwareSpec
+from repro.hw import HardwareSpec, running_spec
 
 
 # ---------------------------------------------------------------------------
@@ -64,8 +64,10 @@ class RuntimeConfig:
     ``cache_dir``  — home of the calibration + autotune JSON caches (was
                      ``$REPRO_COST_CACHE``; default ``~/.cache/repro/...``).
     ``hardware``   — base :class:`HardwareSpec` for the analytic model
-                     (default: the TPU-v5e datasheet).  Calibration replaces
-                     measured fields on top of it.
+                     (default: on a TPU, the attached chip's
+                     ``hw.DEVICE_SPECS`` row by ``device_kind``, an unknown
+                     kind raising; elsewhere the TPU-v5e datasheet).
+                     Calibration replaces measured fields on top of it.
     ``mesh_shape`` — mesh topology as ``{axis: size}`` (e.g. ``{"data": 8,
                      "model": 2}``); ``None`` means one data axis over all
                      visible devices.
@@ -222,7 +224,8 @@ class Runtime:
                 drift_window=self.config.drift_window,
                 drift_threshold=self.config.drift_threshold,
                 drift_overrides=self.config.drift_overrides)
-            base = self.config.hardware if self.config.hardware is not None else V5E
+            base = (self.config.hardware if self.config.hardware is not None
+                    else running_spec())
             corrections = (CorrectionState()
                            if self.config.corrections else None)
             if self.config.calibrate:
@@ -270,10 +273,10 @@ class Runtime:
         """The jax Mesh for :meth:`mesh_shape` (built lazily; the axis
         sizes must multiply to the visible device count)."""
         if self._mesh is None:
-            import jax
+            from repro.launch.mesh import make_mesh
 
             shape = self.mesh_shape()
-            self._mesh = jax.make_mesh(tuple(shape.values()), tuple(shape))
+            self._mesh = make_mesh(tuple(shape.values()), tuple(shape))
         return self._mesh
 
     # ------------------------------------------------------------------
@@ -506,6 +509,7 @@ class Runtime:
         mesh = None
         if mesh_shape is not None:
             from repro.distributed.sharding import validate_serve_mesh
+            from repro.launch.mesh import make_mesh
 
             shape = {"data": 1, "model": 1}
             unknown = set(mesh_shape) - set(shape)
@@ -528,8 +532,8 @@ class Runtime:
                     f"{jax.device_count()} (forcing a CPU mesh takes "
                     f"XLA_FLAGS=--xla_force_host_platform_device_count=N "
                     f"before jax initializes)")
-            mesh = jax.make_mesh((shape["data"], shape["model"]),
-                                 ("data", "model"))
+            mesh = make_mesh((shape["data"], shape["model"]),
+                             ("data", "model"))
         if model is None:
             model = build_model(cfg)
         if params is None:

@@ -50,6 +50,7 @@ import queue as _queue
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from repro.launch.process import cpu_only_children
 from repro.serving.faults import guarded_call
 from repro.serving.frontend import topology as topo_mod
 from repro.serving.frontend.stream import StreamBroken, TokenStream
@@ -313,7 +314,8 @@ class ServingFrontend:
             args=(wid, q, out_q, self._worker_cpus[wid],
                   self.max_len),
             daemon=True, name=f"repro-intake-{wid}")
-        p.start()
+        with cpu_only_children():  # workers never hold the chip
+            p.start()
         return q, out_q, p
 
     def _spawn_emit_proc(self) -> Tuple[Any, Any, Any]:
@@ -323,7 +325,8 @@ class ServingFrontend:
             target=_emission_main,
             args=(in_q, out_q, self._worker_cpus[self.config.workers]),
             daemon=True, name="repro-emission")
-        p.start()
+        with cpu_only_children():  # workers never hold the chip
+            p.start()
         return in_q, out_q, p
 
     def _ping_all(self) -> None:

@@ -34,7 +34,8 @@ def test_sample_sort_all_pivots_correct_and_random_worst():
     out = run_distributed("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.sort import distributed_sort, PIVOT_STRATEGIES
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(3), (4096,))
         ref = np.sort(np.asarray(x))
         imb = {}
@@ -56,7 +57,8 @@ def test_sample_sort_nonuniform_input():
     run_distributed("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.sort import distributed_sort
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         # skewed data: exponential + duplicates + non-multiple length
         key = jax.random.PRNGKey(0)
         x = jnp.concatenate([jnp.exp(jax.random.normal(key, (3000,))),
@@ -70,7 +72,8 @@ def test_adaptive_matmul_parallel_strategies_match_serial():
     run_distributed("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.core.dispatch import adaptive_matmul
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         k1, k2 = jax.random.split(jax.random.PRNGKey(0))
         a = jax.random.normal(k1, (104, 72))   # non-multiples: exercises padding
         b = jax.random.normal(k2, (72, 88))
@@ -89,7 +92,8 @@ def test_moe_ep_matches_dense_oracle():
     run_distributed("""
         import jax, jax.numpy as jnp, numpy as np
         from repro.models import ffn as ffn_lib
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         d, f, e, topk = 32, 64, 8, 2
         params = ffn_lib.moe_init(jax.random.PRNGKey(1), d, f, e, "swiglu")
         x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, d))
@@ -118,7 +122,8 @@ def test_pjit_train_loss_matches_single_device():
         batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (B, S), 0, cfg.vocab_size)}
         ref, _ = jax.jit(model.loss)(params, batch)
 
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         ctx = ShardingCtx(mesh=mesh, data_axes=("pod", "data"), moe_capacity_factor=8.0)
         pshard = param_shardings(jax.eval_shape(lambda: params), mesh,
                                  data_axes=("pod", "data"))

@@ -31,7 +31,8 @@ def test_checkpoint_elastic_reshard(tmp_path):
         from repro.distributed.sharding import param_shardings, batch_sharding
         from repro.checkpoint import save
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = get_config("tinyllama-1.1b").reduced()
         model = build_model(cfg)
         loop = TrainLoopConfig()
@@ -57,7 +58,8 @@ def test_checkpoint_elastic_reshard(tmp_path):
         from repro.distributed.sharding import param_shardings
         from repro.checkpoint import restore_resharded
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = get_config("tinyllama-1.1b").reduced()
         model = build_model(cfg)
         loop = TrainLoopConfig()

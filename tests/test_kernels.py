@@ -197,3 +197,36 @@ def test_wkv_kernel_matches_xla_chunked(rng):
     out_x, _ = wkv_chunked(r, k, v, logw, u, None, chunk=16)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_x),
                                atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("rows,n,dtype", [
+    (1, 1 << 14, jnp.float32),  # two 64-row chunks: the across-chunk stage
+    (2, 2048, jnp.float32),  # one chunk spans both rows
+    (3, 1500, jnp.int32),
+    (2, 300, jnp.bfloat16),  # widened to f32 and back
+])
+def test_sort_exchange_layouts_match_ref(rng, rows, n, dtype):
+    """The roll-and-select partner exchange in every form the kernel has:
+    lanes, rows within a chunk, whole chunks, and rows sharing a chunk."""
+    if dtype == jnp.int32:
+        x = jax.random.randint(rng, (rows, n), -1000, 1000, dtype)
+    else:
+        x = jax.random.normal(rng, (rows, n)).astype(dtype)
+    out = ops.sort(x, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref.sort_ref(x)))
+
+
+def test_wkv_kernel_bonus_per_head_and_channel(rng):
+    """u differs across heads and channels, and batch > 1: the (BH, 1, N)
+    bonus layout must pick each folded head's own row."""
+    b, s, h, n = 3, 24, 2, 8
+    ks = jax.random.split(rng, 5)
+    r, k, v = (jax.random.normal(kk, (b, s, h, n)) for kk in ks[:3])
+    logw = -jnp.exp(jax.random.normal(ks[3], (b, s, h, n)))
+    u = jax.random.normal(ks[4], (h, n))
+    out, state = ops.wkv(r, k, v, logw, u, chunk=8, interpret=True)
+    exp_out, exp_state = ref.wkv_ref(r, k, v, logw, u)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(exp_out),
+                               atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(state), np.asarray(exp_state),
+                               atol=1e-4, rtol=1e-4)

@@ -31,7 +31,8 @@ def test_gpipe_matches_sequential():
         import jax, jax.numpy as jnp, numpy as np
         from repro.distributed.pipeline import gpipe
 
-        mesh = jax.make_mesh((4,), ("pod",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("pod",))
         S, M, mb, d = 4, 6, 3, 8
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (S, d, d)) * 0.3
