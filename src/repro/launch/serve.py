@@ -281,6 +281,9 @@ def run(argv=None) -> Tuple[Runtime, List[ServeResult]]:
             print(f"    host syncs {res.report.host_syncs} "
                   f"({res.report.host_syncs_per_token:.3f}/token), "
                   f"device dispatches {res.report.device_dispatches}")
+            print(f"    prefilled {res.report.prefilled_tokens} of "
+                  f"{res.report.prefill_padded_tokens} padded prompt tokens, "
+                  f"decode slot-steps {res.report.decode_slot_steps}")
             if args.paged:
                 print(f"    paged KV: peak live tokens "
                       f"{res.report.live_tokens}, reserved blocks "

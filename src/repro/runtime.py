@@ -382,7 +382,8 @@ class Runtime:
               paged: bool = False, block_size: int = 16,
               kv_blocks: Optional[int] = None, prefix_cache="auto",
               frontend=None, stream="auto", pin: bool = False,
-              stop_event=None, now_fn=time.perf_counter) -> ServeResult:
+              stop_event=None, now_fn=time.perf_counter,
+              tracer=None) -> ServeResult:
         """Run a request ``trace`` (a list of ``repro.Request``).
 
         ``continuous`` is the slot-pooled engine scheduled by this runtime's
@@ -435,6 +436,11 @@ class Runtime:
         token-identical by construction — and cross-checked against the
         emission worker's transcript at drain.
 
+        ``tracer`` (a ``repro.serving.spans.SpanRecorder``, continuous mode
+        only) records host spans: ``serve/setup`` from here to the start of
+        ``engine.run``, with ``serve/setup/engine_init`` and
+        ``serve/setup/warmup`` inside it, then the engine's own spans.
+
         ``static`` is the lockstep baseline: the batch forms at the last
         arrival and every request's latency includes that wait; it requires
         equal-length prompts.  ``params=None`` initializes fresh parameters
@@ -452,6 +458,7 @@ class Runtime:
                                             TokenStream)
         from repro.serving.frontend.workers import _pickled_size
         from repro.serving.scheduler import RequestState
+        from repro.serving.spans import maybe_span
 
         if not trace:
             raise ValueError("serve() needs a non-empty trace of Requests")
@@ -504,8 +511,13 @@ class Runtime:
         if isinstance(frontend, int) and frontend < 1:
             raise ValueError(f"frontend worker count must be >= 1, "
                              f"got {frontend}")
+        if mode == "static" and tracer is not None:
+            raise ValueError(
+                "the span recorder instruments the continuous engine's "
+                "loop; mode='static' has none")
         if paged and block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
+        setup = None if tracer is None else tracer.open("serve/setup")
         mesh = None
         if mesh_shape is not None:
             from repro.distributed.sharding import validate_serve_mesh
@@ -584,15 +596,17 @@ class Runtime:
                 stall_s = (watchdog_ms or 0) / 1e3 * 20 + 1.0
                 injector = FaultInjector((FaultSpec(
                     inject_fault, site=site, after=2, stall_s=stall_s),))
-            engine = ContinuousServeEngine(
-                model, params, n_slots=slots, max_len=max_len, eos_id=eos_id,
-                pad_id=pad_id, cost_engine=self.engine,
-                prefill_chunk=prefill_chunk, macro_step=macro_step,
-                mesh=mesh, shard_params=shard_params,
-                queue_limit=queue_limit, max_retries=max_retries,
-                paged=paged, block_size=block_size, kv_blocks=kv_blocks,
-                prefix_cache=(True if prefix_cache == "auto"
-                              else prefix_cache))
+            with maybe_span(tracer, "serve/setup/engine_init"):
+                engine = ContinuousServeEngine(
+                    model, params, n_slots=slots, max_len=max_len,
+                    eos_id=eos_id, pad_id=pad_id, cost_engine=self.engine,
+                    prefill_chunk=prefill_chunk, macro_step=macro_step,
+                    mesh=mesh, shard_params=shard_params,
+                    queue_limit=queue_limit, max_retries=max_retries,
+                    paged=paged, block_size=block_size, kv_blocks=kv_blocks,
+                    prefix_cache=(True if prefix_cache == "auto"
+                                  else prefix_cache),
+                    tracer=tracer)
             if warmup:
                 # compile prefill (shape keys on the trace-wide max prompt
                 # length every group pads to) AND every macro horizon the
@@ -699,6 +713,8 @@ class Runtime:
                         stream_obj = TokenStream()
                     engine.stream = stream_obj
 
+                if setup is not None:
+                    tracer.end(setup)
                 report = engine.run(run_trace, now_fn=now_fn)
 
                 if stream_obj is not None:
@@ -731,6 +747,8 @@ class Runtime:
                     report.frontend_respawns = fe.respawns
                     report.requests.extend(failed_intake)
             finally:
+                if setup is not None:
+                    tracer.end(setup)  # a no-op once it has ended
                 if fe is not None:
                     fe.close()
                 engine.stream = None  # engine stays reusable stream-free

@@ -18,6 +18,9 @@ scheduler.py — Request lifecycle state machine + ServeScheduler (site=serve
                deadline-aware load shedding, prefix-cache reuse)
 faults.py    — FaultSpec/FaultInjector (raise | nan | stall) + guarded_call
                (watchdog + bounded retry-with-backoff around device steps)
+spans.py     — SpanRecorder: optional in-memory host spans at the engine's
+               layer boundaries (admission and macro-step phases), each
+               also a profiler TraceAnnotation
 frontend/    — multi-process serving front end (DESIGN.md §9): host CPU
                topology discovery + SMT-aware affinity planning, pinned
                intake/emission worker processes over bounded IPC queues

@@ -55,6 +55,7 @@ from repro.serving.scheduler import (
 from repro.serving.frontend.stream import StreamBroken, TokenStream
 from repro.serving.paging import default_kv_blocks
 from repro.serving.slots import SlotPool
+from repro.serving.spans import Span, SpanRecorder, maybe_span
 from repro.training.step import (
     make_batched_prefill,
     make_decode_macro_step,
@@ -70,6 +71,11 @@ from repro.training.step import (
 _COLLECTIVE_RE = re.compile(
     r"(?<!%)\b(?:all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(?:-start)?\(")
+
+
+# the (dispatch, sync) span names of each jitted step
+_ADMIT_STEP_SPANS = ("serve/admit/dispatch", "serve/admit/sync")
+_MACRO_STEP_SPANS = ("serve/macro/dispatch", "serve/macro/sync")
 
 
 def emitted_count(out: np.ndarray, eos_id: int) -> int:
@@ -198,6 +204,12 @@ class ServeReport:
     prefix_hit_tokens: int = 0  # prompt tokens served from the radix cache
     prefilled_tokens: int = 0   # prompt tokens actually prefilled
     cow_count: int = 0          # copy-on-write page duplications
+    # prefill and decode occupancy: what the dispatched programs computed
+    # (every row of the pool, padded to the group's chunk-rounded length;
+    # every slot for each of K steps), against which prefilled_tokens and
+    # generated_tokens are the useful part
+    prefill_padded_tokens: int = 0  # rows x padded length, per prefill
+    decode_slot_steps: int = 0      # n_slots x K, per macro-step
     # streaming / front-end accounting (all zero without a token stream /
     # multi-process front end).  IPC fields are filled by Runtime.serve
     # from the ServingFrontend's counters — the engine never sees a queue.
@@ -290,6 +302,8 @@ class ServeReport:
             "reserved_blocks": self.reserved_blocks,
             "prefix_hit_tokens": self.prefix_hit_tokens,
             "prefilled_tokens": self.prefilled_tokens,
+            "prefill_padded_tokens": self.prefill_padded_tokens,
+            "decode_slot_steps": self.decode_slot_steps,
             "prefix_hit_rate": self.prefix_hit_rate,
             "cow_count": self.cow_count,
             "streamed_tokens": self.streamed_tokens,
@@ -337,7 +351,11 @@ class ContinuousServeEngine:
     the jitted prefill/macro-step programs pin their outputs to the same
     layout so donation stays in-place across shards.  A replicate verdict
     executes exactly the single-device path (the decision is still
-    ledgered and the mesh still reported)."""
+    ledgered and the mesh still reported).
+
+    Passing ``tracer`` (a ``SpanRecorder``) records a host span at each
+    layer boundary of ``run`` and ``warmup`` (``repro.serving.spans``);
+    without one the loop records nothing."""
 
     def __init__(self, model: Model, params, *, n_slots: int = 4,
                  max_len: int = 256, eos_id: int = 0,
@@ -353,8 +371,10 @@ class ContinuousServeEngine:
                  paged: bool = False, block_size: int = 16,
                  kv_blocks: Optional[int] = None,
                  prefix_cache: bool = True,
-                 stream: Optional[TokenStream] = None):
+                 stream: Optional[TokenStream] = None,
+                 tracer: Optional[SpanRecorder] = None):
         self.model = model
+        self.tracer = tracer
         self.params = params
         self.max_len = max_len
         self.eos_id = eos_id
@@ -510,6 +530,8 @@ class ContinuousServeEngine:
         self.prefix_hit_tokens = 0
         self.prefilled_tokens = 0
         self.cow_count = 0
+        self.prefill_padded_tokens = 0
+        self.decode_slot_steps = 0
         self._peak_live_tokens = 0
         self._peak_blocks = 0
 
@@ -573,6 +595,32 @@ class ContinuousServeEngine:
             before_thunk, watchdog_s=self.watchdog_s,
             retries=self.max_retries, backoff_s=self.retry_backoff_s,
             on_retry=on_retry, on_watchdog=on_watchdog)
+
+    def _step(self, site: str, names, call, touched: List[Request],
+              parent: Optional[Span]):
+        """One jitted step ``call() -> (out, new_state)`` and its host sync,
+        through ``_dispatch``.  Returns the host copy of ``out``, the new
+        state, and the seconds from dispatch to the end of the sync: with a
+        recorder, the start of span ``names[0]`` to the end of ``names[1]``
+        (both under ``parent``, also when a guard runs the step on a worker
+        thread), so the ledger and the spans read one clock."""
+        tr = self.tracer
+
+        def thunk(cancel):
+            # the host sync happens INSIDE the guarded call, so the
+            # watchdog covers the device execution, not just the dispatch
+            if tr is None:
+                t0 = time.perf_counter()
+                out, state = call()
+                out = np.asarray(out)
+                return out, state, time.perf_counter() - t0
+            with tr.span(names[0], parent) as d:
+                out, state = call()
+            with tr.span(names[1], parent) as y:
+                out = np.asarray(out)
+            return out, state, y.end - d.start
+
+        return self._dispatch(site, thunk, touched)
 
     def _publish(self, req: Request, tokens, done: bool, t: float) -> None:
         """Publish a request's newly-emitted tokens to the attached stream
@@ -655,7 +703,8 @@ class ContinuousServeEngine:
             kept_prompts.append(p)
         return kept, deferred
 
-    def _admit_group(self, reqs: List[Request], now) -> None:
+    def _admit_group(self, reqs: List[Request], now,
+                     span: Optional[Span] = None) -> None:
         """Admit a group of requests with ONE batched prefill lowered
         directly into their pooled slots (no single-slot state + insert
         copy, one host sync for the whole group).  ``now`` is the run
@@ -674,7 +723,11 @@ class ContinuousServeEngine:
         ONE page) and prefills only the suffix; the full prompt's pages
         are inserted back into the trie after prefill so the next request
         sharing the prefix hits.  A preempted request re-admitted here
-        re-pins its own prompt's pages the same way."""
+        re-pins its own prompt's pages the same way.
+
+        ``span`` is the enclosing ``serve/admit`` span, if a recorder is
+        attached: its phases nest under it and it takes the group's
+        attributes."""
         slots = [self.pool.acquire(r) for r in reqs]
         prompts = [np.concatenate([np.asarray(r.prompt, np.int32),
                                    np.asarray(r.tokens, np.int32)])
@@ -684,83 +737,82 @@ class ContinuousServeEngine:
         prefix_decs = []  # (decision, prompt_len, applied) per request
         any_hit = False
         if self.paged:
-            bs = self.block_size
-            for r, s, p in zip(reqs, slots, prompts):
-                plen = int(p.shape[-1])
-                toks = tuple(int(t) for t in p)
-                match = (self.pool.blocks.lookup(toks)
-                         if self.prefix_cache else None)
-                hit = match.hit_tokens(bs) if match is not None else 0
-                cow = 1 if (match is not None
-                            and match.tail_donor is not None) else 0
-                applied, dec_p = self.scheduler.serve_prefix(
-                    plen, hit_tokens=hit, cow_blocks=cow, block_size=bs,
-                    override=self._prefix_override)
-                if applied > 0:
-                    self.pool.assign_prefix(s, match.block_ids)
-                    if match.tail_donor is not None:
-                        self.pool.cow_block(s, match.tail_donor)
-                        self.cow_count += 1
-                    starts[s] = applied
-                    any_hit = True
-                elif match is not None:
-                    # full-prefill verdict: drop the lookup's pins
-                    self.pool.blocks.release(match.block_ids)
-                    if match.tail_donor is not None:
-                        self.pool.blocks.decref(match.tail_donor)
-                self.pool.ensure_blocks(s, plen)
-                self.prefix_hit_tokens += applied
-                self.prefilled_tokens += plen - applied
-                prefix_decs.append((dec_p, plen, applied))
+            with maybe_span(self.tracer, "serve/admit/prefix"):
+                bs = self.block_size
+                for r, s, p in zip(reqs, slots, prompts):
+                    plen = int(p.shape[-1])
+                    toks = tuple(int(t) for t in p)
+                    match = (self.pool.blocks.lookup(toks)
+                             if self.prefix_cache else None)
+                    hit = match.hit_tokens(bs) if match is not None else 0
+                    cow = 1 if (match is not None
+                                and match.tail_donor is not None) else 0
+                    applied, dec_p = self.scheduler.serve_prefix(
+                        plen, hit_tokens=hit, cow_blocks=cow, block_size=bs,
+                        override=self._prefix_override)
+                    if applied > 0:
+                        self.pool.assign_prefix(s, match.block_ids)
+                        if match.tail_donor is not None:
+                            self.pool.cow_block(s, match.tail_donor)
+                            self.cow_count += 1
+                        starts[s] = applied
+                        any_hit = True
+                    elif match is not None:
+                        # full-prefill verdict: drop the lookup's pins
+                        self.pool.blocks.release(match.block_ids)
+                        if match.tail_donor is not None:
+                            self.pool.blocks.decref(match.tail_donor)
+                    self.pool.ensure_blocks(s, plen)
+                    self.prefix_hit_tokens += applied
+                    self.prefilled_tokens += plen - applied
+                    prefix_decs.append((dec_p, plen, applied))
         else:
             self.prefilled_tokens += sum(int(p.shape[-1]) for p in prompts)
-        # prefix-hit rows prefill SUFFIX tokens only (never empty: the
-        # lookup caps hits at prompt_len - 1 so the first generated token
-        # always comes from a real forward).  A group with any hit pads to
-        # the longest suffix instead of the trace-wide prompt pad — that's
-        # the compute reduction; the extra compiled prefill shapes are
-        # bounded by the chunk grid.
-        suffixes = [p[int(starts[s]):] for s, p in zip(slots, prompts)]
-        lmax = max([int(sfx.shape[-1]) for sfx in suffixes]
-                   + ([] if any_hit else [self._group_pad or 0]))
-        override = None if self.prefill_chunk == "auto" else self.prefill_chunk
-        chunk, dec = self.scheduler.prefill_chunk(
-            lmax, active_decodes=self.pool.active_count - len(reqs),
-            override=override)
-        tokens = np.zeros((self.pool.n_slots, lmax), np.int32)
-        lengths = np.zeros((self.pool.n_slots,), np.int32)
-        t_adm = now()
-        for r, s, sfx in zip(reqs, slots, suffixes):
-            if r.admitted_s is None:
-                r.admitted_s = t_adm
-            r.mark(RequestState.PREFILLING, t_adm)
-            tokens[s, : sfx.shape[-1]] = sfx
-            lengths[s] = sfx.shape[-1]
-        chunks = jnp.asarray(_prefill_chunks(tokens, chunk))
-        lens = jnp.asarray(lengths)
-        if self.paged:
-            starts_in = jnp.asarray(starts)
-            bt_in = self.pool.block_tables()
-            extra = (starts_in, bt_in)
-        else:
-            extra = ()
-        self.collective_ops += self._count_collectives(
-            ("prefill", chunks.shape), self._prefill,
-            self.params, self.pool.state, chunks, lens, *extra)
-
-        def thunk(cancel):
-            first, new_state = self._prefill(
+        with maybe_span(self.tracer, "serve/admit/prepare"):
+            # prefix-hit rows prefill SUFFIX tokens only (never empty: the
+            # lookup caps hits at prompt_len - 1 so the first generated
+            # token always comes from a real forward).  A group with any hit
+            # pads to the longest suffix instead of the trace-wide prompt
+            # pad — that's the compute reduction; the extra compiled prefill
+            # shapes are bounded by the chunk grid.
+            suffixes = [p[int(starts[s]):] for s, p in zip(slots, prompts)]
+            lmax = max([int(sfx.shape[-1]) for sfx in suffixes]
+                       + ([] if any_hit else [self._group_pad or 0]))
+            override = (None if self.prefill_chunk == "auto"
+                        else self.prefill_chunk)
+            chunk, dec = self.scheduler.prefill_chunk(
+                lmax, active_decodes=self.pool.active_count - len(reqs),
+                override=override)
+            tokens = np.zeros((self.pool.n_slots, lmax), np.int32)
+            lengths = np.zeros((self.pool.n_slots,), np.int32)
+            t_adm = now()
+            for r, s, sfx in zip(reqs, slots, suffixes):
+                if r.admitted_s is None:
+                    r.admitted_s = t_adm
+                r.mark(RequestState.PREFILLING, t_adm)
+                tokens[s, : sfx.shape[-1]] = sfx
+                lengths[s] = sfx.shape[-1]
+            chunks = jnp.asarray(_prefill_chunks(tokens, chunk))
+            lens = jnp.asarray(lengths)
+            if self.paged:
+                starts_in = jnp.asarray(starts)
+                bt_in = self.pool.block_tables()
+                extra = (starts_in, bt_in)
+            else:
+                extra = ()
+            self.collective_ops += self._count_collectives(
+                ("prefill", chunks.shape), self._prefill,
                 self.params, self.pool.state, chunks, lens, *extra)
-            # ONE host sync for the whole group; syncing INSIDE the guarded
-            # call means the watchdog covers the device execution, not just
-            # the async dispatch
-            return np.asarray(first), new_state
 
-        t0 = time.perf_counter()
-        first_np, self.pool.state = self._dispatch("prefill", thunk, reqs)
-        dt = time.perf_counter() - t0
+        first_np, self.pool.state, dt = self._step(
+            "prefill", _ADMIT_STEP_SPANS,
+            lambda: self._prefill(self.params, self.pool.state, chunks, lens,
+                                  *extra),
+            reqs, span)
+        padded_len = chunks.shape[0] * chunk
         self.device_dispatches += 1
         self.host_syncs += 1
+        self.prefill_padded_tokens += self.pool.n_slots * padded_len
         self.scheduler.record_measured(
             dec, dt, note=f"prefill group={len(reqs)} len={lmax} chunk={chunk}")
         for dec_p, plen, applied in prefix_decs:
@@ -768,36 +820,184 @@ class ContinuousServeEngine:
                 dec_p, dt,
                 note=f"serve_prefix len={plen} hit={applied} "
                      f"group={len(reqs)}")
-        t_first = now()
-        for r, s, p in zip(reqs, slots, prompts):
-            tk = int(first_np[s])
-            r.tokens.append(tk)
-            if r.first_token_s is None:
-                r.first_token_s = t_first
-            self.pool.set_pos(s, int(p.shape[-1]))
-            if self.prefix_cache:
-                # publish the full prompt's pages into the trie BEFORE any
-                # release: pinned there, they survive slot turnover (dedupe
-                # swaps repoint this slot at already-resident duplicates)
-                swaps = self.pool.blocks.insert(
-                    tuple(int(t) for t in p), self.pool.slot_table(s))
-                self.pool.apply_swaps(s, swaps)
-            if tk == self.eos_id or len(r.tokens) >= r.max_new_tokens:
-                r.mark(RequestState.COMPLETED, t_first)
-                self.pool.release(s)
-                self._last_tok[s] = self.pad_id
-                self._budget[s] = 0
-                self._publish(r, (tk,), done=True, t=t_first)
+        with maybe_span(self.tracer, "serve/admit/finish"):
+            t_first = now()
+            for r, s, p in zip(reqs, slots, prompts):
+                tk = int(first_np[s])
+                r.tokens.append(tk)
+                if r.first_token_s is None:
+                    r.first_token_s = t_first
+                self.pool.set_pos(s, int(p.shape[-1]))
+                if self.prefix_cache:
+                    # publish the full prompt's pages into the trie BEFORE
+                    # any release: pinned there, they survive slot turnover
+                    # (dedupe swaps repoint this slot at already-resident
+                    # duplicates)
+                    swaps = self.pool.blocks.insert(
+                        tuple(int(t) for t in p), self.pool.slot_table(s))
+                    self.pool.apply_swaps(s, swaps)
+                if tk == self.eos_id or len(r.tokens) >= r.max_new_tokens:
+                    r.mark(RequestState.COMPLETED, t_first)
+                    self.pool.release(s)
+                    self._last_tok[s] = self.pad_id
+                    self._budget[s] = 0
+                    self._publish(r, (tk,), done=True, t=t_first)
+                else:
+                    r.mark(RequestState.DECODING, t_first)
+                    self._last_tok[s] = tk
+                    self._budget[s] = r.max_new_tokens - len(r.tokens)
+                    self._publish(r, (tk,), done=False, t=t_first)
+            self._peak_live_tokens = max(self._peak_live_tokens,
+                                         int(self.pool.positions().sum()))
+            if self.paged:
+                self._peak_blocks = max(self._peak_blocks,
+                                        self.pool.blocks.used_blocks)
+        if span is not None:
+            span.attrs.update(
+                rids=[r.rid for r in reqs], rows=self.pool.n_slots,
+                padded_len=padded_len, useful_tokens=int(lengths.sum()),
+                prefix_hit_tokens=int(starts.sum()), chunk=chunk)
+
+    def _macro_step(self, active: Dict[int, Request], now,
+                    any_deadlines: bool,
+                    span: Optional[Span] = None) -> Dict[int, Request]:
+        """One K-token macro-step over the pool: choose the horizon and
+        upload the inputs, dispatch and sync, then parse each live slot's
+        tokens.  Returns the slots still active (none after a failed step).
+        ``span`` is the enclosing ``serve/macro`` span, if a recorder is
+        attached."""
+        with maybe_span(self.tracer, "serve/macro/plan"):
+            batch_size = len(active)
+            remaining = tuple(sorted(int(self._budget[s]) for s in active))
+            override = (None if self.macro_step == "auto"
+                        else self.macro_step)
+            # key on the same budget clipping the CostEngine applies, so
+            # repeat compositions dedupe instead of re-recording as every
+            # budget decrements
+            cap = (max(self.scheduler.macro_candidates) if override is None
+                   else override)
+            key = (batch_size, tuple(min(r, cap) for r in remaining))
+            horizon, dec = self.scheduler.macro_horizon(
+                remaining, override=override,
+                record=key != self._last_macro_key)
+            self._last_macro_key = key
+            mask = self.pool.active_mask()
+            macro_fn = self._macro(horizon)
+            tok_in = jnp.asarray(self._last_tok)
+            mask_in = jnp.asarray(mask)
+            budget_in = jnp.asarray(self._budget)
+            if self.paged:
+                # grow each live slot's table to cover this macro-step's K
+                # cache writes, then upload the tables (fixed shape — no
+                # recompile; async — no host sync; NOT donated)
+                pos = self.pool.positions()
+                for s in active:
+                    self.pool.ensure_blocks(s, int(pos[s]) + horizon)
+                mextra = (self.pool.block_tables(),)
+                self._peak_live_tokens = max(self._peak_live_tokens,
+                                             int(pos.sum()))
+                self._peak_blocks = max(self._peak_blocks,
+                                        self.pool.blocks.used_blocks)
             else:
-                r.mark(RequestState.DECODING, t_first)
-                self._last_tok[s] = tk
-                self._budget[s] = r.max_new_tokens - len(r.tokens)
-                self._publish(r, (tk,), done=False, t=t_first)
-        self._peak_live_tokens = max(self._peak_live_tokens,
-                                     int(self.pool.positions().sum()))
-        if self.paged:
-            self._peak_blocks = max(self._peak_blocks,
-                                    self.pool.blocks.used_blocks)
+                mextra = ()
+            self.collective_ops += self._count_collectives(
+                ("macro", horizon), macro_fn,
+                self.params, self.pool.state, tok_in, mask_in, budget_in,
+                *mextra)
+
+        touched = list(active.values())
+        try:
+            em, self.pool.state, dt_step = self._step(
+                "macro", _MACRO_STEP_SPANS,
+                lambda: macro_fn(self.params, self.pool.state, tok_in,
+                                 mask_in, budget_in, *mextra),
+                touched, span)
+        except StepFailed as e:
+            self._fail_inflight(touched, now(),
+                                reason=f"macro step failed: {e}")
+            return {}
+        self.device_dispatches += 1
+        self.host_syncs += 1
+        self.decode_slot_steps += self.pool.n_slots * horizon
+        self.scheduler.record_measured(
+            dec, dt_step, note=f"macro K={horizon} b={batch_size}")
+        if self._shard_pending:
+            self.scheduler.record_measured(
+                self._shard_decision, dt_step / horizon,
+                note=f"serve_shard tp={self.tp} per-step from macro "
+                     f"K={horizon} b={batch_size}")
+            self._shard_pending = False
+        emitted = 0
+        with maybe_span(self.tracer, "serve/macro/emit"):
+            # injected-NaN fault class: NaN logits argmax to garbage tokens;
+            # the injector corrupts the host copy and the validation below
+            # (piggybacked on the macro-step sync the engine already pays —
+            # zero extra syncs) catches it
+            bad_slots: set = set()
+            if self.injector is not None:
+                em = self.injector.corrupt("macro", em, sorted(active))
+                vocab = self.model.cfg.vocab_size
+                bad = np.argwhere((em < 0) | (em >= vocab))
+                bad_slots = {int(s) for s in bad[:, 0]} & set(active)
+            t_emit = now()
+            for slot in list(active):
+                req = active[slot]
+                if slot in bad_slots:
+                    # poison output fails THIS request; the other slots'
+                    # device state advanced normally
+                    req.mark(RequestState.FAILED, t_emit,
+                             reason="corrupt step output (NaN logits)")
+                    self.pool.release(slot)
+                    self._last_tok[slot] = self.pad_id
+                    self._budget[slot] = 0
+                    self._last_macro_key = None
+                    self._publish(req, (), done=True, t=t_emit)
+                    del active[slot]
+                    continue
+                n_before = len(req.tokens)
+                finished = False
+                for j in range(horizon):
+                    tk = int(em[slot, j])
+                    req.tokens.append(tk)
+                    if (tk == self.eos_id
+                            or len(req.tokens) >= req.max_new_tokens):
+                        finished = True
+                        break
+                n_emitted = len(req.tokens) - n_before
+                emitted += n_emitted
+                self.pool.advance(slot, n_emitted)  # before release zeroes
+                # the macro-step's one host sync already happened —
+                # streaming this burst costs no extra device traffic
+                burst = tuple(req.tokens[n_before:])
+                if finished:
+                    req.mark(RequestState.COMPLETED, t_emit)
+                    self.pool.release(slot)
+                    self._last_tok[slot] = self.pad_id
+                    self._budget[slot] = 0
+                    self._publish(req, burst, done=True, t=t_emit)
+                    del active[slot]
+                elif (any_deadlines and req.deadline_s is not None
+                      and t_emit - req.arrival_s > req.deadline_s):
+                    # deadlines are enforced at macro-step boundaries:
+                    # evict to TIMED_OUT, free the slot immediately
+                    req.mark(RequestState.TIMED_OUT, t_emit,
+                             reason="total-latency deadline exceeded "
+                                    "while decoding")
+                    self.pool.release(slot)
+                    self._last_tok[slot] = self.pad_id
+                    self._budget[slot] = 0
+                    self._last_macro_key = None
+                    self._publish(req, burst, done=True, t=t_emit)
+                    del active[slot]
+                else:
+                    self._last_tok[slot] = int(em[slot, horizon - 1])
+                    self._budget[slot] -= n_emitted
+                    self._publish(req, burst, done=False, t=t_emit)
+        if span is not None:
+            span.attrs.update(
+                rids=[r.rid for r in touched], k=horizon,
+                rows=self.pool.n_slots, live=batch_size, emitted=emitted)
+        return active
 
     # ------------------------------------------------------------------
 
@@ -847,6 +1047,7 @@ class ContinuousServeEngine:
         ret0, wd0 = self.step_retries, self.watchdog_fires
         hit0, pf0, cow0 = (self.prefix_hit_tokens, self.prefilled_tokens,
                            self.cow_count)
+        pad0, slot0 = self.prefill_padded_tokens, self.decode_slot_steps
         ev0 = tok0 = 0
         if self.stream is not None:
             ev0 = self.stream.published_events
@@ -866,23 +1067,27 @@ class ContinuousServeEngine:
             """Move arrived requests into the waiting queue, bouncing off a
             full bounded queue (backpressure -> typed REJECTED) and expiring
             deadlines that lapsed while QUEUED."""
-            while pending and pending[0].arrival_s <= t:
-                r = pending.popleft()
-                if (self.queue_limit is not None
-                        and len(waiting) >= self.queue_limit):
-                    r.mark(RequestState.REJECTED, t, reason="queue_full")
-                    continue
-                waiting.append(r)
-            if any_deadlines:
-                still = []
-                for r in waiting:
-                    if (r.deadline_s is not None
-                            and t - r.arrival_s > r.deadline_s):
-                        r.mark(RequestState.TIMED_OUT, t,
-                               reason="deadline expired while queued")
-                    else:
-                        still.append(r)
-                waiting[:] = still
+            with maybe_span(self.tracer, "serve/intake") as sp:
+                n_pending = len(pending)
+                while pending and pending[0].arrival_s <= t:
+                    r = pending.popleft()
+                    if (self.queue_limit is not None
+                            and len(waiting) >= self.queue_limit):
+                        r.mark(RequestState.REJECTED, t, reason="queue_full")
+                        continue
+                    waiting.append(r)
+                if any_deadlines:
+                    still = []
+                    for r in waiting:
+                        if (r.deadline_s is not None
+                                and t - r.arrival_s > r.deadline_s):
+                            r.mark(RequestState.TIMED_OUT, t,
+                                   reason="deadline expired while queued")
+                        else:
+                            still.append(r)
+                    waiting[:] = still
+                if sp is not None:
+                    sp.attrs["arrived"] = n_pending - len(pending)
 
         try:
             while pending or waiting or active:
@@ -919,50 +1124,53 @@ class ContinuousServeEngine:
                     intake(t)
                     if not waiting:
                         break
-                    n_admit, _ = self.scheduler.admission(
-                        active=self.pool.active_count, waiting=len(waiting),
-                        free_slots=self.pool.free_count)
-                    if n_admit <= 0:
-                        break
-                    # stable sort: priority first, then arrival order — at
-                    # uniform priority this IS the original FIFO order
-                    waiting.sort(key=lambda r: (-r.priority, r.arrival_s))
-                    group: List[Request] = []
-                    want = min(n_admit, self.pool.free_count, len(waiting))
-                    while len(group) < want and waiting:
-                        r = waiting[0]
-                        if (r.deadline_s is not None
-                                or r.ttft_deadline_s is not None):
-                            ok, _ = self.scheduler.serve_admit(
-                                r, now=t,
-                                active=self.pool.active_count + len(group),
-                                n_slots=self.pool.n_slots)
-                            if not ok:
-                                waiting.pop(0)
-                                r.mark(RequestState.REJECTED, t,
-                                       reason="deadline_infeasible")
-                                continue
-                        group.append(waiting.pop(0))
-                    if not group:
-                        continue  # everything at the head was shed
-                    if self.prefix_cache and len(group) > 1:
-                        group, deferred = self._split_group(group)
-                        if deferred:
-                            # back to the queue head: next admission round
-                            # the donor's pages are published and these
-                            # turn into radix hits
-                            waiting[0:0] = deferred
-                    try:
-                        self._admit_group(group, now)
-                    except StepFailed as e:
-                        # prefill died (retries exhausted or abandoned):
-                        # the donated pool state is suspect — fail the
-                        # group AND anything in flight, drain, keep serving
-                        self._fail_inflight(
-                            group + list(active.values()), now(),
-                            reason=f"prefill step failed: {e}")
-                        active = {}
-                        continue
+                    with maybe_span(self.tracer, "serve/admit") as adm:
+                        n_admit, _ = self.scheduler.admission(
+                            active=self.pool.active_count,
+                            waiting=len(waiting),
+                            free_slots=self.pool.free_count)
+                        if n_admit <= 0:
+                            break
+                        # stable sort: priority first, then arrival order —
+                        # at uniform priority this IS the original FIFO order
+                        waiting.sort(key=lambda r: (-r.priority, r.arrival_s))
+                        group: List[Request] = []
+                        want = min(n_admit, self.pool.free_count,
+                                   len(waiting))
+                        while len(group) < want and waiting:
+                            r = waiting[0]
+                            if (r.deadline_s is not None
+                                    or r.ttft_deadline_s is not None):
+                                ok, _ = self.scheduler.serve_admit(
+                                    r, now=t,
+                                    active=self.pool.active_count + len(group),
+                                    n_slots=self.pool.n_slots)
+                                if not ok:
+                                    waiting.pop(0)
+                                    r.mark(RequestState.REJECTED, t,
+                                           reason="deadline_infeasible")
+                                    continue
+                            group.append(waiting.pop(0))
+                        if not group:
+                            continue  # everything at the head was shed
+                        if self.prefix_cache and len(group) > 1:
+                            group, deferred = self._split_group(group)
+                            if deferred:
+                                # back to the queue head: next admission round
+                                # the donor's pages are published and these
+                                # turn into radix hits
+                                waiting[0:0] = deferred
+                        try:
+                            self._admit_group(group, now, adm)
+                        except StepFailed as e:
+                            # prefill died (retries exhausted or abandoned):
+                            # the donated pool state is suspect — fail the
+                            # group AND anything in flight, drain, keep serving
+                            self._fail_inflight(
+                                group + list(active.values()), now(),
+                                reason=f"prefill step failed: {e}")
+                            active = {}
+                            continue
                     active = {s: self.pool.owner(s)
                               for s in self.pool.active_slots()}
 
@@ -1000,151 +1208,24 @@ class ContinuousServeEngine:
                         # distinguishes a real clock from a pinned test
                         # clock, which advances by `offset` instead of
                         # sleeping wall time.
-                        wait = pending[0].arrival_s - now()
-                        if wait > 0:
-                            before = now()
-                            time.sleep(min(wait, 0.001))
-                            if now() <= before:
-                                # pinned test clock: jump straight to the
-                                # next arrival instead of sleeping forever
-                                offset += wait
-                            else:
-                                rest = pending[0].arrival_s - now()
-                                if rest > 0:
-                                    time.sleep(rest)
+                        with maybe_span(self.tracer, "serve/wait_arrival"):
+                            wait = pending[0].arrival_s - now()
+                            if wait > 0:
+                                before = now()
+                                time.sleep(min(wait, 0.001))
+                                if now() <= before:
+                                    # pinned test clock: jump straight to
+                                    # the next arrival instead of sleeping
+                                    offset += wait
+                                else:
+                                    rest = pending[0].arrival_s - now()
+                                    if rest > 0:
+                                        time.sleep(rest)
                     continue
 
                 # --- one K-token macro-step over the pool ---
-                batch_size = len(active)
-                remaining = tuple(sorted(int(self._budget[s]) for s in active))
-                override = None if self.macro_step == "auto" else self.macro_step
-                # key on the same budget clipping the CostEngine applies, so
-                # repeat compositions dedupe instead of re-recording as every
-                # budget decrements
-                cap = max(self.scheduler.macro_candidates) if override is None \
-                    else override
-                key = (batch_size, tuple(min(r, cap) for r in remaining))
-                horizon, dec = self.scheduler.macro_horizon(
-                    remaining, override=override,
-                    record=key != self._last_macro_key)
-                self._last_macro_key = key
-                mask = self.pool.active_mask()
-                macro_fn = self._macro(horizon)
-                tok_in = jnp.asarray(self._last_tok)
-                mask_in = jnp.asarray(mask)
-                budget_in = jnp.asarray(self._budget)
-                if self.paged:
-                    # grow each live slot's table to cover this macro-step's
-                    # K cache writes, then upload the tables (fixed shape —
-                    # no recompile; async — no host sync; NOT donated)
-                    pos = self.pool.positions()
-                    for s in active:
-                        self.pool.ensure_blocks(s, int(pos[s]) + horizon)
-                    mextra = (self.pool.block_tables(),)
-                    self._peak_live_tokens = max(self._peak_live_tokens,
-                                                 int(pos.sum()))
-                    self._peak_blocks = max(self._peak_blocks,
-                                            self.pool.blocks.used_blocks)
-                else:
-                    mextra = ()
-                self.collective_ops += self._count_collectives(
-                    ("macro", horizon), macro_fn,
-                    self.params, self.pool.state, tok_in, mask_in, budget_in,
-                    *mextra)
-
-                def thunk(cancel, _fn=macro_fn, _tok=tok_in, _mask=mask_in,
-                          _budget=budget_in, _extra=mextra):
-                    emitted, new_state = _fn(
-                        self.params, self.pool.state, _tok, _mask, _budget,
-                        *_extra)
-                    # THE host sync for K tokens, inside the guard so the
-                    # watchdog covers device execution, not just dispatch
-                    return np.asarray(emitted), new_state
-
-                t_step = time.perf_counter()
-                try:
-                    em, self.pool.state = self._dispatch(
-                        "macro", thunk, list(active.values()))
-                except StepFailed as e:
-                    self._fail_inflight(list(active.values()), now(),
-                                        reason=f"macro step failed: {e}")
-                    active = {}
-                    continue
-                dt_step = time.perf_counter() - t_step
-                self.device_dispatches += 1
-                self.host_syncs += 1
-                self.scheduler.record_measured(
-                    dec, dt_step, note=f"macro K={horizon} b={batch_size}")
-                if self._shard_pending:
-                    self.scheduler.record_measured(
-                        self._shard_decision, dt_step / horizon,
-                        note=f"serve_shard tp={self.tp} per-step from macro "
-                             f"K={horizon} b={batch_size}")
-                    self._shard_pending = False
-                # injected-NaN fault class: NaN logits argmax to garbage
-                # tokens; the injector corrupts the host copy and the
-                # validation below (piggybacked on the macro-step sync the
-                # engine already pays — zero extra syncs) catches it
-                bad_slots: set = set()
-                if self.injector is not None:
-                    em = self.injector.corrupt("macro", em,
-                                               sorted(active))
-                    vocab = self.model.cfg.vocab_size
-                    bad = np.argwhere((em < 0) | (em >= vocab))
-                    bad_slots = {int(s) for s in bad[:, 0]} & set(active)
-                t_emit = now()
-                for slot in list(active):
-                    req = active[slot]
-                    if slot in bad_slots:
-                        # poison output fails THIS request; the other
-                        # slots' device state advanced normally
-                        req.mark(RequestState.FAILED, t_emit,
-                                 reason="corrupt step output (NaN logits)")
-                        self.pool.release(slot)
-                        self._last_tok[slot] = self.pad_id
-                        self._budget[slot] = 0
-                        self._last_macro_key = None
-                        self._publish(req, (), done=True, t=t_emit)
-                        del active[slot]
-                        continue
-                    n_before = len(req.tokens)
-                    finished = False
-                    for j in range(horizon):
-                        tk = int(em[slot, j])
-                        req.tokens.append(tk)
-                        if (tk == self.eos_id
-                                or len(req.tokens) >= req.max_new_tokens):
-                            finished = True
-                            break
-                    n_emitted = len(req.tokens) - n_before
-                    self.pool.advance(slot, n_emitted)  # before release zeroes
-                    # the macro-step's one host sync already happened —
-                    # streaming this burst costs no extra device traffic
-                    burst = tuple(req.tokens[n_before:])
-                    if finished:
-                        req.mark(RequestState.COMPLETED, t_emit)
-                        self.pool.release(slot)
-                        self._last_tok[slot] = self.pad_id
-                        self._budget[slot] = 0
-                        self._publish(req, burst, done=True, t=t_emit)
-                        del active[slot]
-                    elif (any_deadlines and req.deadline_s is not None
-                          and t_emit - req.arrival_s > req.deadline_s):
-                        # deadlines are enforced at macro-step boundaries:
-                        # evict to TIMED_OUT, free the slot immediately
-                        req.mark(RequestState.TIMED_OUT, t_emit,
-                                 reason="total-latency deadline exceeded "
-                                        "while decoding")
-                        self.pool.release(slot)
-                        self._last_tok[slot] = self.pad_id
-                        self._budget[slot] = 0
-                        self._last_macro_key = None
-                        self._publish(req, burst, done=True, t=t_emit)
-                        del active[slot]
-                    else:
-                        self._last_tok[slot] = int(em[slot, horizon - 1])
-                        self._budget[slot] -= n_emitted
-                        self._publish(req, burst, done=False, t=t_emit)
+                with maybe_span(self.tracer, "serve/macro") as mac:
+                    active = self._macro_step(active, now, any_deadlines, mac)
         except BaseException:
             # abort safety net (fatal faults, KeyboardInterrupt, bugs):
             # leave the ENGINE reusable — in-flight requests FAILED, pool
@@ -1173,6 +1254,8 @@ class ContinuousServeEngine:
             prefix_hit_tokens=self.prefix_hit_tokens - hit0,
             prefilled_tokens=self.prefilled_tokens - pf0,
             cow_count=self.cow_count - cow0,
+            prefill_padded_tokens=self.prefill_padded_tokens - pad0,
+            decode_slot_steps=self.decode_slot_steps - slot0,
             streamed_tokens=(self.stream.published_tokens - tok0
                              if self.stream is not None else 0),
             stream_events=(self.stream.published_events - ev0
@@ -1188,21 +1271,27 @@ class ContinuousServeEngine:
         generates only a couple of tokens: horizon precompilation is the
         idle loop's job, so warmup cost does not scale with
         ``max_new_tokens``."""
-        dummy_new = min(2, max(max_new_tokens, 1))
-        req = Request("_warmup", np.ones((prompt_len,), np.int32), dummy_new)
-        self.run([req])
-        idle_tok = jnp.asarray(np.full((self.pool.n_slots,), self.pad_id,
-                                       np.int32))
-        idle_mask = jnp.zeros((self.pool.n_slots,), bool)
-        idle_budget = jnp.zeros((self.pool.n_slots,), np.int32)
-        horizons = [k for k in self.scheduler.macro_candidates
-                    if k <= max(max_new_tokens - 1, 1)]
-        if self.macro_step != "auto":
-            horizons = [self.macro_step]
-        idle_extra = (self.pool.block_tables(),) if self.paged else ()
-        for k in horizons:
-            emitted, self.pool.state = self._macro(k)(
-                self.params, self.pool.state, idle_tok, idle_mask,
-                idle_budget, *idle_extra)
-            np.asarray(emitted)
+        with maybe_span(self.tracer, "serve/setup/warmup"):
+            dummy_new = min(2, max(max_new_tokens, 1))
+            req = Request("_warmup", np.ones((prompt_len,), np.int32),
+                          dummy_new)
+            self.run([req])
+            idle_tok = jnp.asarray(np.full((self.pool.n_slots,), self.pad_id,
+                                           np.int32))
+            idle_mask = jnp.zeros((self.pool.n_slots,), bool)
+            idle_budget = jnp.zeros((self.pool.n_slots,), np.int32)
+            horizons = [k for k in self.scheduler.macro_candidates
+                        if k <= max(max_new_tokens - 1, 1)]
+            if self.macro_step != "auto":
+                horizons = [self.macro_step]
+            idle_extra = (self.pool.block_tables(),) if self.paged else ()
+            for k in horizons:
+                with maybe_span(self.tracer,
+                                "serve/setup/warmup_macro") as sp:
+                    if sp is not None:
+                        sp.attrs["k"] = k
+                    emitted, self.pool.state = self._macro(k)(
+                        self.params, self.pool.state, idle_tok, idle_mask,
+                        idle_budget, *idle_extra)
+                    np.asarray(emitted)
         self._last_macro_key = None
