@@ -267,6 +267,17 @@ def stack_state_map(cfg: ModelConfig, fn, *states):
     return mapper(0, *states)
 
 
+def pages_only(state) -> bool:
+    """True when the positions ``pos`` are a decode state's only per-slot
+    leaf: every other leaf is a paged page pool (``pk``/``pv``), shared by
+    all slots.  Only then can a program step a subset of the slots without
+    carrying the rest of their state along."""
+    leaves = jax.tree_util.tree_flatten_with_path(
+        {k: v for k, v in state.items() if k != "pos"})[0]
+    return all(getattr(path[-1], "key", None) in ("pk", "pv")
+               for path, _ in leaves)
+
+
 # ---------------------------------------------------------------------------
 # Whole decoder stack
 # ---------------------------------------------------------------------------

@@ -44,6 +44,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core.costs.engine import CostEngine
 from repro.models.model import Model, mrope_positions
+from repro.models.transformer import pages_only
 from repro.serving.faults import FaultInjector, StepFailed, guarded_call
 from repro.serving.scheduler import (
     Request,
@@ -205,11 +206,12 @@ class ServeReport:
     prefilled_tokens: int = 0   # prompt tokens actually prefilled
     cow_count: int = 0          # copy-on-write page duplications
     # prefill and decode occupancy: what the dispatched programs computed
-    # (every row of the pool, padded to the group's chunk-rounded length;
-    # every slot for each of K steps), against which prefilled_tokens and
+    # (every prefill row, padded to the group's chunk-rounded length; every
+    # slot for each of K steps), against which prefilled_tokens and
     # generated_tokens are the useful part
     prefill_padded_tokens: int = 0  # rows x padded length, per prefill
     decode_slot_steps: int = 0      # n_slots x K, per macro-step
+    narrow_admissions: int = 0      # prefills on fewer rows than n_slots
     # streaming / front-end accounting (all zero without a token stream /
     # multi-process front end).  IPC fields are filled by Runtime.serve
     # from the ServingFrontend's counters — the engine never sees a queue.
@@ -304,6 +306,7 @@ class ServeReport:
             "prefilled_tokens": self.prefilled_tokens,
             "prefill_padded_tokens": self.prefill_padded_tokens,
             "decode_slot_steps": self.decode_slot_steps,
+            "narrow_admissions": self.narrow_admissions,
             "prefix_hit_rate": self.prefix_hit_rate,
             "cow_count": self.cow_count,
             "streamed_tokens": self.streamed_tokens,
@@ -513,6 +516,18 @@ class ContinuousServeEngine:
                                     donate_argnums=(1,))
             self._macro_out = None
         self._macro_fns: Dict[int, Callable] = {}
+        # an admission prefills only its group's rows, rounded up to a row
+        # bucket (a power of two, capped at n_slots), when positions are the
+        # only per-slot state: the paged pool's pages are shared, so nothing
+        # else of the idle slots has to ride along.  Window rings, recurrent
+        # states and dense caches are per-slot, so those engines keep
+        # prefilling every slot.
+        self._row_buckets = (
+            sorted({min(1 << i, n_slots)
+                    for i in range(n_slots.bit_length() + 1)})
+            if self.paged and pages_only(self.pool.state) else [n_slots])
+        # (n_chunks, rows, chunk) of every prefill compiled so far
+        self._prefill_shapes: set = set()
         # host mirrors of per-slot last token / remaining token budget
         self._last_tok = np.full((n_slots,), self.pad_id, np.int32)
         self._budget = np.zeros((n_slots,), np.int32)
@@ -532,6 +547,7 @@ class ContinuousServeEngine:
         self.cow_count = 0
         self.prefill_padded_tokens = 0
         self.decode_slot_steps = 0
+        self.narrow_admissions = 0
         self._peak_live_tokens = 0
         self._peak_blocks = 0
 
@@ -725,6 +741,10 @@ class ContinuousServeEngine:
         sharing the prefix hits.  A preempted request re-admitted here
         re-pins its own prompt's pages the same way.
 
+        The prefill runs on the smallest row bucket that holds the group:
+        its slots padded to a power of two where only positions are
+        per-slot (paged, all full attention), every slot otherwise.
+
         ``span`` is the enclosing ``serve/admit`` span, if a recorder is
         attached: its phases nest under it and it takes the group's
         attributes."""
@@ -783,26 +803,32 @@ class ContinuousServeEngine:
             chunk, dec = self.scheduler.prefill_chunk(
                 lmax, active_decodes=self.pool.active_count - len(reqs),
                 override=override)
-            tokens = np.zeros((self.pool.n_slots, lmax), np.int32)
+            n_rows = next(r for r in self._row_buckets if r >= len(reqs))
+            # a prefill over every slot keeps row = slot; a narrower one
+            # takes the group's slots in ascending order, which is how the
+            # program finds them (where ``lengths`` is positive)
+            narrow = n_rows < self.pool.n_slots
+            row_of = ({s: j for j, s in enumerate(sorted(slots))} if narrow
+                      else {s: s for s in slots})
+            tokens = np.zeros((n_rows, lmax), np.int32)
             lengths = np.zeros((self.pool.n_slots,), np.int32)
             t_adm = now()
             for r, s, sfx in zip(reqs, slots, suffixes):
                 if r.admitted_s is None:
                     r.admitted_s = t_adm
                 r.mark(RequestState.PREFILLING, t_adm)
-                tokens[s, : sfx.shape[-1]] = sfx
+                tokens[row_of[s], : sfx.shape[-1]] = sfx
                 lengths[s] = sfx.shape[-1]
             chunks = jnp.asarray(_prefill_chunks(tokens, chunk))
             lens = jnp.asarray(lengths)
             if self.paged:
-                starts_in = jnp.asarray(starts)
-                bt_in = self.pool.block_tables()
-                extra = (starts_in, bt_in)
+                extra = (jnp.asarray(starts), self.pool.block_tables())
             else:
                 extra = ()
             self.collective_ops += self._count_collectives(
                 ("prefill", chunks.shape), self._prefill,
                 self.params, self.pool.state, chunks, lens, *extra)
+            self._prefill_shapes.add(chunks.shape)
 
         first_np, self.pool.state, dt = self._step(
             "prefill", _ADMIT_STEP_SPANS,
@@ -812,7 +838,8 @@ class ContinuousServeEngine:
         padded_len = chunks.shape[0] * chunk
         self.device_dispatches += 1
         self.host_syncs += 1
-        self.prefill_padded_tokens += self.pool.n_slots * padded_len
+        self.prefill_padded_tokens += n_rows * padded_len
+        self.narrow_admissions += narrow
         self.scheduler.record_measured(
             dec, dt, note=f"prefill group={len(reqs)} len={lmax} chunk={chunk}")
         for dec_p, plen, applied in prefix_decs:
@@ -823,7 +850,7 @@ class ContinuousServeEngine:
         with maybe_span(self.tracer, "serve/admit/finish"):
             t_first = now()
             for r, s, p in zip(reqs, slots, prompts):
-                tk = int(first_np[s])
+                tk = int(first_np[row_of[s]])
                 r.tokens.append(tk)
                 if r.first_token_s is None:
                     r.first_token_s = t_first
@@ -854,8 +881,9 @@ class ContinuousServeEngine:
                                         self.pool.blocks.used_blocks)
         if span is not None:
             span.attrs.update(
-                rids=[r.rid for r in reqs], rows=self.pool.n_slots,
-                padded_len=padded_len, useful_tokens=int(lengths.sum()),
+                rids=[r.rid for r in reqs], rows=n_rows,
+                slots=self.pool.n_slots, padded_len=padded_len,
+                useful_tokens=int(lengths.sum()),
                 prefix_hit_tokens=int(starts.sum()), chunk=chunk)
 
     def _macro_step(self, active: Dict[int, Request], now,
@@ -1048,6 +1076,7 @@ class ContinuousServeEngine:
         hit0, pf0, cow0 = (self.prefix_hit_tokens, self.prefilled_tokens,
                            self.cow_count)
         pad0, slot0 = self.prefill_padded_tokens, self.decode_slot_steps
+        narrow0 = self.narrow_admissions
         ev0 = tok0 = 0
         if self.stream is not None:
             ev0 = self.stream.published_events
@@ -1256,6 +1285,7 @@ class ContinuousServeEngine:
             cow_count=self.cow_count - cow0,
             prefill_padded_tokens=self.prefill_padded_tokens - pad0,
             decode_slot_steps=self.decode_slot_steps - slot0,
+            narrow_admissions=self.narrow_admissions - narrow0,
             streamed_tokens=(self.stream.published_tokens - tok0
                              if self.stream is not None else 0),
             stream_events=(self.stream.published_events - ev0
@@ -1265,17 +1295,19 @@ class ContinuousServeEngine:
         """Compile the prefill/decode/reset executables outside any timed
         trace: one SHORT dummy request through the normal machinery (the
         prefill shape keys on ``prompt_len`` — pass the trace's max prompt
-        length), then every macro-step horizon the scheduler could pick
-        for budgets up to ``max_new_tokens`` (idle all-masked calls —
-        pooled state is donated through and comes back frozen).  The dummy
-        generates only a couple of tokens: horizon precompilation is the
-        idle loop's job, so warmup cost does not scale with
-        ``max_new_tokens``."""
+        length), the prefill at that length for every other row bucket
+        (compiled, not run), then every macro-step horizon the scheduler
+        could pick for budgets up to ``max_new_tokens`` (idle all-masked
+        calls — pooled state is donated through and comes back frozen).
+        The dummy generates only a couple of tokens: horizon
+        precompilation is the idle loop's job, so warmup cost does not
+        scale with ``max_new_tokens``."""
         with maybe_span(self.tracer, "serve/setup/warmup"):
             dummy_new = min(2, max(max_new_tokens, 1))
             req = Request("_warmup", np.ones((prompt_len,), np.int32),
                           dummy_new)
             self.run([req])
+            self._compile_row_buckets()
             idle_tok = jnp.asarray(np.full((self.pool.n_slots,), self.pad_id,
                                            np.int32))
             idle_mask = jnp.zeros((self.pool.n_slots,), bool)
@@ -1295,3 +1327,24 @@ class ContinuousServeEngine:
                         idle_budget, *idle_extra)
                     np.asarray(emitted)
         self._last_macro_key = None
+
+    def _compile_row_buckets(self) -> None:
+        """Compile, without running, the prefill of every row bucket at
+        each chunk grid a prefill has used so far; a later call of the
+        same shapes finds the program compiled."""
+        n_slots = self.pool.n_slots
+        lens = jnp.zeros((n_slots,), jnp.int32)
+        extra = (lens, self.pool.block_tables()) if self.paged else ()
+        for n_chunks, _, chunk in sorted(self._prefill_shapes):
+            for rows in self._row_buckets:
+                shape = (n_chunks, rows, chunk)
+                if shape in self._prefill_shapes:
+                    continue
+                with maybe_span(self.tracer,
+                                "serve/setup/warmup_prefill") as sp:
+                    if sp is not None:
+                        sp.attrs["rows"] = rows
+                    self._prefill.lower(
+                        self.params, self.pool.state,
+                        jnp.zeros(shape, jnp.int32), lens, *extra).compile()
+                self._prefill_shapes.add(shape)

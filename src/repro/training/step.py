@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.model import Model, mrope_positions
+from repro.models.transformer import pages_only
 from repro.optim.adamw import AdamWConfig, adamw_init, adamw_update
 from repro.optim.compression import compress_gradients, init_compression
 from repro.optim.schedule import warmup_cosine
@@ -195,12 +196,38 @@ def make_batched_prefill(model: Model, ctx=None) -> Callable:
     the length limit all continue from the reused prefix.  ``block_tables``
     routes paged cache writes; rows not being prefilled get their table
     zeroed so masked writes land in the null block.
+
+    ``chunks`` may hold fewer rows ``R`` than there are slots where every
+    leaf of ``state`` but ``pos`` is a shared page pool (``pages_only``):
+    row ``j`` is then the ``j``-th slot, in ascending order, whose
+    ``lengths`` entry is positive, and pad rows take the index
+    ``n_slots``.  The program gathers those slots' positions, lengths,
+    starts and block tables, runs the same scan on ``R`` rows, scatters
+    the new positions back (pad rows dropped) and returns ``first_tok``
+    as (R,).
     """
     mrope = model.cfg.pos_type == "mrope"
 
     def prefill_fn(params, state, chunks, lengths, starts=None,
                    block_tables=None):
         n_chunks, b, c = chunks.shape
+        n_slots = lengths.shape[0]
+        rows = None
+        if b < n_slots:
+            if not pages_only(state):
+                raise ValueError(
+                    f"a prefill of {b} rows over {n_slots} slots needs a "
+                    "state whose only per-slot leaf is 'pos'")
+            rows = jnp.nonzero(lengths > 0, size=b, fill_value=n_slots)[0]
+
+            def take(v):
+                return None if v is None else v.at[rows].get(
+                    mode="fill", fill_value=0)
+
+            pool_pos = state["pos"]
+            lengths, starts, block_tables = (
+                take(lengths), take(starts), take(block_tables))
+            state = {**state, "pos": take(pool_pos)}
         if starts is not None:
             state = dict(state)
             state["pos"] = jnp.where(
@@ -233,6 +260,9 @@ def make_batched_prefill(model: Model, ctx=None) -> Callable:
         first0 = jnp.zeros((b,), jnp.int32)
         (state, first), _ = jax.lax.scan(
             body, (state, first0), (jnp.arange(n_chunks), chunks))
+        if rows is not None:
+            state = {**state, "pos": pool_pos.at[rows].set(
+                state["pos"], mode="drop")}
         return first, state
 
     return prefill_fn

@@ -13,12 +13,16 @@
 * lifecycle interplay: preemption/deadline eviction releases the victim's
   pages (trie-pinned prefix blocks survive and resume re-pins them), and a
   fatal-abort drain reclaims the WHOLE BlockPool
+* an admission prefills its group's rows (a power of two), not every
+  slot, with the tokens of the every-slot program; warm-up compiles every
+  row bucket; engines with other per-slot state keep every slot
 * forced 8-device mesh: paged + sharded decode stays token-identical
 """
 
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -39,6 +43,7 @@ from repro.serving import (
     ServeScheduler,
     default_kv_blocks,
 )
+from repro.serving.spans import COMPILE_EVENT, SpanRecorder
 
 PROMPT_LEN = 7
 MAX_NEW = 9
@@ -422,6 +427,168 @@ def test_fatal_abort_drains_whole_block_pool():
     rep = _run(engine, prompts)
     assert rep.state_counts() == {"COMPLETED": 2}
     assert _tokens(rep) == clean
+
+
+# ---------------------------------------------------------------------------
+# Admission prefill on the group's rows, not every slot
+# ---------------------------------------------------------------------------
+
+
+def _trace(cfg, shape, seed=11):
+    """Requests from (prompt_len, arrival_s, priority) triples."""
+    rng = np.random.default_rng(seed)
+    return [Request(f"r{i}", rng.integers(1, cfg.vocab_size, (p,)).astype(
+        np.int32), MAX_NEW, arrival_s=a, priority=pr)
+        for i, (p, a, pr) in enumerate(shape)]
+
+
+def _admissions(engine, reqs, now_fn):
+    """The report and each admission's (group size, rows, padded length)."""
+    with SpanRecorder() as rec:
+        engine.tracer = rec
+        rep = engine.run(reqs, now_fn=now_fn)
+    engine.tracer = None
+    return rep, [(len(s.attrs["rids"]), s.attrs["rows"], s.attrs["padded_len"])
+                 for s in rec.spans if s.name == "serve/admit"
+                 and s.attrs.get("rids")]
+
+
+# case -> (n_slots, (prompt_len, arrival_s, priority) per request, engine
+# keywords, group sizes and prefill rows of the admissions)
+NARROW_CASES = {
+    "one": (8, [(7, 0.0, 0)], {}, [(1, 1)]),
+    # 3 rows pad to 4: one row carries the dropped index n_slots
+    "three": (8, [(7, 0.0, 0), (5, 0.0, 0), (6, 0.0, 0)], {}, [(3, 4)]),
+    "all_slots": (8, [(4 + i % 4, 0.0, 0) for i in range(8)], {}, [(8, 8)]),
+    # the later pair pins the first request's published pages
+    "prefix_hit": (8, [(7, 0.0, 0), (7, 1.0, 0), (7, 1.0, 0)],
+                   {"prefix_cache": "force"}, [(1, 1), (2, 2)]),
+    # "high" evicts a low-priority slot; the victim re-admits alone
+    "preempted": (2, [(7, 0.0, 0), (6, 0.0, 0), (5, 0.005, 5)],
+                  {"macro_step": 1}, [(2, 2), (1, 1), (1, 1)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NARROW_CASES))
+def test_narrow_prefill_matches_every_slot_prefill(case):
+    """An admission prefills only its group's rows, padded to a power of
+    two, and serves the same tokens as a prefill over every slot; the
+    padded-token counter and ``narrow_admissions`` follow the rows."""
+    n_slots, shape, kw, want = NARROW_CASES[case]
+    cfg, model, params = _build()
+    runs = {}
+    for name in ("narrow", "wide"):
+        engine = _engine(model, params, n_slots=n_slots, eos_id=-1,
+                         paged=True, block_size=BLOCK, kv_blocks=64, **kw)
+        if name == "wide":  # the program over every slot, steered here
+            engine._row_buckets = [n_slots]
+        reqs = _trace(cfg, shape)
+        if case == "prefix_hit":
+            for r in reqs[1:]:
+                r.prompt[:6] = reqs[0].prompt[:6]
+        # preemption needs arrivals while slots decode: a ticking clock
+        runs[name] = _admissions(engine, reqs, _tick_clock()
+                                 if case == "preempted" else lambda: 0.0)
+    (rep, adm), (wide, wide_adm) = runs["narrow"], runs["wide"]
+    assert rep.state_counts() == {"COMPLETED": len(shape)}
+    assert _tokens(rep) == _tokens(wide)
+    assert [(g, r) for g, r, _ in adm] == want
+    assert [(g, n_slots) for g, _ in want] == [(g, r) for g, r, _ in wide_adm]
+    assert rep.prefill_padded_tokens == sum(r * n for _, r, n in adm)
+    assert wide.prefill_padded_tokens == sum(r * n for _, r, n in wide_adm)
+    assert rep.narrow_admissions == sum(r < n_slots for _, r in want)
+    assert wide.narrow_admissions == 0
+    if case == "prefix_hit":
+        assert rep.prefix_hit_tokens == wide.prefix_hit_tokens > 0
+    if case == "preempted":
+        assert rep.preemptions == wide.preemptions >= 1
+
+
+def test_narrow_prefill_leaves_other_slots_alone():
+    """The narrow program moves only its group's positions (pad rows are
+    dropped) and gives each row the first token the every-slot program
+    gives that slot."""
+    cfg, model, params = _build()
+    engine = _engine(model, params, n_slots=8, paged=True, block_size=BLOCK)
+    bt = np.zeros((8, -(-MAX_LEN // BLOCK)), np.int32)
+    slots = [1, 4, 6]
+    for j, s in enumerate(slots):
+        bt[s, :2] = [1 + 2 * j, 2 + 2 * j]
+    pos0 = np.arange(8, dtype=np.int32) + 3
+    lengths = np.zeros((8,), np.int32)
+    lengths[slots] = [7, 5, 6]
+    starts = np.zeros((8,), np.int32)
+    toks = _prompts(cfg, 3, seed=4)
+    wide_toks = np.zeros((1, 8, PROMPT_LEN), np.int32)
+    wide_toks[0, slots] = toks
+    narrow_toks = np.zeros((1, 4, PROMPT_LEN), np.int32)
+    narrow_toks[0, :3] = toks
+    out = {}
+    for name, chunks in (("narrow", narrow_toks), ("wide", wide_toks)):
+        state = dict(engine.pool.state, pos=jnp.asarray(pos0))
+        first, state = engine._prefill(
+            params, state, jnp.asarray(chunks), jnp.asarray(lengths),
+            jnp.asarray(starts), jnp.asarray(bt))
+        engine.pool.state = state
+        out[name] = (np.asarray(first), np.asarray(state["pos"]))
+    first, pos = out["narrow"]
+    want = pos0.copy()
+    want[slots] = lengths[slots]
+    np.testing.assert_array_equal(pos, want)
+    np.testing.assert_array_equal(out["wide"][1], want)
+    assert first.shape == (4,)
+    np.testing.assert_array_equal(first[:3], out["wide"][0][slots])
+
+
+def test_warmup_compiles_every_row_bucket():
+    """After ``warmup`` at the trace's longest prompt, a run whose groups
+    take every row bucket (4, 2 and 1 of 4 slots) compiles nothing."""
+    cfg, model, params = _build()
+    engine = _engine(model, params, n_slots=4, eos_id=-1, macro_step=2,
+                     paged=True, block_size=BLOCK)
+    shape = ([(PROMPT_LEN, 0.0, 0)] * 4 + [(5, 1.0, 0)] * 2
+             + [(6, 2.0, 0)])
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == COMPILE_EVENT:
+            compiles.append(duration)
+
+    try:
+        jax.clear_caches()  # what earlier tests compiled is not warm-up's
+        engine.warmup(PROMPT_LEN, MAX_NEW)
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        try:
+            rep, adm = _admissions(engine, _trace(cfg, shape), lambda: 0.0)
+        finally:
+            jax.monitoring.unregister_event_duration_listener(on_event)
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+    assert [(g, r) for g, r, _ in adm] == [(4, 4), (2, 2), (1, 1)]
+    assert rep.state_counts() == {"COMPLETED": len(shape)}
+    assert compiles == []
+
+
+@pytest.mark.parametrize("arch,paged", [
+    ("tinyllama-1.1b", False),    # dense per-slot caches
+    ("rwkv6-3b", True),           # recurrent state per slot
+    ("recurrentgemma-2b", True),  # rglru state and local window rings
+])
+def test_engines_outside_the_rule_prefill_every_slot(arch, paged):
+    """Engines with per-slot state besides the positions keep the prefill
+    over every slot.  (Enc-dec engines cannot be paged, so they fall under
+    the dense case.)"""
+    cfg, model, params = _build(arch)
+    kw = {"paged": True, "block_size": BLOCK} if paged else {}
+    engine = _engine(model, params, n_slots=4, eos_id=-1, **kw)
+    rep, adm = _admissions(
+        engine, _trace(cfg, [(7, 0.0, 0), (5, 1.0, 0), (6, 1.0, 0)]),
+        lambda: 0.0)
+    assert rep.state_counts() == {"COMPLETED": 3}
+    assert [(g, r) for g, r, _ in adm] == [(1, 4), (2, 4)]
+    assert rep.narrow_admissions == 0
 
 
 # ---------------------------------------------------------------------------

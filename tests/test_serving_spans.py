@@ -110,8 +110,10 @@ def test_span_tree_is_well_formed(served):
         "serve/intake", "serve/admit", "serve/macro", "serve/wait_arrival"}
 
 
-def test_span_attributes_add_up_to_the_counters(served):
+def test_span_attributes_add_up_to_the_counters(served, request):
     _, rep, spans = served
+    # paged engines prefill each group on its own rows (1 or 2 of 2 slots)
+    narrow = VARIANTS[request.node.callspec.params["served"]].get("paged")
     admits = [s.attrs for s in spans if s.name == "serve/admit"]
     macros = [s.attrs for s in spans if s.name == "serve/macro"]
     assert sum(a["useful_tokens"] for a in admits) == rep.prefilled_tokens
@@ -122,8 +124,13 @@ def test_span_attributes_add_up_to_the_counters(served):
     assert first_tokens == len(TRACE)
     assert first_tokens + sum(m["emitted"] for m in macros) == \
         rep.generated_tokens
-    assert all(a["rows"] == SLOTS and a["padded_len"] % a["chunk"] == 0
+    assert all(a["slots"] == SLOTS and a["padded_len"] % a["chunk"] == 0
                for a in admits)
+    assert [a["rows"] for a in admits] == [
+        len(a["rids"]) if narrow else SLOTS for a in admits]
+    assert rep.narrow_admissions == sum(a["rows"] < SLOTS for a in admits)
+    assert rep.narrow_admissions > 0 if narrow else \
+        rep.narrow_admissions == 0
     assert all(m["live"] == len(m["rids"]) <= m["rows"] for m in macros)
     assert sum(s.attrs["arrived"] for s in spans
                if s.name == "serve/intake") == len(TRACE)

@@ -114,9 +114,11 @@ def test_bitonic_sort_compiles(one_chip, tuner, rows, n):
         jax.ShapeDtypeStruct((rows, n), jnp.float32, sharding=one_chip))
 
 
-@pytest.mark.parametrize("program", ["prefill", "macro_step"])
+@pytest.mark.parametrize("program", ["prefill", "prefill_one_row",
+                                     "macro_step"])
 def test_phi3_serve_program_compiles_and_fits(one_chip, program):
-    """The continuous engine's paged prefill and decode macro-step for
+    """The continuous engine's paged prefill (over every slot, and over the
+    one row of a single admitted request) and decode macro-step for
     phi3-mini-3.8b at its published widths (4 slots of 576 tokens, 16-token
     pages) compile for one chip, and weights, pool and temporaries fit its
     HBM."""
@@ -138,9 +140,10 @@ def test_phi3_serve_program_compiles_and_fits(one_chip, program):
         slots, max_len, per_slot=True, paging=(pages, page))))
     ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
     tables = ints(slots, -(-max_len // page))
-    if program == "prefill":
+    if program.startswith("prefill"):
         fn = make_batched_prefill(model)
-        args = (ints(2, slots, 256), ints(slots), ints(slots), tables)
+        rows = 1 if program == "prefill_one_row" else slots
+        args = (ints(2, rows, 256), ints(slots), ints(slots), tables)
     else:
         fn = make_decode_macro_step(model, 8, eos_id=-1, pad_id=0)
         args = (ints(slots), jax.ShapeDtypeStruct((slots,), bool,
