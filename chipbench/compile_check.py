@@ -7,8 +7,10 @@ and print what ``memory_analysis()`` says they hold on the device.
 
 For each cell: the continuous engine's batched paged prefill at the cell's
 padded prompt shape (every admission pads to the longest prompt of the mix)
-and its decode macro-step at the largest horizon, 32.  Nothing runs; the
-compiler refuses what would not fit or not compile on the chip.
+and its decode macro-step at the largest horizon, 32, with the program's
+configuration and its parameters' shapes taken from the configuration's
+family, as a run takes them.  Nothing runs; the compiler refuses what would
+not fit or not compile on the chip.
 ``--slots`` and ``--max-len`` compile the cell at other slot counts and
 slot lengths, to choose them.
 """
@@ -26,7 +28,7 @@ from pathlib import Path
 sys.path[0] = str(Path(__file__).resolve().parents[1])
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
-from chipbench import spec  # noqa: E402
+from chipbench import spec, weights  # noqa: E402
 
 GIB = 2**30
 
@@ -41,7 +43,9 @@ def programs(cell, slots: int, one_chip, max_len=None):
     from repro.training.step import (make_batched_prefill,
                                      make_decode_macro_step)
 
-    cfg = spec.program_config(cell.config)
+    fam = cell.family
+    s = fam.sizes(cell.config)
+    cfg = fam.program_config(cell.config)
     model = build_model(cfg)
     t = cell.traffic
     max_len = int(max_len or t["max_len"])
@@ -57,7 +61,8 @@ def programs(cell, slots: int, one_chip, max_len=None):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
             a.shape, a.dtype, sharding=one_chip), tree)
 
-    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    params = on_chip(weights.program_params(fam, weights.shapes(fam, s),
+                                            model))
     state = on_chip(jax.eval_shape(lambda: model.init_decode_state(
         slots, max_len, per_slot=True, paging=(blocks, bs))))
     def i32(*shape):

@@ -5,11 +5,14 @@ The window drives the program's normal serve path::
 
     Runtime.serve(mode="continuous", paged=True) -> ServeScheduler
         -> ContinuousServeEngine (jit(prefill_fn), jit(macro_fn))
-        -> the dense transformer in repro.models
+        -> the model in repro.models, built as the configuration's family
+           (families/<name>.py) says
 
 ``Runtime.serve`` compiles and warms up inside the call; the harness's clock
 is first called when ``engine.run`` starts, and that call opens the window:
-``setup_s`` ends there.  Requests are stamped by the engine on the
+``setup_s`` ends there.  A traced run also passes the program's span
+recorder to ``Runtime.serve``; the end-to-end runs (``--trace 0``) do not.
+Requests are stamped by the engine on the
 harness's clock, from their due time, so a stall counts against every
 request behind it.  At the window's end a ``stop_intake`` mix stops intake
 (requests never admitted end REJECTED and are not attempted) and the
@@ -21,7 +24,6 @@ from __future__ import annotations
 
 import dataclasses
 import gc
-import importlib
 import math
 import shutil
 import sys
@@ -32,7 +34,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from chipbench import costs, generator, spec, weights, xtrace
+from chipbench import costs, generator, spanread, spec, weights, xtrace
 
 # the reference is compared over a sample of finished requests holding at
 # least this many served tokens and requests (the longest always among them)
@@ -102,6 +104,9 @@ class Run:
     admissions: List[Admission]
     trace: Optional[xtrace.Summary] = None
     trace_end_s: Optional[float] = None  # window time the trace stopped
+    # the program's spans on the window clock (traced runs of a program
+    # that has a span recorder), as spanread.on_window gives them
+    spans: Optional[list] = None
 
     def due_in_window(self) -> List[Req]:
         return [r for r in self.requests if r.due_s < self.seconds]
@@ -178,30 +183,6 @@ class WindowClock:
             if self.on_close is not None:
                 self.on_close()
         return t
-
-
-def program_params(w: Dict, model) -> Dict:
-    """The drawn weights in the program's parameter layout, checked leaf by
-    leaf against what ``model.init`` would make."""
-    import jax
-
-    p = {
-        "embed": w["embed"], "unembed": w["lm_head"],
-        "final_norm": {"scale": w["final_norm"]},
-        "layers": {
-            "ln1": {"scale": w["attn_norm"]},
-            "ln2": {"scale": w["mlp_norm"]},
-            "attn": {k: w[k] for k in ("wq", "wk", "wv", "wo")},
-            "ffn": {"w_in": w["w_up"], "w_gate": w["w_gate"],
-                    "w_out": w["w_down"]},
-        },
-    }
-    want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
-    got = jax.tree.map(lambda a: (a.shape, a.dtype), p)
-    if jax.tree.map(lambda a: (a.shape, a.dtype), want) != got:
-        raise spec.SpecError("drawn weights do not match the program's "
-                             "parameter layout")
-    return p
 
 
 # --------------------------------------------------------------- the run --
@@ -289,9 +270,8 @@ def compare(cell: spec.Cell, s: Dict, seed: int, picked: List[Req],
     import jax
     import jax.numpy as jnp
 
-    ref = importlib.import_module(
-        f"chipbench.references.{cell.config['reference']}")
-    w = weights.draw(s, seed)
+    ref = cell.reference
+    w = weights.draw(cell.family, s, seed)
     fn = jax.jit(lambda w, t: ref.gaps(w, s, t, control=control))
     max_len = int(cell.traffic["max_len"])
     out = {"max_gap": 0.0, "compared_tokens": 0}
@@ -328,10 +308,11 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     log = log or (lambda msg: print(msg, file=sys.stderr, flush=True))
     device = device_info(require_chip, cell.chips)
     clock = CompileClock()
-    s = spec.sizes(cell.config)
-    cfg = spec.program_config(cell.config)
+    fam = cell.family
+    s = fam.sizes(cell.config)
+    cfg = fam.program_config(cell.config)
     model = build_model(cfg)
-    params = program_params(weights.draw(s, seed), model)
+    params = weights.program_params(fam, weights.draw(fam, s, seed), model)
     t = cell.traffic
     arrivals = generator.make_requests(t, vocab=s["vocab"], seed=seed,
                                        seconds=seconds)
@@ -361,13 +342,15 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
                 pass
 
     wclock = WindowClock(seconds, stop, on_open, on_close)
+    rec = _span_recorder() if trace else None
     rt = Runtime(RuntimeConfig())
     try:
         res = rt.serve(cfg, trace_reqs, mode="continuous", model=model,
                        params=params, slots=int(t["slots"]),
                        max_len=int(t["max_len"]), eos_id=-1, pad_id=0,
                        paged=True, block_size=int(t["block_size"]),
-                       stream=stream, now_fn=wclock, stop_event=stop)
+                       stream=stream, now_fn=wclock, stop_event=stop,
+                       **({} if rec is None else {"tracer": rec}))
         if wclock.closed_at is None:  # everything drained inside the window
             wclock.closed_at = time.perf_counter() - wclock.t0
             on_close()
@@ -383,15 +366,21 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
                   window_compiles=(at_open["close_programs"]
                                    - at_open["programs"]),
                   requests=reqs, bursts=list(stream.bursts), steps=steps,
-                  admissions=admissions)
+                  admissions=admissions,
+                  spans=(None if rec is None
+                         else spanread.on_window(rec.spans, wclock.t0)))
         del res, params, trace_reqs
         gc.collect()
         if trace:
             run.trace_end_s = wclock.closed_at
             t_read = time.perf_counter()
-            run.trace = _summarize_trace(prof_dir, run)
+            trace_lines = _summarize_trace(prof_dir, run)
             log(f"trace read in {time.perf_counter() - t_read:.1f} s")
+            for line in trace_lines:
+                log(line)
     finally:
+        if rec is not None:
+            rec.close()
         if prof_dir is not None:
             shutil.rmtree(prof_dir, ignore_errors=True)
 
@@ -425,7 +414,7 @@ def run_cell(cell: spec.Cell, *, seed: int, seconds: float, trace: bool,
     chosen = cell.per_layer if trace else cell.end_to_end
     metrics = {}
     for m in chosen:
-        v = spec.metric_reader(m.name)(run)
+        v = spec.metric_reader(m.name, cell.bench_dir)(run)
         if v is not None:
             metrics[m.name] = {"value": v, "unit": m.unit}
     device["memory_peak_bytes"] = peak_bytes
@@ -485,17 +474,46 @@ def window_profile(run: Run) -> List[str]:
             f"{pause[1]:.3f} s"]
 
 
-def _summarize_trace(prof_dir: str, run: Run) -> Optional[xtrace.Summary]:
+def _span_recorder():
+    """The program's span recorder, or None for a program that has none."""
+    try:
+        from repro.serving.spans import SpanRecorder
+    except ImportError:
+        return None
+    return SpanRecorder()
+
+
+def _summarize_trace(prof_dir: str, run: Run) -> List[str]:
+    """Reads the window's trace into ``run.trace`` and returns lines for
+    standard error: device-idle time split by the program's host spans, the
+    macro-steps as spans, trace and stream saw them, and how much of the op
+    time carries a scope path."""
     import glob
+
+    from jax.profiler import ProfileData
 
     paths = glob.glob(f"{prof_dir}/**/*.xplane.pb", recursive=True)
     if not paths:
-        return None
-    devices, marks = xtrace.load(paths[0])
+        return []
+    with open(paths[0], "rb") as f:
+        xspace = f.read()
+    pd = ProfileData.from_serialized_xspace(xspace)
+    devices, marks = xtrace.from_profile(pd)
     if not devices:
-        return None
-    return xtrace.summarize(devices, marks, label=lambda a, b: gap_label(
-        run, a, b))
+        return []
+    run.trace = summary = xtrace.summarize(
+        devices, marks, label=lambda a, b: gap_label(run, a, b),
+        scopes=xtrace.op_scopes(xspace))
+    host = spanread.host_spans(pd)
+    lines = spanread.lines(run, spanread.idle_by_span(devices, marks, host),
+                           host)
+    op_s = sum(summary.op_s.values())
+    if op_s:
+        lines.append(
+            f"op time with a scope path: "
+            f"{100 * sum(summary.scope_s.values()) / op_s:.2f}% of "
+            f"{op_s:.3f} s, in {len(summary.scope_s)} paths")
+    return lines
 
 
 def gap_label(run: Run, a: float, b: float) -> str:

@@ -1,22 +1,28 @@
 """What a cell is, read from ``BENCHMARK.json`` and the files it names.
 
-Everything that belongs to one configuration, one traffic mix or one metric
-lives in a file of its own, found by name:
+Everything that belongs to one configuration, one model family, one
+traffic mix or one metric lives in a file of its own, found by name:
 
 * ``chipbench/configs/<config>.json``: the model's sizes under their
   published keys (``file`` in ``BENCHMARK.json``);
+* ``chipbench/families/<family>.py`` and ``chipbench/references/<family>.py``,
+  ``<family>`` being the configuration's ``reference`` key: what the
+  benchmark knows of the family's shape (sizes, the program's
+  configuration and parameter layout, weights, work counts), and its plain
+  reference;
 * ``chipbench/traffic/<traffic>.json``: the mix the generator reads;
 * ``chipbench/metrics/<metric>.py``: the reader of one metric, or of every
   metric whose name starts with ``<metric>.``;
 * ``chipbench/limits/<cell>.json``: the limits of the numbers that decide
   ``correct`` in that cell, with the readings they were set from.
 
-A later cell adds files and entries; no code changes.
+A later cell or family adds files and entries; no code changes.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
@@ -24,25 +30,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parent
-
-# published config key -> field of the program's ModelConfig
-PROGRAM_FIELDS = {
-    "num_hidden_layers": "n_layers",
-    "hidden_size": "d_model",
-    "num_attention_heads": "n_heads",
-    "num_key_value_heads": "n_kv_heads",
-    "head_dim": "head_dim",
-    "intermediate_size": "d_ff",
-    "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta",
-    "rms_norm_eps": "norm_eps",
-    "tie_word_embeddings": "tie_embeddings",
-    "max_position_embeddings": "max_seq_len",
-    "dtype": "dtype",
-}
-# the published activation of the gated MLP -> the program's name for it
-PROGRAM_ACTIVATION = {"silu": "swiglu"}
-
 
 @dataclasses.dataclass(frozen=True)
 class Metric:
@@ -61,6 +48,9 @@ class Cell:
     end_to_end: Tuple[Metric, ...]
     per_layer: Tuple[Metric, ...]
     limits: Dict[str, Dict[str, Any]]
+    bench_dir: Path = BENCH_DIR
+    family: Any = None  # the module families/<reference>.py
+    reference: Any = None  # the module references/<reference>.py
 
 
 class SpecError(ValueError):
@@ -86,7 +76,8 @@ def load_cell(name: str, root: Path = ROOT, *,
               bench_dir: Path = BENCH_DIR) -> Cell:
     """The cell ``name`` of ``root/BENCHMARK.json`` (or of ``bench``) with
     its configuration, traffic and limits read from their files (traffic
-    and limits under ``bench_dir``)."""
+    and limits under ``bench_dir``), and its family and reference modules
+    (``find_module``)."""
     if bench is None:
         bench = _read_json(root / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
@@ -109,53 +100,49 @@ def load_cell(name: str, root: Path = ROOT, *,
             else m.moves in reported))
     return Cell(name=name, config=config, traffic=traffic,
                 chips=int(w["chips"]), end_to_end=mine, per_layer=per_layer,
-                limits=limits)
+                limits=limits, bench_dir=bench_dir,
+                family=find_module("families", config["reference"],
+                                   bench_dir),
+                reference=find_module("references", config["reference"],
+                                      bench_dir))
 
 
-def metric_reader(name: str) -> Callable[[Any], Optional[float]]:
+def _find(kind: str, stem: str, bench_dir: Path) -> Optional[Path]:
+    """``<kind>/<stem>.py`` in ``chipbench/``, else in ``bench_dir``."""
+    for d in (BENCH_DIR, Path(bench_dir)):
+        if (d / kind / f"{stem}.py").is_file():
+            return d / kind / f"{stem}.py"
+    return None
+
+
+@functools.lru_cache(maxsize=None)
+def _load(path: Path):
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_{path.parent.name}_{path.stem.replace('.', '_')}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def find_module(kind: str, stem: str, bench_dir: Path = BENCH_DIR):
+    """The module ``<kind>/<stem>.py`` (``families``, ``references`` or
+    ``metrics``), from ``chipbench/`` or else from ``bench_dir``; each file
+    is loaded once."""
+    path = _find(kind, stem, bench_dir)
+    if path is None:
+        raise SpecError(f"no {kind}/{stem}.py under {BENCH_DIR} or "
+                        f"{bench_dir}")
+    return _load(path)
+
+
+def metric_reader(name: str, bench_dir: Path = BENCH_DIR
+                  ) -> Callable[[Any], Optional[float]]:
     """``read(run) -> value or None`` from ``metrics/<name>.py``, else from
     ``metrics/<base>.py`` where ``base`` is ``name`` up to its first dot
     (one reader serves ``prefill_ms.ttft`` and ``prefill_ms.tput``)."""
     for stem in (name, name.split(".", 1)[0]):
-        path = BENCH_DIR / "metrics" / f"{stem}.py"
-        if path.is_file():
-            spec = importlib.util.spec_from_file_location(
-                f"chipbench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
-    raise SpecError(f"no reader for metric {name!r} under "
-                    f"{BENCH_DIR / 'metrics'}")
-
-
-def program_config(config: Dict[str, Any]):
-    """The program's ModelConfig for a configuration file: the registered
-    architecture ``program_arch`` with every published size of the file
-    written over it, so what runs is what the file states."""
-    from repro.configs import get_config
-
-    base = get_config(config["program_arch"])
-    fields = {PROGRAM_FIELDS[k]: config[k] for k in PROGRAM_FIELDS
-              if k in config}
-    act = config.get("hidden_act")
-    if act is not None:
-        if act not in PROGRAM_ACTIVATION:
-            raise SpecError(f"no program activation for {act!r}")
-        fields["activation"] = PROGRAM_ACTIVATION[act]
-    return dataclasses.replace(base, **fields)
-
-
-def sizes(config: Dict[str, Any]) -> Dict[str, Any]:
-    """The plain sizes the reference, the weights and the cost functions
-    read, with the head size resolved the published way."""
-    d, h = config["hidden_size"], config["num_attention_heads"]
-    return {
-        "layers": config["num_hidden_layers"], "d": d, "heads": h,
-        "kv_heads": config["num_key_value_heads"],
-        "head_dim": config.get("head_dim") or d // h,
-        "ff": config["intermediate_size"], "vocab": config["vocab_size"],
-        "rope_theta": float(config["rope_theta"]),
-        "eps": float(config["rms_norm_eps"]),
-        "tied": bool(config.get("tie_word_embeddings", False)),
-        "dtype": config.get("dtype", "bfloat16"),
-    }
+        path = _find("metrics", stem, bench_dir)
+        if path is not None:
+            return _load(path).read
+    raise SpecError(f"no reader for metric {name!r} under {BENCH_DIR} or "
+                    f"{bench_dir}")

@@ -28,6 +28,7 @@ def test_sound_run_is_correct(tiny, name):
     assert out["readings"]["compared_tokens"] >= 50
     assert "max_gap_fp8" not in out["readings"]
     assert set(out["metrics"]) == {m.name for m in out["run"].cell.end_to_end}
+    assert out["run"].spans is None  # the recorder is on in traced runs only
 
 
 @pytest.mark.parametrize("name", ["tiny-batch", "tiny-chat"])

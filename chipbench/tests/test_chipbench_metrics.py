@@ -11,6 +11,7 @@ SIZES = {"layers": 2, "d": 64, "heads": 4, "kv_heads": 2, "head_dim": 16,
          "ff": 128, "vocab": 256, "rope_theta": 1e4, "eps": 1e-5,
          "tied": False, "dtype": "bfloat16"}
 PEAK = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
+FAMILY = spec.find_module("families", "dense_decoder")
 
 
 def req(rid, due, adm, first, fin, n, state="COMPLETED"):
@@ -19,7 +20,8 @@ def req(rid, due, adm, first, fin, n, state="COMPLETED"):
 
 
 def make_run(trace=None):
-    cell = spec.Cell("c", {}, {"window_end": "drain"}, 1, (), (), {})
+    cell = spec.Cell("c", {}, {"window_end": "drain"}, 1, (), (), {},
+                     family=FAMILY)
     reqs = [req("a", 0.0, 0.1, 0.5, 2.5, 5), req("b", 1.0, 1.5, 2.0, 4.0, 9),
             req("c", 3.0, None, None, None, 0, state="FAILED")]
     steps = [harness.Step(1.0, 4, [(10, 4), (11, 2)]),
@@ -64,14 +66,15 @@ def test_trace_readers():
     run = make_run(tr)
     assert read("decode_step_ms.tput", run) == pytest.approx(60.0 / 6)
     assert read("device_idle_share.tput", run) == pytest.approx(20.0)
-    least = sum(costs.decode_step_least_s(SIZES, s.contexts(j), PEAK)
+    least = sum(costs.decode_step_least_s(FAMILY, SIZES, s.contexts(j),
+                                          PEAK)
                 for s in run.steps for j in range(s.k))
     assert read("decode_roofline.tput", run) == pytest.approx(
         100 * least / 0.06)
     # 4 + 2 + 2 tokens decoded, each at its own context
     ctx = [11, 12, 13, 14, 12, 13, 15, 16]
     assert read("decode_mfu.ttft", run) == pytest.approx(
-        100 * sum(costs.token_flops(SIZES, c) for c in ctx) / (0.06 * 197e12))
+        100 * sum(FAMILY.token_flops(SIZES, c) for c in ctx) / (0.06 * 197e12))
 
 
 def test_step_contexts():

@@ -144,32 +144,25 @@ def test_innermost_names_the_deepest_open_span():
 
 
 @pytest.mark.parametrize("name", ["tiny-batch", "tiny-chat"])
-def test_traced_tiny_run_reports_every_span_metric(tiny, name, monkeypatch):
-    """A traced run with the program's recorder passed to ``Runtime.serve``
-    (what ``harness.run_cell`` would do) reads every span metric."""
-    from repro.runtime import Runtime
-    from repro.serving.spans import SpanRecorder
-
+def test_traced_tiny_run_reports_every_span_metric(tiny, name):
+    """A traced run passes the program's recorder to ``Runtime.serve``: the
+    run has its spans on the window clock, every span reader returns a
+    number, and the cell's span metrics are in the result."""
     bench, d = tiny
     cell = spec.load_cell(name, d, bench=bench, bench_dir=d)
-    serve, seen = Runtime.serve, {}
-
-    def with_recorder(self, *a, **kw):
-        seen["clock"] = kw["now_fn"]
-        return serve(self, *a, tracer=rec, **kw)
-
-    monkeypatch.setattr(Runtime, "serve", with_recorder)
-    with SpanRecorder() as rec:
-        out = harness.run_cell(cell, seed=2**31 + 7, seconds=2.0,
-                               trace=True, t_start=time.perf_counter(),
-                               require_chip=False, log=lambda msg: None)
+    out = harness.run_cell(cell, seed=2**31 + 7, seconds=2.0, trace=True,
+                           t_start=time.perf_counter(), require_chip=False,
+                           log=lambda msg: None)
     assert out["correct"], out["checks"]
     run = out["run"]
-    run.spans = spanread.on_window(rec.spans, seen["clock"].t0)
+    assert run.spans and any(s.start >= 0 for s in run.spans)
     values = {m: read(m, run) for m in SPAN_METRICS}
     assert all(v is not None for v in values.values()), values
     assert 0 < values["prefill_useful_share.ttft"] <= 100
     assert 0 < values["decode_slot_share.tput"] <= 100
     assert 0 < values["setup_serve_s"] < run.setup_s
+    mine = {m.name for m in cell.per_layer}
+    spans = mine & set(SPAN_METRICS)
+    assert spans and spans <= set(out["metrics"])
     (line,) = spanread.lines(run, None)
     assert line.startswith("serve/macro spans in the traced window")

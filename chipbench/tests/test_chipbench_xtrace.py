@@ -139,3 +139,86 @@ def test_loops_are_left_out_of_the_top_ops():
     assert dict(s.top_ops) == pytest.approx(
         {"macro/fusion.1": 400e-9, "macro/fusion.2": 600e-9})
     assert s.busy_s == pytest.approx(1000e-9)
+
+
+def _varint(n):
+    out = b""
+    while True:
+        out += bytes([(n & 0x7F) | (0x80 if n > 0x7F else 0)])
+        n >>= 7
+        if not n:
+            return out
+
+
+def _pb(field, payload):
+    """A length-delimited protobuf field."""
+    return _varint(field << 3 | 2) + _varint(len(payload)) + payload
+
+
+def hlo_proto(ops):
+    """An HloProto whose one computation holds instructions ``ops`` = {name:
+    op_name or None}."""
+    ins = b"".join(
+        _pb(2, _pb(1, n.encode()) + (_pb(7, _pb(1, b"dot") + _pb(2, s.encode()))
+                                     if s else b""))
+        for n, s in ops.items())
+    return _pb(1, _pb(1, b"module") + _pb(3, _pb(1, b"main") + ins))
+
+
+def metadata_plane(programs):
+    """The host metadata plane: one event metadata per program, named as its
+    ``XLA Modules`` events, holding its HLO proto."""
+    meta = ""
+    for i, (name, ops) in enumerate(programs.items(), start=1):
+        data = "".join(f"\\{b:03o}" for b in hlo_proto(ops))
+        meta += (f'  event_metadata {{ key: {i} value {{ id: {i} '
+                 f'name: "{name}" stats {{ metadata_id: 900 bytes_value: '
+                 f'"{data}" }} }} }}\n')
+    return (f'planes {{ id: 97 name: "{xtrace.METADATA_PLANE}"\n{meta}'
+            f'  stat_metadata {{ key: 900 value {{ id: 900 '
+            f'name: "{xtrace.HLO_PROTO}" }} }}\n}}\n')
+
+
+PROGRAMS = {
+    "jit_prefill_fn(1)": {"fusion.1": "jit(prefill_fn)/attn/dot_general",
+                          "fusion.2": None},
+    "jit_macro_fn(2)": {"fusion.1": "jit(macro_fn)/while/body/attn/dot_general",
+                        "convolution.3": "jit(macro_fn)/while/body/mlp/dot_general",
+                        "copy.9": "jit(macro_fn)/copy"},
+}
+
+
+def test_op_and_scope_seconds():
+    """Each op's device seconds by name and by the scope path of its HLO
+    instruction in its own program; together they are the op time that
+    ``top_ops`` is cut from."""
+    from jax.profiler import ProfileData
+
+    text = xspace(EVENTS, [(xtrace.OPEN, 0), (xtrace.CLOSE, 1000)]) + \
+        metadata_plane(PROGRAMS)
+    scopes = xtrace.op_scopes(ProfileData.text_proto_to_serialized_xspace(text))
+    assert scopes == {p: {n: s for n, s in ops.items() if s}
+                      for p, ops in PROGRAMS.items()}
+    devices, marks = load(text)
+    s = xtrace.summarize(devices, marks, scopes=scopes)
+    assert s.op_s == pytest.approx({
+        "prefill/fusion.1": 200e-9, "prefill/fusion.2": 100e-9,
+        "macro/fusion.1": 150e-9, "macro/convolution.3": 250e-9})
+    # the same instruction name in two programs takes each program's path
+    assert s.scope_s == pytest.approx({
+        "jit(prefill_fn)/attn/dot_general": 200e-9,
+        "jit(macro_fn)/while/body/attn/dot_general": 150e-9,
+        "jit(macro_fn)/while/body/mlp/dot_general": 250e-9})
+    # no op overlaps another: op time is busy time; fusion.2 has no path
+    assert sum(s.op_s.values()) == pytest.approx(s.busy_s)
+    assert sum(s.scope_s.values()) == pytest.approx(
+        sum(s.op_s.values()) - s.op_s["prefill/fusion.2"])
+    assert s.under("attn") == pytest.approx(350e-9)
+    assert s.under("mlp") == pytest.approx(250e-9)
+    assert s.under("att") == 0
+    assert dict(s.top_ops) == s.op_s
+    top2 = xtrace.summarize(devices, marks, scopes=scopes, top=2)
+    assert top2.top_ops == sorted(s.op_s.items(), key=lambda kv: -kv[1])[:2]
+    # without scopes, op time stays and no path is read
+    bare = xtrace.summarize(devices, marks)
+    assert bare.op_s == s.op_s and bare.scope_s == {}

@@ -2,10 +2,13 @@
 jitted call, in the type they are served in.
 
 The arrays are the benchmark's input, like the prompts: the harness hands
-them to the program in its layout (``harness.program_params``), and the
-plain reference draws them again from the same seed once the program is
-gone.  Matrices are bfloat16 at fan-in scale (embedding and output head at
-0.02); each RMSNorm gain is ``1 + g`` with ``g`` a float32 offset.
+them to the program in its layout (``program_params``), and the plain
+reference draws them again from the same seed once the program is gone.
+The configuration's family names the leaves and their shapes
+(``leaf_shapes``); the rule that draws them is the same for every family:
+leaf ``i`` of the sorted names from ``fold_in(key, i)``, matrices in the
+served type at fan-in scale (embedding and output head at 0.02), each
+RMSNorm gain ``1 + g`` with ``g`` a float32 offset.
 """
 
 from __future__ import annotations
@@ -16,26 +19,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-
-def leaf_shapes(s: Dict) -> Dict[str, tuple]:
-    """name -> (shape, scale): ``scale`` is the fan-in of a matrix, None for
-    the 0.02 tables, ``"norm"`` for a gain offset."""
-    L, d, h, kv, hd, f, v = (s["layers"], s["d"], s["heads"], s["kv_heads"],
-                             s["head_dim"], s["ff"], s["vocab"])
-    return {
-        "embed": ((v, d), None),
-        "lm_head": ((v, d), None),
-        "final_norm": ((d,), "norm"),
-        "attn_norm": ((L, d), "norm"),
-        "wq": ((L, d, h, hd), d),
-        "wk": ((L, d, kv, hd), d),
-        "wv": ((L, d, kv, hd), d),
-        "wo": ((L, h * hd, d), h * hd),
-        "mlp_norm": ((L, d), "norm"),
-        "w_gate": ((L, d, f), d),
-        "w_up": ((L, d, f), d),
-        "w_down": ((L, f, d), f),
-    }
+from chipbench import spec
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -45,10 +29,9 @@ def seed_key(seed: int) -> jax.Array:
                               seed >> 32)
 
 
-def _draw(s: Dict, key) -> Dict[str, jax.Array]:
-    dtype = jnp.dtype(s["dtype"])
+def _draw(leaves: Dict[str, tuple], dtype, key) -> Dict[str, jax.Array]:
     out = {}
-    for i, (name, (shape, fan)) in enumerate(sorted(leaf_shapes(s).items())):
+    for i, (name, (shape, fan)) in enumerate(sorted(leaves.items())):
         k = jax.random.fold_in(key, i)
         z = jax.random.normal(k, shape, jnp.float32)
         if fan == "norm":
@@ -60,10 +43,32 @@ def _draw(s: Dict, key) -> Dict[str, jax.Array]:
 
 
 @functools.lru_cache(maxsize=None)
-def _drawer(frozen_sizes):
-    return jax.jit(functools.partial(_draw, dict(frozen_sizes)))
+def _drawer(family, frozen_sizes):
+    s = dict(frozen_sizes)
+    return jax.jit(functools.partial(_draw, family.leaf_shapes(s),
+                                     jnp.dtype(s["dtype"])))
 
 
-def draw(s: Dict, seed: int) -> Dict[str, jax.Array]:
-    """Every weight of sizes ``s`` for ``seed``, in one device program."""
-    return _drawer(tuple(sorted(s.items())))(seed_key(seed))
+def draw(family, s: Dict, seed: int) -> Dict[str, jax.Array]:
+    """Every weight of ``family`` at sizes ``s`` for ``seed``, in one device
+    program."""
+    return _drawer(family, tuple(sorted(s.items())))(seed_key(seed))
+
+
+def shapes(family, s: Dict) -> Dict[str, jax.ShapeDtypeStruct]:
+    """The shapes and types ``draw`` gives, with nothing drawn."""
+    return jax.eval_shape(_drawer(family, tuple(sorted(s.items()))),
+                          seed_key(0))
+
+
+def program_params(family, w: Dict, model) -> Dict:
+    """The drawn weights ``w`` (arrays or shapes) in the program's parameter
+    layout, as the family maps them, checked leaf by leaf against what
+    ``model.init`` would make."""
+    p = family.program_params(w, model)
+    want = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    if (jax.tree.map(lambda a: (a.shape, a.dtype), want)
+            != jax.tree.map(lambda a: (a.shape, a.dtype), p)):
+        raise spec.SpecError("drawn weights do not match the program's "
+                             "parameter layout")
+    return p
