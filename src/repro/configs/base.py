@@ -50,8 +50,28 @@ class ModelConfig:
     lru_width: int = 0  # RG-LRU state width (default d_model)
     # attention-free archs set n_heads=0; enc-dec cross-attn uses n_heads.
     max_seq_len: int = 131072
-    # MoE options
+    # MoE options: ``moe_shared_experts`` experts of width ``d_ff`` fused
+    # into one shared MLP that every token passes through
     moe_shared_experts: int = 0
+    # router scoring: "softmax" (top-k of the softmax, renormalised) or
+    # "sigmoid" (DeepSeek-V3 noaux_tc: top-k of sigmoid + a correction bias
+    # that only chooses, weights the chosen sigmoids over their sum)
+    moe_scoring: str = "softmax"
+    routed_scaling: float = 1.0  # routed weights' factor (sigmoid scoring)
+    # the experts this program holds, the first held_expert_count of the
+    # router's n_experts (0: all); the layer computes only their part
+    held_expert_count: int = 0
+    # leading layers with a dense MLP of width dense_d_ff before the
+    # expert layers (DeepSeek's first_k_dense_replace)
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    # --- latent attention (MLA, DeepSeek-V2/V3), on when kv_lora_rank > 0:
+    # queries of qk_nope + qk_rope per head; keys and values from one
+    # kv_lora_rank latent per token plus one qk_rope key shared by heads
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # source provenance (doc only)
     source: str = ""
 
@@ -73,6 +93,27 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_held_experts(self) -> int:
+        return self.held_expert_count or self.n_experts
+
+    @property
+    def latent_width(self) -> int:
+        """Width of one token's MLA cache entry in one layer: the latent
+        and the shared rope key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    def kv_bytes_per_token(self, dtype_bytes: int) -> int:
+        """Cache bytes one token keeps in one full-attention layer: K and V
+        of every KV head, or one latent entry under MLA."""
+        if self.is_mla:
+            return self.latent_width * dtype_bytes
+        return 2 * self.n_kv_heads * self.resolved_head_dim * dtype_bytes
+
+    @property
     def attn_free(self) -> bool:
         return all(b == "rwkv" for b in self.block_pattern)
 
@@ -91,20 +132,41 @@ class ModelConfig:
         return len(set(self.block_pattern)) == 1
 
     # ------------------------------------------------------------------
+    def _attn_params(self) -> int:
+        d, hd = self.d_model, self.resolved_head_dim
+        if self.is_mla:
+            h, r = self.n_heads, self.kv_lora_rank
+            return (d * h * (self.qk_nope_head_dim + self.qk_rope_head_dim)
+                    + d * self.latent_width + r
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * d)
+        return (d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd)
+                + (self.n_heads * hd) * d)
+
+    def _ffn_params(self, routed: float) -> float:
+        """One layer's FFN with ``routed`` routed experts counted (MoE)."""
+        n_glu = 3 if self.activation in ("swiglu", "geglu") else 2
+        expert = n_glu * self.d_model * self.d_ff
+        if not self.is_moe:
+            return expert
+        router = self.d_model * self.n_experts
+        if self.moe_scoring == "sigmoid":
+            router += self.n_experts  # the correction bias
+        return (routed + self.moe_shared_experts) * expert + router
+
     def param_count(self) -> int:
-        """Approximate parameter count N (used for 6·N·D MODEL_FLOPS)."""
+        """Approximate parameter count N (used for 6·N·D MODEL_FLOPS): the
+        parameters this program holds, so only the held experts count."""
+        return int(self._count(self.n_held_experts))
+
+    def _count(self, routed: float) -> float:
         d = self.d_model
-        hd = self.resolved_head_dim
         counts = {"attn": 0, "local": 0, "rglru": 0, "rwkv": 0}
         for i in range(self.n_layers):
             counts[self.block_kind(i)] += 1
         n_attn = counts["attn"] + counts["local"]
         n_glu = 3 if self.activation in ("swiglu", "geglu") else 2
-        # attention blocks
-        attn_params = (
-            d * (self.n_heads * hd) + 2 * d * (self.n_kv_heads * hd)
-            + (self.n_heads * hd) * d
-        )
+        attn_params = self._attn_params()
         total = n_attn * attn_params
         # rglru blocks: in/gate proj + out proj + diagonal gates
         lru_w = self.lru_width or d
@@ -113,38 +175,38 @@ class ModelConfig:
         # channel-mix replaces the FFN on rwkv layers
         total += counts["rwkv"] * (5 * d * d + 2 * d * 64)
         total += counts["rwkv"] * (2 * d * self.d_ff + d * d)
-        # FFN on all non-rwkv layers
+        # FFN on all non-rwkv layers; leading dense layers of a MoE stack
+        # have a dense MLP of width dense_d_ff
         n_ffn = self.n_layers - counts["rwkv"]
-        if self.is_moe:
-            ffn = (self.n_experts + self.moe_shared_experts) * n_glu * d * self.d_ff
-            ffn += d * self.n_experts  # router
-        else:
-            ffn = n_glu * d * self.d_ff
-        total += n_ffn * ffn
+        n_dense = min(self.first_k_dense, n_ffn) if self.is_moe else 0
+        total += n_dense * n_glu * d * self.dense_d_ff
+        total += (n_ffn - n_dense) * self._ffn_params(routed)
         if self.is_encdec:
             # encoder layers: self-attn + ffn; decoder additionally cross-attn
             enc = self.encoder_layers * (attn_params + n_glu * d * self.d_ff)
             total += enc + self.n_layers * attn_params  # cross attention
         total += self.vocab_size * d * (1 if self.tie_embeddings else 2)
-        return int(total)
+        return total
 
     def active_param_count(self) -> int:
-        """Active parameters per token (MoE: only routed + shared experts)."""
+        """Active parameters per token (MoE: the routed experts a token
+        reaches among those held, in expectation, and the shared ones)."""
         if not self.is_moe:
             return self.param_count()
-        d = self.d_model
-        n_glu = 3 if self.activation in ("swiglu", "geglu") else 2
-        all_moe = (self.n_experts + self.moe_shared_experts) * n_glu * d * self.d_ff
-        active_moe = (self.experts_per_token + self.moe_shared_experts) * n_glu * d * self.d_ff
-        return int(self.param_count() - self.n_layers * (all_moe - active_moe))
+        return int(self._count(self.experts_per_token * self.n_held_experts
+                               / self.n_experts))
 
     # ------------------------------------------------------------------
     def reduced(self) -> "ModelConfig":
         """CPU-smoke-scale variant of the same family."""
         period = len(self.block_pattern)
         # hybrid patterns keep >= 2 full periods so the period-scan path is
-        # exercised at smoke scale
+        # exercised at smoke scale; a leading dense layer keeps one, and two
+        # expert layers follow it
         n_layers = max(2 * period if period > 1 else 2, 2)
+        first_dense = min(self.first_k_dense, 1)
+        if first_dense:
+            n_layers = first_dense + 2
         # keep the pattern intact, shrink everything else
         return dataclasses.replace(
             self,
@@ -164,6 +226,13 @@ class ModelConfig:
             rnn_head_dim=16,
             max_seq_len=128,
             dtype="float32",
+            held_expert_count=0,
+            first_k_dense=first_dense,
+            dense_d_ff=192 if self.dense_d_ff else 0,
+            kv_lora_rank=32 if self.is_mla else 0,
+            qk_nope_head_dim=16 if self.is_mla else 0,
+            qk_rope_head_dim=8 if self.is_mla else 0,
+            v_head_dim=16 if self.is_mla else 0,
         )
 
 

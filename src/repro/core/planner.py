@@ -149,10 +149,10 @@ def plan_model(
     # via FSDP+TP; activations dominated by remat boundaries + caches)
     pbytes = _param_bytes(cfg, train) / chips
     if shape.kind == "decode":
-        hd = cfg.resolved_head_dim or 0
         n_attn = sum(1 for i in range(cfg.n_layers) if cfg.block_kind(i) == "attn")
         n_local = sum(1 for i in range(cfg.n_layers) if cfg.block_kind(i) == "local")
-        cache = 2 * 2 * cfg.n_kv_heads * hd * shape.global_batch * (
+        # bf16 cache: per-head K and V per token, one latent entry (MLA)
+        cache = cfg.kv_bytes_per_token(2) * shape.global_batch * (
             n_attn * shape.seq_len + n_local * max(cfg.window_size, 1)
         )
         pbytes += cache / chips

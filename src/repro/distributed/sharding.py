@@ -141,6 +141,17 @@ def _spec_for(path: str, arr, *, fsdp, model: str, mesh_shape: Dict[str, int],
         if fits(0, fsdp):
             return wrap(fsdp, None, None)
         return wrap(*([None] * ndim))
+    # --- latent attention (MLA): the down-projection to the latent and the
+    # shared rope key (D, r + rope) is one matrix for every head; the
+    # up-projection (r, H, nope + dv) splits by head like w[qkv]
+    if re.search(r"attn/wkv_a$", path):
+        if fits(0, fsdp):
+            return wrap(fsdp, None)
+        return wrap(None, None)
+    if re.search(r"attn/wkv_b$", path):
+        if fits(1, model):
+            return wrap(None, model, None)
+        return wrap(None, None, None)
     if re.search(r"(attn|cross)/wo$", path):
         # (H*hd, D)
         if fits(0, model) and fits(1, fsdp):
@@ -153,12 +164,12 @@ def _spec_for(path: str, arr, *, fsdp, model: str, mesh_shape: Dict[str, int],
         return wrap(None, None, None)
     if re.search(r"ffn/router$", path):
         return wrap(None, None)
-    # --- dense FFN: (D, F) in / (F, D) out
-    if re.search(r"ffn/(w_in|w_gate)$", path):
+    # --- dense FFN: (D, F) in / (F, D) out; a MoE layer's shared MLP too
+    if re.search(r"ffn/(shared/)?(w_in|w_gate)$", path):
         if fits(1, model) and fits(0, fsdp):
             return wrap(fsdp, model)
         return wrap(None, None)
-    if re.search(r"ffn/w_out$", path):
+    if re.search(r"ffn/(shared/)?w_out$", path):
         if fits(0, model) and fits(1, fsdp):
             return wrap(model, fsdp)
         return wrap(None, None)
@@ -296,7 +307,8 @@ def serve_state_sharding(state_shape: Any, mesh: Mesh, *,
     replication — the same feasibility-before-speedup rule as
     ``param_shardings``.  Positions and recurrent/conv states replicate:
     they are per-slot vectors or square per-head states the model axis has
-    no clean dim for.
+    no clean dim for; so does a latent pool (``pl``, MLA), whose one entry
+    per token every head reads.
     """
     tp = mesh.shape.get(model_axis, 1)
 

@@ -12,7 +12,14 @@ Two execution paths:
   * Pallas path: kernels/ops.flash_attention (TPU target, validated in
     interpret mode).
 
-Shapes: q (B, S, Hq, hd); k, v (B, Skv, Hkv, hd); Hq % Hkv == 0.
+Shapes: q (B, S, Hq, hd); k, v (B, Skv, Hkv, hd); Hq % Hkv == 0.  The
+dense and chunked paths also take values of another head size (v (B, Skv,
+Hkv, dv), latent attention's 128 against its 192-wide keys).
+
+Latent attention (MLA, DeepSeek-V2/V3) keeps one latent per token and
+layer in its cache (``mla_*`` below): the plain form expands it to per-head
+keys and values and runs the attention above; the absorbed form scores the
+queries against the cached latent itself (``mla_absorbed_scores``).
 """
 
 from __future__ import annotations
@@ -66,7 +73,7 @@ def dense_attention(
     scores = jnp.where(mask[None, None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bngst,btnd->bsngd", probs, v.astype(jnp.float32))
-    return out.reshape(b, sq, hq, hd).astype(q.dtype)
+    return out.reshape(b, sq, hq, v.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -92,13 +99,14 @@ def chunked_attention(
     b, sq, hq, hd = q.shape
     skv = k.shape[1]
     n_kv = k.shape[2]
+    dv = v.shape[-1]
     if skv % chunk:
         pad = chunk - skv % chunk
         k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
         v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
     n_chunks = k.shape[1] // chunk
     kc = k.reshape(b, n_chunks, chunk, n_kv, hd).transpose(1, 0, 2, 3, 4)
-    vc = v.reshape(b, n_chunks, chunk, n_kv, hd).transpose(1, 0, 2, 3, 4)
+    vc = v.reshape(b, n_chunks, chunk, n_kv, dv).transpose(1, 0, 2, 3, 4)
 
     qg = _group_query(q, n_kv).astype(jnp.float32) * hd ** -0.5
     qpos = jnp.arange(sq)
@@ -129,13 +137,13 @@ def chunked_attention(
 
     m0 = jnp.full((b, n_kv, sq, hq // n_kv), NEG_INF, jnp.float32)
     l0 = jnp.zeros((b, n_kv, sq, hq // n_kv), jnp.float32)
-    acc0 = jnp.zeros((b, n_kv, sq, hq // n_kv, hd), jnp.float32)
+    acc0 = jnp.zeros((b, n_kv, sq, hq // n_kv, dv), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, acc0), (jnp.arange(n_chunks), kc, vc),
         unroll=n_chunks if unroll else 1,
     )
     out = acc / jnp.maximum(l, 1e-30)[..., None]
-    out = out.transpose(0, 2, 1, 3, 4).reshape(b, sq, hq, hd)
+    out = out.transpose(0, 2, 1, 3, 4).reshape(b, sq, hq, dv)
     return out.astype(q.dtype)
 
 
@@ -247,6 +255,22 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
 # ---------------------------------------------------------------------------
 
 
+def _paged_rows(pos, block_tables, b: int, sq: int, block_size: int):
+    """Flat pool rows (B * S_new,) of the logical positions ``pos + i``:
+    block ``pos // block_size`` of each row's table, or block 0 (the null
+    page) past the table's end."""
+    max_blocks = block_tables.shape[1]
+    pos = jnp.reshape(jnp.asarray(pos), (-1,))  # () or (B,) -> (1,) or (B,)
+    pos = jnp.broadcast_to(pos, (b,))
+    logical = pos[:, None] + jnp.arange(sq)[None, :]  # (B, S_new)
+    valid = logical < max_blocks * block_size
+    bidx = jnp.clip(logical // block_size, 0, max_blocks - 1)
+    table = jnp.take_along_axis(block_tables, bidx, axis=1)  # (B, S_new)
+    table = jnp.where(valid, table, 0)  # out-of-range -> null block
+    phys = table * block_size + logical % block_size  # (B, S_new) flat rows
+    return phys.reshape(-1)
+
+
 def paged_update_kv_cache(pk, pv, k_new, v_new, pos, block_tables,
                           block_size: int):
     """Scatter (B, S_new, Hkv, hd) new KV into the shared page pool.
@@ -262,16 +286,7 @@ def paged_update_kv_cache(pk, pv, k_new, v_new, pos, block_tables,
     where the mask zeroes it exactly (exp(NEG_INF) == 0.0 in f32)."""
     b = k_new.shape[0]
     sq = k_new.shape[1]
-    max_blocks = block_tables.shape[1]
-    pos = jnp.reshape(jnp.asarray(pos), (-1,))  # () or (B,) -> (1,) or (B,)
-    pos = jnp.broadcast_to(pos, (b,))
-    logical = pos[:, None] + jnp.arange(sq)[None, :]  # (B, S_new)
-    valid = logical < max_blocks * block_size
-    bidx = jnp.clip(logical // block_size, 0, max_blocks - 1)
-    table = jnp.take_along_axis(block_tables, bidx, axis=1)  # (B, S_new)
-    table = jnp.where(valid, table, 0)  # out-of-range -> null block
-    phys = table * block_size + logical % block_size  # (B, S_new) flat rows
-    flat = phys.reshape(-1)
+    flat = _paged_rows(pos, block_tables, b, sq, block_size)
     nk = k_new.astype(pk.dtype).reshape((b * sq,) + k_new.shape[2:])
     nv = v_new.astype(pv.dtype).reshape((b * sq,) + v_new.shape[2:])
     shape = pk.shape
@@ -294,6 +309,99 @@ def paged_gather_kv(pk, pv, block_tables, block_size: int):
     kc = kc.reshape((b, max_blocks * block_size) + pk.shape[2:])
     vc = vc.reshape((b, max_blocks * block_size) + pv.shape[2:])
     return kc, vc
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA)
+# ---------------------------------------------------------------------------
+
+
+def mla_plain_kv(latent, k_rope, wkv_b, nope: int):
+    """The plain form's per-head keys and values: ``wkv_b`` (r, H, nope +
+    dv) turns the normed latent (B, T, r) into each head's key part
+    ``c W_uk`` and value ``c W_uv``; the roped key (B, T, rope) is shared
+    by every head.  Returns k (B, T, H, nope + rope), v (B, T, H, dv)."""
+    w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
+    k_nope = jnp.einsum("btr,rhd->bthd", latent, w_uk)
+    v = jnp.einsum("btr,rhd->bthd", latent, w_uv)
+    h = wkv_b.shape[1]
+    k_pe = jnp.broadcast_to(k_rope[:, :, None, :],
+                            k_rope.shape[:2] + (h, k_rope.shape[-1]))
+    return jnp.concatenate([k_nope, k_pe.astype(k_nope.dtype)], -1), v
+
+
+def mla_absorbed_scores(q_nope, q_rope, latent, k_rope, w_uk):
+    """Unscaled float32 scores (B, H, S, T) of queries against cached
+    latents, the same numbers the plain form computes.
+
+    The plain form's key of head ``h`` at position ``t`` is ``[c_t W_uk[h],
+    k_rope_t]``, so ``q · k = q_nope · (c_t W_uk[h]) + q_rope · k_rope_t =
+    (W_uk[h] q_nope) · c_t + q_rope · k_rope_t``: turning each query's
+    ``q_nope`` through ``W_uk`` once (into the r-wide latent space) lets
+    every score be read from the cached latent, with no per-head key ever
+    formed.  The values absorb the same way on the output side
+    (``mla_decode_attention``): ``sum_t p_t c_t W_uv = (sum_t p_t c_t)
+    W_uv``."""
+    f32 = jnp.float32
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope.astype(f32), w_uk.astype(f32))
+    s = jnp.einsum("bshr,btr->bhst", q_lat, latent.astype(f32))
+    return s + jnp.einsum("bshe,bte->bhst", q_rope.astype(f32),
+                          k_rope.astype(f32))
+
+
+def mla_decode_attention(q_nope, q_rope, cache, cache_pos, wkv_b, *,
+                         scale: float):
+    """Absorbed latent attention of (B, S_new, H, .) queries against a
+    latent cache (B, T, r + rope) of normed latents and roped shared keys,
+    masked as ``decode_attention`` masks (``cache_pos`` counts valid
+    entries including the S_new just written).  Computes in float32, as
+    ``decode_attention`` does.  Returns (B, S_new, H, dv)."""
+    b, sq = q_nope.shape[:2]
+    r = wkv_b.shape[0]
+    nope = q_nope.shape[-1]
+    latent, k_rope = cache[..., :r], cache[..., r:]
+    w_uk, w_uv = wkv_b[..., :nope], wkv_b[..., nope:]
+    s = mla_absorbed_scores(q_nope, q_rope, latent, k_rope, w_uk) * scale
+    skv = cache.shape[1]
+    kpos = jnp.arange(skv)
+    limit = (jnp.reshape(jnp.asarray(cache_pos), (-1, 1))
+             - (sq - 1) + jnp.arange(sq)[None])  # (1 or B, S_new)
+    mask = jnp.broadcast_to(kpos[None, None, :] < limit[:, :, None],
+                            (b, sq, skv))
+    s = jnp.where(mask[:, None], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o_lat = jnp.einsum("bhst,btr->bshr", p, latent.astype(jnp.float32))
+    out = jnp.einsum("bshr,rhd->bshd", o_lat, w_uv.astype(jnp.float32))
+    return out.astype(q_nope.dtype)
+
+
+def update_latent_cache(cache, new, pos):
+    """Insert (B, S_new, W) latent entries into a (B, Smax, W) cache at
+    ``pos``, a scalar or a (B,) vector of per-slot positions."""
+    pos = jnp.asarray(pos)
+    new = new.astype(cache.dtype)
+    if pos.ndim == 0:
+        return jax.lax.dynamic_update_slice(cache, new, (0, pos, 0))
+    return jax.vmap(lambda c, n, p: jax.lax.dynamic_update_slice(
+        c, n, (p, 0)))(cache, new, pos)
+
+
+def paged_update_latent(pool, new, pos, block_tables, block_size: int):
+    """Scatter (B, S_new, W) latent entries into a (n_blocks, block_size,
+    W) pool, with the addressing (null page included) of
+    ``paged_update_kv_cache``."""
+    b, sq = new.shape[:2]
+    flat = _paged_rows(pos, block_tables, b, sq, block_size)
+    shape = pool.shape
+    rows = new.astype(pool.dtype).reshape(b * sq, shape[-1])
+    return pool.reshape(-1, shape[-1]).at[flat].set(rows).reshape(shape)
+
+
+def paged_gather_latent(pool, block_tables, block_size: int):
+    """Each slot's logical latent view (B, max_blocks * block_size, W)."""
+    b, max_blocks = block_tables.shape
+    return pool[block_tables].reshape(b, max_blocks * block_size,
+                                      pool.shape[-1])
 
 
 # ---------------------------------------------------------------------------

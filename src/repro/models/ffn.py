@@ -1,10 +1,18 @@
 """Feed-forward blocks: dense GLU FFNs and Mixture-of-Experts.
 
+One routing function (``route``) serves every MoE path: softmax top-k
+(renormalised), or DeepSeek-V3's sigmoid scores with a correction bias that
+only chooses.  A layer holds the experts ``[first, first + E)`` of the
+router's ``n`` outputs (all of them unless told otherwise) and computes
+only their part of the result; shared experts (``params["shared"]``, one
+MLP) are added for every token.
+
 MoE has two execution paths:
 
-* ``moe_dense`` — reference/oracle path: every expert computed for every
-  token, outputs combined by router weight.  Exact (no token dropping);
-  used at smoke scale and as the allclose oracle for the EP path.
+* ``moe_apply`` off a mesh — every held expert computed for every token,
+  weighted by the router (zero where a token did not choose it).  Exact (no
+  capacity, no token dropping); it is the serving path and the allclose
+  oracle for EP.
 
 * ``moe_ep`` — expert-parallel production path, run under ``shard_map``:
   experts are sharded over the ``model`` mesh axis, tokens are sharded over
@@ -63,26 +71,64 @@ def ffn_apply(params, x, activation: str):
 # ---------------------------------------------------------------------------
 
 
-def moe_init(key, d: int, f: int, n_experts: int, activation: str, dtype=jnp.float32):
+def moe_init(key, d: int, f: int, n_experts: int, activation: str,
+             dtype=jnp.float32, *, n_held: Optional[int] = None,
+             shared_ff: int = 0, scoring: str = "softmax"):
+    """Router over ``n_experts`` (float32), the ``n_held`` experts this
+    layer holds (default all), a correction bias for sigmoid scoring, and
+    a shared MLP of width ``shared_ff`` (0: none)."""
     from repro.models.common import dense_init
 
-    ks = jax.random.split(key, 4)
+    e = n_experts if n_held is None else n_held
+    ks = jax.random.split(key, 5)
     p = {
         "router": dense_init(ks[0], d, (n_experts,), jnp.float32),
-        "w_in": dense_init(ks[1], d, (n_experts, f), dtype).transpose(1, 0, 2),
-        "w_out": dense_init(ks[2], f, (n_experts, d), dtype).transpose(1, 0, 2),
+        "w_in": dense_init(ks[1], d, (e, f), dtype).transpose(1, 0, 2),
+        "w_out": dense_init(ks[2], f, (e, d), dtype).transpose(1, 0, 2),
     }
     if is_glu(activation):
-        p["w_gate"] = dense_init(ks[3], d, (n_experts, f), dtype).transpose(1, 0, 2)
+        p["w_gate"] = dense_init(ks[3], d, (e, f), dtype).transpose(1, 0, 2)
+    if scoring == "sigmoid":
+        p["router_bias"] = jnp.zeros((n_experts,), jnp.float32)
+    if shared_ff:
+        p["shared"] = ffn_init(ks[4], d, shared_ff, activation, dtype)
     return p  # expert tensors: (E, D, F) / (E, F, D)
 
 
-def _router_topk(logits: jax.Array, k: int):
-    """Return (weights, ids): renormalized top-k router weights."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    w, ids = jax.lax.top_k(probs, k)
-    w = w / jnp.maximum(w.sum(axis=-1, keepdims=True), 1e-9)
-    return w, ids
+def route(logits: jax.Array, top_k: int, *, scoring: str = "softmax",
+          bias: Optional[jax.Array] = None, scale: float = 1.0):
+    """Return (weights (T, K) float32, ids (T, K)) of each token's experts.
+
+    ``softmax``: the top-k of the softmax, renormalised to sum to one.
+    ``sigmoid`` (DeepSeek-V3 ``noaux_tc`` with one group): scores are
+    ``sigmoid(logits)``; the choice is the top-k of score + ``bias`` (the
+    correction bias steers the choice only); the weights are the chosen
+    scores over their sum, times ``scale`` (``routed_scaling_factor``)."""
+    logits = logits.astype(jnp.float32)
+    if scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        w, ids = jax.lax.top_k(probs, top_k)
+        w = w / jnp.maximum(w.sum(axis=-1, keepdims=True), 1e-9)
+        return w, ids
+    if scoring != "sigmoid":
+        raise ValueError(f"unknown router scoring {scoring!r}")
+    scores = jax.nn.sigmoid(logits)
+    choice = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, ids = jax.lax.top_k(choice, top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    w = w / (w.sum(axis=-1, keepdims=True) + 1e-20)
+    return w * scale, ids
+
+
+def held_counts(ids: jax.Array, first: int, n_held: int,
+                token_mask: Optional[jax.Array] = None) -> jax.Array:
+    """(2,) int32: routed (token, expert) pairs that land on the held
+    experts, and held experts with at least one routed token, over the
+    tokens where ``token_mask`` (T,) is true (every token if None)."""
+    hit = (ids[:, :, None] == first + jnp.arange(n_held)).any(axis=1)
+    if token_mask is not None:
+        hit &= token_mask[:, None]
+    return jnp.stack([hit.sum(), hit.any(axis=0).sum()]).astype(jnp.int32)
 
 
 def load_balance_loss(logits: jax.Array, ids: jax.Array, n_experts: int) -> jax.Array:
@@ -94,24 +140,29 @@ def load_balance_loss(logits: jax.Array, ids: jax.Array, n_experts: int) -> jax.
     return n_experts * jnp.sum(f * p_mean)
 
 
-def moe_dense(params, x, *, top_k: int, activation: str):
-    """Oracle path: compute every expert for every token."""
-    act = activation_fn(activation)
-    b, s, d = x.shape
-    t = x.reshape(-1, d)
+def _routing(params, t, top_k, scoring, scale):
+    """The router's logits and route() of tokens ``t`` (T, D)."""
     logits = t.astype(jnp.float32) @ params["router"]
-    w, ids = _router_topk(logits, top_k)  # (T,K)
+    w, ids = route(logits, top_k, scoring=scoring,
+                   bias=params.get("router_bias"), scale=scale)
+    return logits, w, ids
+
+
+def _held_part(params, t, w, ids, first: int, activation: str):
+    """Held experts' part of the result for tokens ``t`` (T, D): a dense
+    product over the held experts, each token's weight zero where it did
+    not choose the expert."""
+    act = activation_fn(activation)
+    n_held = params["w_in"].shape[0]
+    hit = ids[:, :, None] == first + jnp.arange(n_held)  # (T, K, E)
+    gate = jnp.sum(jnp.where(hit, w[..., None], 0.0), axis=1)  # (T, E)
     h = jnp.einsum("td,edf->tef", t, params["w_in"])
     if is_glu(activation):
         h = act(jnp.einsum("td,edf->tef", t, params["w_gate"])) * h
     else:
         h = act(h)
-    y_all = jnp.einsum("tef,efd->ted", h, params["w_out"])  # (T,E,D)
-    onehot_w = jnp.zeros((t.shape[0], params["router"].shape[1]), y_all.dtype)
-    onehot_w = onehot_w.at[jnp.arange(t.shape[0])[:, None], ids].add(w.astype(y_all.dtype))
-    y = jnp.einsum("ted,te->td", y_all, onehot_w)
-    aux = load_balance_loss(logits, ids, params["router"].shape[1])
-    return y.reshape(b, s, d), aux
+    h = (h * gate[..., None]).astype(t.dtype)
+    return jnp.einsum("tef,efd->td", h, params["w_out"])
 
 
 def _round_up(n: int, m: int) -> int:
@@ -119,11 +170,13 @@ def _round_up(n: int, m: int) -> int:
 
 
 def _moe_local(t, router, w_in, w_gate, w_out, *, top_k, n_experts, ep_shards,
-               capacity, activation, model_axis):
+               capacity, activation, model_axis, bias=None,
+               scoring="softmax", scale=1.0, first=0):
     """Per-device body of the EP path (runs inside shard_map).
 
     t: (T, D) local tokens (replicated over the model axis);
-    w_*: (E_loc, D, F) local expert shards; ``capacity`` is PER EXPERT.
+    w_*: (E_loc, D, F) local expert shards of the ``n_experts`` held ones,
+    which start at global expert ``first``; ``capacity`` is PER EXPERT.
 
     Dispatch layout: a fixed (E_loc, capacity, D) slot buffer per rank and
     batched einsums.  §Perf iteration 1 (EXPERIMENTS.md): the earlier
@@ -136,10 +189,11 @@ def _moe_local(t, router, w_in, w_gate, w_out, *, top_k, n_experts, ep_shards,
     T, d = t.shape
     e_loc = n_experts // ep_shards
     rank = jax.lax.axis_index(model_axis)
-    lo = rank * e_loc
+    lo = first + rank * e_loc
 
     logits = t.astype(jnp.float32) @ router
-    w, ids = _router_topk(logits, top_k)  # (T, K)
+    w, ids = route(logits, top_k, scoring=scoring, bias=bias,
+                   scale=scale)  # (T, K)
     flat_ids = ids.reshape(-1)  # (T*K,)
     flat_w = w.reshape(-1).astype(t.dtype)  # keep combine traffic in bf16
     local = (flat_ids >= lo) & (flat_ids < lo + e_loc)
@@ -183,11 +237,15 @@ def moe_ep(
     data_axes,
     model_axis: str = "model",
     capacity_factor: float = 2.0,
+    scoring: str = "softmax",
+    scale: float = 1.0,
+    first: int = 0,
 ):
-    """Expert-parallel MoE over ``mesh``; see module docstring."""
+    """Expert-parallel MoE over ``mesh`` (routed part); see module
+    docstring.  Returns (y, aux)."""
 
     b, s, d = x.shape
-    n_experts = params["router"].shape[1]
+    n_experts = params["w_in"].shape[0]  # held: split over the model axis
     ep = mesh.shape[model_axis]
     dp = 1
     for ax in data_axes:
@@ -200,24 +258,32 @@ def moe_ep(
     dspec = tuple(data_axes) if len(data_axes) > 1 else data_axes[0]
     has_gate = "w_gate" in params
 
-    def body(t3, router, w_in, w_gate, w_out):
+    bias = params.get("router_bias")
+    has_bias = bias is not None
+
+    def body(t3, router, rbias, w_in, w_gate, w_out):
         t = t3.reshape(-1, d)
         y = _moe_local(
             t, router, w_in, w_gate if has_gate else None, w_out,
             top_k=top_k, n_experts=n_experts, ep_shards=ep, capacity=capacity,
             activation=activation, model_axis=model_axis,
+            bias=rbias if has_bias else None, scoring=scoring, scale=scale,
+            first=first,
         )
         return y.reshape(t3.shape)
 
     in_specs = (
         P(dspec, None, None),  # x: tokens sharded over data axes
         P(None, None),  # router replicated
+        P(None),  # correction bias replicated
         P(model_axis, None, None),  # experts sharded over model
         P(model_axis, None, None),
         P(model_axis, None, None),
     )
-    args = (x, params["router"], params["w_in"],
-            params.get("w_gate", params["w_in"]), params["w_out"])
+    args = (x, params["router"],
+            bias if has_bias else jnp.zeros((1,), jnp.float32),
+            params["w_in"], params.get("w_gate", params["w_in"]),
+            params["w_out"])
     y = jax.shard_map(
         body,
         mesh=mesh,
@@ -226,22 +292,34 @@ def moe_ep(
         check_vma=False,
     )(*args)
     # aux loss from a (cheap, tokens x E) global router replay
-    logits = x.reshape(-1, d).astype(jnp.float32) @ params["router"]
-    _, ids = jax.lax.top_k(jax.nn.softmax(logits, -1), top_k)
-    aux = load_balance_loss(logits, ids, n_experts)
+    logits, _, ids = _routing(params, x.reshape(-1, d), top_k, scoring, scale)
+    aux = load_balance_loss(logits, ids, params["router"].shape[1])
     return y, aux
 
 
-def moe_apply(params, x, *, top_k: int, activation: str, ctx=None):
-    """Dispatch: EP under a mesh context, dense oracle otherwise.
+def moe_apply(params, x, *, top_k: int, activation: str, ctx=None,
+              scoring: str = "softmax", scale: float = 1.0, first: int = 0,
+              token_mask: Optional[jax.Array] = None):
+    """The expert layer: EP under a mesh context, the dense product over
+    the held experts otherwise, plus the shared MLP.  Returns (y, aux,
+    counts), ``counts`` the ``held_counts`` of the tokens where
+    ``token_mask`` (B, S) is true.  Scopes ``moe_router``, ``moe_experts``
+    and ``moe_shared`` name its parts in the compiled program.  Sigmoid
+    scoring balances by its correction bias: aux is 0.
 
     The EP collective strategy (replicated-psum vs all-to-all) is a
     CostEngine decision site: the query lands in the engine's ledger at
     trace time.  Only the psum path is implemented, so an all-to-all verdict
     is advisory — the ledger documents the gap instead of hiding it.
     """
+    b, s, d = x.shape
+    t = x.reshape(-1, d)
+    with jax.named_scope("moe_router"):
+        logits, w, ids = _routing(params, t, top_k, scoring, scale)
+        counts = held_counts(
+            ids, first, params["w_in"].shape[0],
+            None if token_mask is None else token_mask.reshape(-1))
     if ctx is not None and ctx.use_ep and ctx.mesh.shape.get(ctx.model_axis, 1) > 1:
-        b, s, d = x.shape
         ep = ctx.mesh.shape[ctx.model_axis]
         engine = getattr(ctx, "cost_engine", None)
         if engine is None:
@@ -256,9 +334,21 @@ def moe_apply(params, x, *, top_k: int, activation: str, ctx=None):
                 "moe_dispatch", dec.query.as_dict(), "replicated_psum",
                 dec.baseline, note=f"engine prefers {dec.choice}; psum is the "
                 f"implemented EP path")
-        return moe_ep(
-            params, x, top_k=top_k, activation=activation, mesh=ctx.mesh,
-            data_axes=ctx.data_axes, model_axis=ctx.model_axis,
-            capacity_factor=ctx.moe_capacity_factor,
-        )
-    return moe_dense(params, x, top_k=top_k, activation=activation)
+        with jax.named_scope("moe_experts"):
+            y, aux = moe_ep(
+                params, x, top_k=top_k, activation=activation, mesh=ctx.mesh,
+                data_axes=ctx.data_axes, model_axis=ctx.model_axis,
+                capacity_factor=ctx.moe_capacity_factor, scoring=scoring,
+                scale=scale, first=first,
+            )
+    else:
+        aux = load_balance_loss(logits, ids, params["router"].shape[1])
+        with jax.named_scope("moe_experts"):
+            y = _held_part(params, t, w, ids, first,
+                           activation).reshape(b, s, d)
+    if scoring == "sigmoid":
+        aux = jnp.zeros((), jnp.float32)
+    if "shared" in params:
+        with jax.named_scope("moe_shared"):
+            y = y + ffn_apply(params["shared"], x, activation)
+    return y, aux, counts

@@ -89,6 +89,10 @@ class Model:
     reset_decode_slots: Callable[..., Any] = None  # (state, slot_mask) -> state
     insert_decode_slot: Callable[..., Any] = None  # (state, src, slot) -> state
     merge_decode_state: Callable[..., Any] = None  # (new, old, active) -> state
+    # models with experts: decode_step that also returns the (2,) int32
+    # [held_assignments, experts_hit] of the tokens where the batch's
+    # optional ``token_mask`` (B, S) is true, summed over the expert layers
+    decode_step_counted: Optional[Callable[..., Any]] = None
 
 
 def build_model(cfg: ModelConfig) -> Model:
@@ -150,7 +154,7 @@ def _build_decoder_only(cfg: ModelConfig) -> Model:
             params["layers"], cfg, x, positions, ctx=ctx, remat=remat
         )
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-        return _logits(params, x), aux
+        return _logits(params, x), tfm.aux_loss(aux)
 
     def prefill(params, batch, ctx=None):
         """Inference prefill: full forward, logits only at the last position."""
@@ -170,6 +174,7 @@ def _build_decoder_only(cfg: ModelConfig) -> Model:
         if ctx is not None:
             x = ctx.constrain_act(x)
         x, _, aux = tfm.stack_apply(params["layers"], cfg, x, positions, ctx=ctx)
+        aux = tfm.aux_loss(aux)
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         table = params["embed"] if cfg.tie_embeddings else params["unembed"]
         targets = tokens[:, 1:]
@@ -194,19 +199,27 @@ def _build_decoder_only(cfg: ModelConfig) -> Model:
             "pos": jnp.zeros(pos_shape, jnp.int32),
         }
 
-    def decode_step(params, state, batch, ctx=None):
+    def _decode(params, state, batch, ctx):
         pos = state["pos"]
         x, positions = _embed(params, batch, decode_offset=pos)
-        if cfg.pos_type == "rope":
-            positions = positions  # (B,1) absolute
-        x, new_layers, _ = tfm.stack_apply(
+        x, new_layers, aux = tfm.stack_apply(
             params["layers"], cfg, x, positions,
             states=state["layers"], cache_pos=pos, ctx=ctx, remat=False,
             block_tables=batch.get("block_tables"),
+            token_mask=batch.get("token_mask"),
         )
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = _logits(params, x)
-        return logits, {"layers": new_layers, "pos": pos + batch["tokens"].shape[1]}
+        return (logits, {"layers": new_layers,
+                         "pos": pos + batch["tokens"].shape[1]}, aux)
+
+    def decode_step(params, state, batch, ctx=None):
+        logits, new_state, _ = _decode(params, state, batch, ctx)
+        return logits, new_state
+
+    def decode_step_counted(params, state, batch, ctx=None):
+        logits, new_state, aux = _decode(params, state, batch, ctx)
+        return logits, new_state, aux["counts"]
 
     def reset_decode_slots(state, slot_mask):
         """Zero the decode state of every slot where ``slot_mask`` is True
@@ -244,6 +257,7 @@ def _build_decoder_only(cfg: ModelConfig) -> Model:
         reset_decode_slots=reset_decode_slots,
         insert_decode_slot=insert_decode_slot,
         merge_decode_state=merge_decode_state,
+        decode_step_counted=decode_step_counted if cfg.is_moe else None,
     )
 
 

@@ -1,7 +1,14 @@
 """Decoder-only transformer assembly: uniform-pattern models run layers under
 ``jax.lax.scan`` over stacked params (small HLO, fast compile at 94 layers);
-hybrid patterns (recurrentgemma) unroll.  Every block kind (attn / local /
-rglru / rwkv) exposes the same (x, state) -> (x, state, aux) interface.
+hybrid patterns (recurrentgemma) unroll; a MoE stack with leading dense
+layers (``first_k_dense``, DeepSeek-V3) runs those alone, then scans the
+expert layers.  Every block kind (attn / local / rglru / rwkv) exposes the
+same (x, state) -> (x, state, aux) interface; full-attention layers of a
+config with ``kv_lora_rank`` are latent attention (MLA).
+
+``aux`` is the auxiliary loss, a scalar; in a model with experts it is
+``{"aux": loss, "counts": (2,) int32}``, the counts being ``held_counts``
+summed over the expert layers (``aux_loss`` reads the loss of either).
 """
 
 from __future__ import annotations
@@ -27,6 +34,10 @@ from repro.models.common import (
     rmsnorm_init,
 )
 
+# decode-state leaves that are paged pools shared by all slots (no slot
+# axis): per-head K and V, or one latent entry per token (MLA)
+POOL_LEAVES = ("pk", "pv", "pl")
+
 # ---------------------------------------------------------------------------
 # Per-layer init
 # ---------------------------------------------------------------------------
@@ -35,6 +46,17 @@ from repro.models.common import (
 def _attn_init(key, cfg: ModelConfig, dtype):
     hd = cfg.resolved_head_dim
     ks = jax.random.split(key, 4)
+    if cfg.is_mla:
+        h, r = cfg.n_heads, cfg.kv_lora_rank
+        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        return {
+            "wq": dense_init(ks[0], cfg.d_model, (h, qk), dtype),
+            "wkv_a": dense_init(ks[1], cfg.d_model, (cfg.latent_width,), dtype),
+            "kv_norm": rmsnorm_init(r, jnp.float32),
+            "wkv_b": dense_init(ks[2], r, (h, cfg.qk_nope_head_dim
+                                           + cfg.v_head_dim), dtype),
+            "wo": dense_init(ks[3], h * cfg.v_head_dim, (cfg.d_model,), dtype),
+        }
     return {
         "wq": dense_init(ks[0], cfg.d_model, (cfg.n_heads, hd), dtype),
         "wk": dense_init(ks[1], cfg.d_model, (cfg.n_kv_heads, hd), dtype),
@@ -43,13 +65,20 @@ def _attn_init(key, cfg: ModelConfig, dtype):
     }
 
 
-def _ffn_init(key, cfg: ModelConfig, dtype):
-    if cfg.is_moe:
-        return ffn_lib.moe_init(key, cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.activation, dtype)
-    return ffn_lib.ffn_init(key, cfg.d_model, cfg.d_ff, cfg.activation, dtype)
+def _ffn_init(key, cfg: ModelConfig, dtype, dense: bool = False):
+    if cfg.is_moe and not dense:
+        return ffn_lib.moe_init(
+            key, cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.activation, dtype,
+            n_held=cfg.n_held_experts,
+            shared_ff=cfg.moe_shared_experts * cfg.d_ff,
+            scoring=cfg.moe_scoring)
+    d_ff = cfg.dense_d_ff if dense else cfg.d_ff
+    return ffn_lib.ffn_init(key, cfg.d_model, d_ff, cfg.activation, dtype)
 
 
-def layer_init(key, cfg: ModelConfig, kind: str, dtype):
+def layer_init(key, cfg: ModelConfig, kind: str, dtype,
+               dense_ffn: bool = False):
+    """``dense_ffn``: a leading dense layer of a MoE stack."""
     k1, k2 = jax.random.split(key)
     p: Dict[str, Any] = {
         "ln1": rmsnorm_init(cfg.d_model, jnp.float32),
@@ -57,7 +86,7 @@ def layer_init(key, cfg: ModelConfig, kind: str, dtype):
     }
     if kind in ("attn", "local"):
         p["attn"] = _attn_init(k1, cfg, dtype)
-        p["ffn"] = _ffn_init(k2, cfg, dtype)
+        p["ffn"] = _ffn_init(k2, cfg, dtype, dense_ffn)
     elif kind == "rglru":
         p["rglru"] = rglru_lib.rglru_init(k1, cfg.d_model, cfg.lru_width or cfg.d_model, dtype)
         p["ffn"] = _ffn_init(k2, cfg, dtype)
@@ -137,22 +166,99 @@ def _attn_apply(
     return out, new_cache
 
 
-def _ffn_apply(params, cfg: ModelConfig, x, ctx):
+def _mla_apply(params, cfg: ModelConfig, x, positions, *, cache=None,
+               cache_pos=None, ctx=None, block_tables=None):
+    """Latent attention (scope ``mla``).  Returns (out, new_cache).
+
+    Queries are ``wq`` (d -> H x (nope + rope)); ``wkv_a`` (d -> r + rope)
+    gives the latent, RMS-normed by ``kv_norm``, and one rope key shared by
+    the heads; RoPE turns only the rope parts; scale (nope + rope)^-0.5,
+    causal.  Without a cache (training, full forwards) the plain form
+    expands the latent to per-head K and V through ``wkv_b``; with one,
+    each token writes one (r + rope) entry per layer (``lat`` per slot, or
+    the paged pool ``pl``) and attention runs in the absorbed form against
+    the cached latents (``attention.mla_absorbed_scores``)."""
+    b, s, d = x.shape
+    h, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    with jax.named_scope("mla"):
+        q = (x @ params["wq"].reshape(d, -1)).reshape(b, s, h, nope + rope)
+        q_nope = q[..., :nope]
+        q_rope = positional(q[..., nope:], positions, cfg.pos_type,
+                            cfg.rope_theta)
+        kv = x @ params["wkv_a"]
+        latent = rmsnorm(params["kv_norm"], kv[..., :r], cfg.norm_eps)
+        k_rope = positional(kv[..., None, r:], positions, cfg.pos_type,
+                            cfg.rope_theta)[:, :, 0]
+        if cache is None:
+            k, v = attn_lib.mla_plain_kv(latent, k_rope, params["wkv_b"],
+                                         nope)
+            out = attn_lib.attention(
+                jnp.concatenate([q_nope, q_rope], -1), k, v, causal=True,
+                chunk=ctx.attn_chunk if ctx is not None else 1024,
+                unroll=bool(ctx is not None and ctx.unroll_scans))
+            new_cache = None
+        else:
+            entry = jnp.concatenate([latent, k_rope], -1)
+            if "pl" in cache:
+                bs = cache["pl"].shape[1]
+                pool = attn_lib.paged_update_latent(
+                    cache["pl"], entry, cache_pos, block_tables, bs)
+                view = attn_lib.paged_gather_latent(pool, block_tables, bs)
+                new_cache = {"pl": pool}
+            else:
+                view = attn_lib.update_latent_cache(cache["lat"], entry,
+                                                    cache_pos)
+                new_cache = {"lat": view}
+            out = attn_lib.mla_decode_attention(
+                q_nope, q_rope, view, cache_pos + s, params["wkv_b"],
+                scale=(nope + rope) ** -0.5)
+        out = out.reshape(b, s, -1) @ params["wo"]
+    return out, new_cache
+
+
+def _aux_zero(cfg: ModelConfig):
     if cfg.is_moe:
-        return ffn_lib.moe_apply(
-            params, x, top_k=cfg.experts_per_token, activation=cfg.activation, ctx=ctx
-        )
-    return ffn_lib.ffn_apply(params, x, cfg.activation), jnp.zeros((), jnp.float32)
+        return {"aux": jnp.zeros((), jnp.float32),
+                "counts": jnp.zeros((2,), jnp.int32)}
+    return jnp.zeros((), jnp.float32)
+
+
+def _aux_add(a, b):
+    return jax.tree.map(lambda u, v: u + v, a, b)
+
+
+def aux_loss(aux):
+    """The auxiliary loss of a stack's ``aux``."""
+    return aux["aux"] if isinstance(aux, dict) else aux
+
+
+def _ffn_apply(params, cfg: ModelConfig, x, ctx, dense: bool = False,
+               token_mask=None):
+    if cfg.is_moe and not dense:
+        y, aux, counts = ffn_lib.moe_apply(
+            params, x, top_k=cfg.experts_per_token, activation=cfg.activation,
+            ctx=ctx, scoring=cfg.moe_scoring, scale=cfg.routed_scaling,
+            token_mask=token_mask)
+        return y, {"aux": aux, "counts": counts}
+    return ffn_lib.ffn_apply(params, x, cfg.activation), _aux_zero(cfg)
 
 
 def layer_apply(
     params, cfg: ModelConfig, kind: str, x, positions, *,
     state=None, cache_pos=None, ctx=None, block_tables=None,
-) -> Tuple[jax.Array, Any, jax.Array]:
-    """Pre-norm residual block. Returns (x, new_state, aux_loss)."""
-    aux = jnp.zeros((), jnp.float32)
+    dense_ffn: bool = False, token_mask=None,
+) -> Tuple[jax.Array, Any, Any]:
+    """Pre-norm residual block. Returns (x, new_state, aux).
+    ``dense_ffn``: a leading dense layer of a MoE stack; ``token_mask``
+    (B, S) marks the tokens an expert layer counts."""
+    aux = _aux_zero(cfg)
     h = rmsnorm(params["ln1"], x, cfg.norm_eps)
-    if kind in ("attn", "local"):
+    if kind == "attn" and cfg.is_mla:
+        out, new_mix_state = _mla_apply(
+            params["attn"], cfg, h, positions, cache=state,
+            cache_pos=cache_pos, ctx=ctx, block_tables=block_tables)
+    elif kind in ("attn", "local"):
         window = cfg.window_size if kind == "local" else 0
         out, new_mix_state = _attn_apply(
             params["attn"], cfg, h, positions, window=window,
@@ -182,7 +288,8 @@ def layer_apply(
             {"time": new_mix_state, "channel": new_cm_state} if state is not None else None
         )
     else:
-        out2, aux = _ffn_apply(params["ffn"], cfg, h2, ctx)
+        out2, aux = _ffn_apply(params["ffn"], cfg, h2, ctx, dense_ffn,
+                               token_mask)
         new_state = new_mix_state
     x = x + out2
     if ctx is not None:
@@ -198,10 +305,18 @@ def layer_apply(
 def layer_init_state(cfg: ModelConfig, kind: str, batch: int, max_len: int, dtype,
                      paging=None):
     """``paging=(n_blocks, block_size)`` switches full-attention layers to a
-    slot-shared page pool (``pk``/``pv`` leaves, no batch axis) addressed by
-    per-slot block tables; windowed/recurrent kinds keep dense per-slot
-    state (their footprint is already O(window) / O(1) per slot)."""
+    slot-shared page pool (``pk``/``pv`` leaves, or one ``pl`` latent leaf
+    under MLA; no batch axis) addressed by per-slot block tables;
+    windowed/recurrent kinds keep dense per-slot state (their footprint is
+    already O(window) / O(1) per slot)."""
     hd = cfg.resolved_head_dim
+    if kind == "attn" and cfg.is_mla:
+        # one latent entry per token and layer, no head axis
+        if paging is not None:
+            n_blocks, block_size = paging
+            return {"pl": jnp.zeros((n_blocks, block_size, cfg.latent_width),
+                                    dtype)}
+        return {"lat": jnp.zeros((batch, max_len, cfg.latent_width), dtype)}
     if kind == "attn":
         if paging is not None:
             n_blocks, block_size = paging
@@ -240,7 +355,7 @@ def stack_state_map(cfg: ModelConfig, fn, *states):
     ``rest_*`` tails) keep the slot axis at 0.  The serving slot pool uses
     this to reset/insert a single slot without knowing the layout.
 
-    Paged page pools (``pk``/``pv`` leaves) have NO slot axis — they are
+    Paged page pools (``POOL_LEAVES``) have NO slot axis — they are
     shared storage addressed by block tables, and slot semantics (reset,
     insert, freeze) live entirely in the tables and refcounts.  Per-slot
     surgery therefore passes them through from the FIRST state tree
@@ -252,10 +367,13 @@ def stack_state_map(cfg: ModelConfig, fn, *states):
     def mapper(axis, *trees):
         return jax.tree_util.tree_map_with_path(
             lambda path, *ls: (
-                ls[0] if getattr(path[-1], "key", None) in ("pk", "pv")
+                ls[0] if getattr(path[-1], "key", None) in POOL_LEAVES
                 else fn(axis, *ls)),
             *trees)
 
+    if _use_lead_scan(cfg):
+        return {key: mapper(1 if key == "scan" else 0, *[s[key] for s in states])
+                for key in states[0]}
     if _use_scan(cfg):
         return mapper(1, *states)
     if _use_period_scan(cfg):
@@ -269,18 +387,25 @@ def stack_state_map(cfg: ModelConfig, fn, *states):
 
 def pages_only(state) -> bool:
     """True when the positions ``pos`` are a decode state's only per-slot
-    leaf: every other leaf is a paged page pool (``pk``/``pv``), shared by
-    all slots.  Only then can a program step a subset of the slots without
-    carrying the rest of their state along."""
+    leaf: every other leaf is a paged page pool (``POOL_LEAVES``), shared
+    by all slots.  Only then can a program step a subset of the slots
+    without carrying the rest of their state along."""
     leaves = jax.tree_util.tree_flatten_with_path(
         {k: v for k, v in state.items() if k != "pos"})[0]
-    return all(getattr(path[-1], "key", None) in ("pk", "pv")
+    return all(getattr(path[-1], "key", None) in POOL_LEAVES
                for path, _ in leaves)
 
 
 # ---------------------------------------------------------------------------
 # Whole decoder stack
 # ---------------------------------------------------------------------------
+
+
+def _use_lead_scan(cfg: ModelConfig) -> bool:
+    """Leading dense layers (``layer_<i>``, applied alone), then a scan over
+    the expert layers (``scan``): their FFNs differ, so one scan cannot
+    hold both."""
+    return cfg.is_moe and cfg.first_k_dense > 0
 
 
 def _use_scan(cfg: ModelConfig) -> bool:
@@ -301,9 +426,24 @@ def _period_split(cfg: ModelConfig):
     return cfg.n_layers // p, cfg.n_layers % p  # (n_groups, remainder)
 
 
+def _lead_kind(cfg: ModelConfig) -> str:
+    if not cfg.uniform_pattern() or cfg.n_layers <= cfg.first_k_dense:
+        raise ValueError(f"{cfg.name}: leading dense layers need a uniform "
+                         "pattern and expert layers after them")
+    return cfg.block_pattern[0]
+
+
 def stack_init(key, cfg: ModelConfig):
     dtype = dtype_of(cfg.dtype)
     keys = jax.random.split(key, cfg.n_layers)
+    if _use_lead_scan(cfg):
+        kind, k = _lead_kind(cfg), cfg.first_k_dense
+        params = {f"layer_{i}": layer_init(keys[i], cfg, kind, dtype,
+                                           dense_ffn=True)
+                  for i in range(k)}
+        params["scan"] = jax.vmap(
+            lambda kk: layer_init(kk, cfg, kind, dtype))(keys[k:])
+        return params
     if _use_scan(cfg):
         kind = cfg.block_pattern[0]
         return jax.vmap(lambda k: layer_init(k, cfg, kind, dtype))(keys)
@@ -331,6 +471,14 @@ def stack_init(key, cfg: ModelConfig):
 
 def stack_init_state(cfg: ModelConfig, batch: int, max_len: int, paging=None):
     dtype = dtype_of(cfg.dtype)
+    if _use_lead_scan(cfg):
+        kind, k = _lead_kind(cfg), cfg.first_k_dense
+        one = layer_init_state(cfg, kind, batch, max_len, dtype, paging)
+        state = {f"layer_{i}": one for i in range(k)}
+        state["scan"] = jax.tree.map(
+            lambda x: jnp.broadcast_to(x[None], (cfg.n_layers - k,) + x.shape),
+            one)
+        return state
     if _use_scan(cfg):
         kind = cfg.block_pattern[0]
         one = layer_init_state(cfg, kind, batch, max_len, dtype, paging)
@@ -362,36 +510,80 @@ def stack_init_state(cfg: ModelConfig, batch: int, max_len: int, paging=None):
     }
 
 
+def _scan_layers(layers, states, cfg: ModelConfig, kind: str, x, aux,
+                 positions, *, cache_pos, ctx, remat: bool, block_tables,
+                 token_mask):
+    """``jax.lax.scan`` of ``layer_apply`` over stacked layer params (and
+    decode states).  Returns (x, new_states or None, aux)."""
+    decode = states is not None
+
+    def body(carry, xs):
+        h, aux = carry
+        if decode:
+            lp, st = xs
+        else:
+            lp, st = xs, None
+        # block_tables is closed over: a scan constant, identical for
+        # every layer (block ids are shared across the stack)
+        h, new_st, a = layer_apply(
+            lp, cfg, kind, h, positions, state=st, cache_pos=cache_pos,
+            ctx=ctx, block_tables=block_tables, token_mask=token_mask
+        )
+        return (h, _aux_add(aux, a)), new_st
+
+    if remat and not decode:
+        body = jax.checkpoint(body, prevent_cse=False)
+    xs = (layers, states) if decode else layers
+    (x, aux), new_states = jax.lax.scan(body, (x, aux), xs)
+    return x, (new_states if decode else None), aux
+
+
 def stack_apply(
     layers, cfg: ModelConfig, x, positions, *,
     states=None, cache_pos=None, ctx=None, remat: bool = True,
-    block_tables=None,
+    block_tables=None, token_mask=None,
 ):
-    """Run all layers. Returns (x, new_states, aux_total)."""
+    """Run all layers. Returns (x, new_states, aux_total).  ``token_mask``
+    (B, S) marks the tokens the expert layers count (all if None)."""
     decode = states is not None
 
-    if _use_scan(cfg):
-        kind = cfg.block_pattern[0]
-
-        def body(carry, xs):
-            h, aux = carry
-            if decode:
-                lp, st = xs
-            else:
-                lp, st = xs, None
-            # block_tables is closed over: a scan constant, identical for
-            # every layer (block ids are shared across the stack)
-            h, new_st, a = layer_apply(
-                lp, cfg, kind, h, positions, state=st, cache_pos=cache_pos,
-                ctx=ctx, block_tables=block_tables
+    if _use_lead_scan(cfg):
+        kind = _lead_kind(cfg)
+        aux = _aux_zero(cfg)
+        new_states = {} if decode else None
+        for i in range(cfg.first_k_dense):
+            fn = functools.partial(
+                layer_apply, cfg=cfg, kind=kind, cache_pos=cache_pos,
+                ctx=ctx, block_tables=block_tables, dense_ffn=True,
+                token_mask=token_mask,
             )
-            return (h, aux + a), new_st
+            lp = layers[f"layer_{i}"]
+            if remat and not decode:
+                x, _, a = jax.checkpoint(
+                    lambda lp, h, pos, f=fn: f(lp, x=h, positions=pos,
+                                               state=None),
+                    prevent_cse=False,
+                )(lp, x, positions)
+            else:
+                x, new_st, a = fn(lp, x=x, positions=positions,
+                                  state=states[f"layer_{i}"] if decode
+                                  else None)
+                if decode:
+                    new_states[f"layer_{i}"] = new_st
+            aux = _aux_add(aux, a)
+        x, scan_states, aux = _scan_layers(
+            layers["scan"], states["scan"] if decode else None, cfg, kind, x,
+            aux, positions, cache_pos=cache_pos, ctx=ctx, remat=remat,
+            block_tables=block_tables, token_mask=token_mask)
+        if decode:
+            new_states["scan"] = scan_states
+        return x, new_states, aux
 
-        if remat and not decode:
-            body = jax.checkpoint(body, prevent_cse=False)
-        xs = (layers, states) if decode else layers
-        (x, aux), new_states = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)), xs)
-        return x, (new_states if decode else None), aux
+    if _use_scan(cfg):
+        return _scan_layers(
+            layers, states, cfg, cfg.block_pattern[0], x, _aux_zero(cfg),
+            positions, cache_pos=cache_pos, ctx=ctx, remat=remat,
+            block_tables=block_tables, token_mask=token_mask)
 
     if _use_period_scan(cfg):
         p = len(cfg.block_pattern)
@@ -409,9 +601,9 @@ def stack_apply(
                 h, new_st, a = layer_apply(
                     lps[str(pos)], cfg, cfg.block_pattern[pos], h, positions,
                     state=st, cache_pos=cache_pos, ctx=ctx,
-                    block_tables=block_tables,
+                    block_tables=block_tables, token_mask=token_mask,
                 )
-                aux = aux + a
+                aux = _aux_add(aux, a)
                 if decode:
                     new_sts[str(pos)] = new_st
             return (h, aux), (new_sts if decode else None)
@@ -421,7 +613,7 @@ def stack_apply(
             body = jax.checkpoint(period_body, prevent_cse=False)
         xs = (layers["groups"], states["groups"]) if decode else layers["groups"]
         (x, aux), new_group_states = jax.lax.scan(
-            body, (x, jnp.zeros((), jnp.float32)), xs
+            body, (x, _aux_zero(cfg)), xs
         )
         new_states = {"groups": new_group_states} if decode else None
         for j in range(rest):
@@ -430,6 +622,7 @@ def stack_apply(
             fn = functools.partial(
                 layer_apply, cfg=cfg, kind=cfg.block_kind(i),
                 cache_pos=cache_pos, ctx=ctx, block_tables=block_tables,
+                token_mask=token_mask,
             )
             if remat and not decode:
                 x, _, a = jax.checkpoint(
@@ -440,10 +633,10 @@ def stack_apply(
                 x, new_st, a = fn(layers[f"rest_{j}"], x=x, positions=positions, state=st)
                 if decode:
                     new_states[f"rest_{j}"] = new_st
-            aux = aux + a
+            aux = _aux_add(aux, a)
         return x, new_states, aux
 
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _aux_zero(cfg)
     new_states = {} if decode else None
     for i in range(cfg.n_layers):
         lp = layers[f"layer_{i}"]
@@ -451,6 +644,7 @@ def stack_apply(
         fn = functools.partial(
             layer_apply, cfg=cfg, kind=cfg.block_kind(i),
             cache_pos=cache_pos, ctx=ctx, block_tables=block_tables,
+            token_mask=token_mask,
         )
         if remat and not decode:
             fn = jax.checkpoint(
@@ -462,5 +656,5 @@ def stack_apply(
             x, new_st, a = fn(lp, x=x, positions=positions, state=st)
             if decode:
                 new_states[f"layer_{i}"] = new_st
-        aux_total = aux_total + a
+        aux_total = _aux_add(aux_total, a)
     return x, new_states, aux_total

@@ -152,7 +152,7 @@ class ServeEngine:
         # prime the caches with ONE batched prefill program (chunk-1 scan
         # replay for families without an exact chunked decode form)
         chunk = p if supports_chunked_prefill(self.model.cfg) else 1
-        tok, state = self._prefill(
+        tok, state, *_ = self._prefill(
             self.params, state,
             jnp.asarray(_prefill_chunks(np.asarray(prompts, np.int32), chunk)),
             jnp.asarray(np.full((b,), p, np.int32)))
@@ -212,6 +212,12 @@ class ServeReport:
     prefill_padded_tokens: int = 0  # rows x padded length, per prefill
     decode_slot_steps: int = 0      # n_slots x K, per macro-step
     narrow_admissions: int = 0      # prefills on fewer rows than n_slots
+    # expert layers (models with experts; zero otherwise), summed over
+    # layers and steps of every prefill and macro-step: routed (token,
+    # expert) pairs of real tokens that land on the held experts, and held
+    # experts that at least one real token reached
+    held_assignments: int = 0
+    experts_hit: int = 0
     # streaming / front-end accounting (all zero without a token stream /
     # multi-process front end).  IPC fields are filled by Runtime.serve
     # from the ServingFrontend's counters — the engine never sees a queue.
@@ -307,6 +313,8 @@ class ServeReport:
             "prefill_padded_tokens": self.prefill_padded_tokens,
             "decode_slot_steps": self.decode_slot_steps,
             "narrow_admissions": self.narrow_admissions,
+            "held_assignments": self.held_assignments,
+            "experts_hit": self.experts_hit,
             "prefix_hit_rate": self.prefix_hit_rate,
             "cow_count": self.cow_count,
             "streamed_tokens": self.streamed_tokens,
@@ -548,6 +556,8 @@ class ContinuousServeEngine:
         self.prefill_padded_tokens = 0
         self.decode_slot_steps = 0
         self.narrow_admissions = 0
+        self.held_assignments = 0
+        self.experts_hit = 0
         self._peak_live_tokens = 0
         self._peak_blocks = 0
 
@@ -614,12 +624,14 @@ class ContinuousServeEngine:
 
     def _step(self, site: str, names, call, touched: List[Request],
               parent: Optional[Span]):
-        """One jitted step ``call() -> (out, new_state)`` and its host sync,
-        through ``_dispatch``.  Returns the host copy of ``out``, the new
-        state, and the seconds from dispatch to the end of the sync: with a
-        recorder, the start of span ``names[0]`` to the end of ``names[1]``
-        (both under ``parent``, also when a guard runs the step on a worker
-        thread), so the ledger and the spans read one clock."""
+        """One jitted step ``call() -> (out, new_state[, counts])`` and its
+        host sync, through ``_dispatch``.  Returns the host copy of ``out``,
+        the new state, and the seconds from dispatch to the end of the
+        sync: with a recorder, the start of span ``names[0]`` to the end of
+        ``names[1]`` (both under ``parent``, also when a guard runs the step
+        on a worker thread), so the ledger and the spans read one clock.
+        The expert counts a model with experts returns are added to the
+        engine's and to ``parent``'s attributes."""
         tr = self.tracer
 
         def thunk(cancel):
@@ -627,16 +639,25 @@ class ContinuousServeEngine:
             # watchdog covers the device execution, not just the dispatch
             if tr is None:
                 t0 = time.perf_counter()
-                out, state = call()
+                out, state, *counts = call()
                 out = np.asarray(out)
-                return out, state, time.perf_counter() - t0
+                counts = [np.asarray(c) for c in counts]
+                return out, state, counts, time.perf_counter() - t0
             with tr.span(names[0], parent) as d:
-                out, state = call()
+                out, state, *counts = call()
             with tr.span(names[1], parent) as y:
                 out = np.asarray(out)
-            return out, state, y.end - d.start
+                counts = [np.asarray(c) for c in counts]
+            return out, state, counts, y.end - d.start
 
-        return self._dispatch(site, thunk, touched)
+        out, state, counts, dt = self._dispatch(site, thunk, touched)
+        if counts:
+            held, hit = (int(v) for v in counts[0])
+            self.held_assignments += held
+            self.experts_hit += hit
+            if parent is not None:
+                parent.attrs.update(held_assignments=held, experts_hit=hit)
+        return out, state, dt
 
     def _publish(self, req: Request, tokens, done: bool, t: float) -> None:
         """Publish a request's newly-emitted tokens to the attached stream
@@ -1077,6 +1098,7 @@ class ContinuousServeEngine:
                            self.cow_count)
         pad0, slot0 = self.prefill_padded_tokens, self.decode_slot_steps
         narrow0 = self.narrow_admissions
+        held0, hit0 = self.held_assignments, self.experts_hit
         ev0 = tok0 = 0
         if self.stream is not None:
             ev0 = self.stream.published_events
@@ -1286,6 +1308,8 @@ class ContinuousServeEngine:
             prefill_padded_tokens=self.prefill_padded_tokens - pad0,
             decode_slot_steps=self.decode_slot_steps - slot0,
             narrow_admissions=self.narrow_admissions - narrow0,
+            held_assignments=self.held_assignments - held0,
+            experts_hit=self.experts_hit - hit0,
             streamed_tokens=(self.stream.published_tokens - tok0
                              if self.stream is not None else 0),
             stream_events=(self.stream.published_events - ev0
@@ -1322,7 +1346,7 @@ class ContinuousServeEngine:
                                 "serve/setup/warmup_macro") as sp:
                     if sp is not None:
                         sp.attrs["k"] = k
-                    emitted, self.pool.state = self._macro(k)(
+                    emitted, self.pool.state, *_ = self._macro(k)(
                         self.params, self.pool.state, idle_tok, idle_mask,
                         idle_budget, *idle_extra)
                     np.asarray(emitted)

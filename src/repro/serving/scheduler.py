@@ -204,18 +204,20 @@ class ServeScheduler:
         self.chunk_candidates = tuple(chunk_candidates)
         self.macro_candidates = tuple(macro_candidates)
         self.dtype_bytes = 4 if cfg.dtype == "float32" else 2
-        # per-token work/weight-stream constants for the analytic serve costs
-        active_params = cfg.active_param_count()
-        self.flops_per_token = 2 * active_params
-        self.weight_bytes = active_params * self.dtype_bytes
+        # per-token work/weight-stream constants for the analytic serve
+        # costs: a token's FLOPs touch its active parameters; a step
+        # streams every weight the program holds (with experts, the held
+        # ones all run for every token: the dense expert product)
+        self.flops_per_token = 2 * cfg.active_param_count()
+        self.weight_bytes = cfg.param_count() * self.dtype_bytes
         self.kv_bytes_per_slot = self._kv_bytes_per_slot(cfg, max_len)
         # per-TOKEN KV bytes across full-attention layers (the unit the
-        # paged pool allocates in; prices prefix-cache CoW page copies)
-        hd = cfg.resolved_head_dim
+        # paged pool allocates in; prices prefix-cache CoW page copies):
+        # per-head K and V, or one latent entry under MLA
         n_attn = sum(1 for i in range(cfg.n_layers)
                      if cfg.block_kind(i) == "attn")
-        self.kv_bytes_per_token = (
-            2 * n_attn * cfg.n_kv_heads * hd * self.dtype_bytes)
+        self.kv_bytes_per_token = n_attn * cfg.kv_bytes_per_token(
+            self.dtype_bytes)
 
     @staticmethod
     def _kv_bytes_per_slot(cfg: ModelConfig, max_len: int) -> int:
@@ -226,7 +228,7 @@ class ServeScheduler:
         for i in range(cfg.n_layers):
             kind = cfg.block_kind(i)
             if kind == "attn":
-                total += 2 * max_len * cfg.n_kv_heads * hd * dtype_bytes
+                total += max_len * cfg.kv_bytes_per_token(dtype_bytes)
             elif kind == "local":
                 total += 2 * cfg.window_size * cfg.n_kv_heads * hd * dtype_bytes
             elif kind == "rglru":

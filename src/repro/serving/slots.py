@@ -33,17 +33,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.model import Model
+from repro.models.transformer import POOL_LEAVES
 from repro.serving.paging import BlockPool
 
 
 def _cow_copy_fn(state, src, dst):
-    """Duplicate physical page ``src`` into ``dst`` across every pk/pv leaf
-    (jit-able: traced scalar indices, fixed shapes — admitting a partial
-    prefix-tail hit never recompiles)."""
+    """Duplicate physical page ``src`` into ``dst`` across every page-pool
+    leaf (``POOL_LEAVES``; jit-able: traced scalar indices, fixed shapes —
+    admitting a partial prefix-tail hit never recompiles)."""
     def copy_leaf(path, leaf):
-        if getattr(path[-1], "key", None) not in ("pk", "pv"):
+        key = getattr(path[-1], "key", None)
+        if key not in POOL_LEAVES:
             return leaf
-        axis = leaf.ndim - 4  # block axis: 0, or 1 under a stacked-layer lead
+        # block axis: 0, or 1 under a stacked-layer lead; a pool has
+        # (n_blocks, block_size, heads, head_dim) or, latent, (n_blocks,
+        # block_size, width)
+        axis = leaf.ndim - (3 if key == "pl" else 4)
         blk = jax.lax.dynamic_slice_in_dim(leaf, src, 1, axis)
         return jax.lax.dynamic_update_slice_in_dim(leaf, blk, dst, axis)
 

@@ -139,13 +139,17 @@ def make_decode_macro_step(model: Model, horizon: int, *, eos_id: int,
     ``active`` the live-slot mask and ``budget`` the per-slot remaining
     token allowance.  Emission semantics match the per-token host loop
     exactly: an active slot's EOS is emitted, then the slot goes quiet.
+    A model with experts (``decode_step_counted``) also returns the (2,)
+    int32 [held_assignments, experts_hit] of the live slots' tokens,
+    summed over the expert layers and the K steps.
     """
     mrope = model.cfg.pos_type == "mrope"
     k_steps = max(int(horizon), 1)
+    counted = model.decode_step_counted is not None
 
     def macro_fn(params, state, tok, active, budget, block_tables=None):
         def body(carry, _):
-            st, tk, act, bud = carry
+            st, tk, act, bud = carry[:4]
             feed = jnp.where(act, tk, jnp.int32(pad_id))[:, None]
             batch = {"tokens": feed}
             if block_tables is not None:
@@ -156,17 +160,29 @@ def make_decode_macro_step(model: Model, horizon: int, *, eos_id: int,
                     act[:, None], block_tables, 0)
             if mrope:
                 batch["positions"] = mrope_positions(feed.shape[0], 1, st["pos"])
-            logits, new_st = model.decode_step(params, st, batch, ctx)
+            if counted:
+                batch["token_mask"] = act[:, None]
+                logits, new_st, cnt = model.decode_step_counted(
+                    params, st, batch, ctx)
+            else:
+                logits, new_st = model.decode_step(params, st, batch, ctx)
             new_st = model.merge_decode_state(new_st, st, act)
             nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
             emit = jnp.where(act, nxt, jnp.int32(pad_id))
             bud = bud - act.astype(jnp.int32)
             new_act = act & (nxt != eos_id) & (bud > 0)
-            return (new_st, jnp.where(act, nxt, tk), new_act, bud), emit
+            out = (new_st, jnp.where(act, nxt, tk), new_act, bud)
+            if counted:
+                out += (carry[4] + cnt,)
+            return out, emit
 
-        (state, _, _, _), emitted = jax.lax.scan(
-            body, (state, tok, active, budget), None, length=k_steps)
-        return emitted.T, state  # (B, K)
+        init = (state, tok, active, budget)
+        if counted:
+            init += (jnp.zeros((2,), jnp.int32),)
+        final, emitted = jax.lax.scan(body, init, None, length=k_steps)
+        if counted:
+            return emitted.T, final[0], final[4]
+        return emitted.T, final[0]  # (B, K)
 
     return macro_fn
 
@@ -205,8 +221,13 @@ def make_batched_prefill(model: Model, ctx=None) -> Callable:
     starts and block tables, runs the same scan on ``R`` rows, scatters
     the new positions back (pad rows dropped) and returns ``first_tok``
     as (R,).
+
+    A model with experts (``decode_step_counted``) also returns the (2,)
+    int32 [held_assignments, experts_hit] of the real prompt tokens,
+    summed over the expert layers and the chunks.
     """
     mrope = model.cfg.pos_type == "mrope"
+    counted = model.decode_step_counted is not None
 
     def prefill_fn(params, state, chunks, lengths, starts=None,
                    block_tables=None):
@@ -234,7 +255,7 @@ def make_batched_prefill(model: Model, ctx=None) -> Callable:
                 lengths > 0, jnp.asarray(starts, jnp.int32), state["pos"])
 
         def body(carry, xs):
-            st, first = carry
+            st, first = carry[:2]
             i, tok = xs  # tok: (B, c)
             off = i * c
             valid = jnp.clip(lengths - off, 0, c)  # true tokens this chunk
@@ -245,7 +266,12 @@ def make_batched_prefill(model: Model, ctx=None) -> Callable:
                     act[:, None], block_tables, 0)
             if mrope:
                 batch["positions"] = mrope_positions(b, c, st["pos"])
-            logits, new_st = model.decode_step(params, st, batch, ctx)
+            if counted:
+                batch["token_mask"] = jnp.arange(c)[None, :] < valid[:, None]
+                logits, new_st, cnt = model.decode_step_counted(
+                    params, st, batch, ctx)
+            else:
+                logits, new_st = model.decode_step(params, st, batch, ctx)
             new_st = model.merge_decode_state(new_st, st, act)
             # decode_step advanced active rows by the full chunk width;
             # ragged rows only actually consumed ``valid`` prompt tokens
@@ -255,14 +281,22 @@ def make_batched_prefill(model: Model, ctx=None) -> Callable:
             last = jnp.take_along_axis(
                 logits, jnp.maximum(valid - 1, 0)[:, None, None], axis=1)[:, 0]
             nxt = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            return (new_st, jnp.where(done_now, nxt, first)), None
+            out = (new_st, jnp.where(done_now, nxt, first))
+            if counted:
+                out += (carry[2] + cnt,)
+            return out, None
 
-        first0 = jnp.zeros((b,), jnp.int32)
-        (state, first), _ = jax.lax.scan(
-            body, (state, first0), (jnp.arange(n_chunks), chunks))
+        init = (state, jnp.zeros((b,), jnp.int32))
+        if counted:
+            init += (jnp.zeros((2,), jnp.int32),)
+        final, _ = jax.lax.scan(
+            body, init, (jnp.arange(n_chunks), chunks))
+        state, first = final[:2]
         if rows is not None:
             state = {**state, "pos": pool_pos.at[rows].set(
                 state["pos"], mode="drop")}
+        if counted:
+            return first, state, final[2]
         return first, state
 
     return prefill_fn

@@ -171,7 +171,7 @@ def test_moe_dense_topk_weights_sum_to_one(rng):
     d, f, e = 8, 16, 4
     params = ffn_lib.moe_init(rng, d, f, e, "swiglu")
     x = jax.random.normal(rng, (2, 6, d))
-    y, aux = ffn_lib.moe_dense(params, x, top_k=2, activation="swiglu")
+    y, aux, _ = ffn_lib.moe_apply(params, x, top_k=2, activation="swiglu")
     assert y.shape == x.shape
     assert np.isfinite(np.asarray(y)).all()
     assert float(aux) > 0.0
@@ -182,7 +182,7 @@ def test_moe_topk1_equals_best_expert(rng):
     d, f, e = 4, 8, 3
     params = ffn_lib.moe_init(rng, d, f, e, "swiglu")
     x = jax.random.normal(rng, (1, 5, d))
-    y, _ = ffn_lib.moe_dense(params, x, top_k=1, activation="swiglu")
+    y, _, _ = ffn_lib.moe_apply(params, x, top_k=1, activation="swiglu")
     t = x.reshape(-1, d)
     logits = t @ params["router"]
     ids = np.asarray(jnp.argmax(logits, -1))

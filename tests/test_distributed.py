@@ -97,7 +97,7 @@ def test_moe_ep_matches_dense_oracle():
         d, f, e, topk = 32, 64, 8, 2
         params = ffn_lib.moe_init(jax.random.PRNGKey(1), d, f, e, "swiglu")
         x = jax.random.normal(jax.random.PRNGKey(2), (4, 16, d))
-        ref, aux_ref = ffn_lib.moe_dense(params, x, top_k=topk, activation="swiglu")
+        ref, aux_ref, _ = ffn_lib.moe_apply(params, x, top_k=topk, activation="swiglu")
         y, aux = ffn_lib.moe_ep(params, x, top_k=topk, activation="swiglu",
                                 mesh=mesh, data_axes=("data",),
                                 capacity_factor=8.0)  # no drops

@@ -74,6 +74,7 @@ def _run_continuous(model, params, prompts, max_new, *, n_slots, **kw):
     "qwen2-vl-72b",         # mrope positions through the shared helper
     "rwkv6-3b",             # recurrent -> chunk-1 replay fallback
     "recurrentgemma-2b",    # hybrid local ring buffer -> replay fallback
+    "moonshot-v1-16b-a3b",  # latent attention, leading dense layer, experts
 ])
 def test_continuous_matches_static(arch):
     cfg, model, params = _build(arch)

@@ -223,12 +223,14 @@ def test_prefill_chunk_never_pads_past_max_len():
 
 
 @pytest.mark.parametrize("arch", ["tinyllama-1.1b", "qwen2-vl-72b",
-                                  "rwkv6-3b", "recurrentgemma-2b"])
+                                  "rwkv6-3b", "recurrentgemma-2b",
+                                  "moonshot-v1-16b-a3b"])
 def test_paged_matches_static_token_identical(arch):
     """Paged continuous serve (pages smaller than a prompt, 6 requests
     turning over 2 slots) must reproduce the dense static baseline exactly.
     Attention-free state (rwkv/window rings) stays per-slot dense — those
-    families exercise the mixed paged/dense state tree."""
+    families exercise the mixed paged/dense state tree; latent attention
+    pages one latent entry per token (the ``pl`` pool)."""
     cfg, model, params = _build(arch)
     prompts = _prompts(cfg, 6)
     rt = Runtime()
@@ -251,7 +253,8 @@ def test_paged_matches_static_token_identical(arch):
     # prefix reuse only arms on all-attention stacks; paged storage itself
     # works everywhere decoder-only
     assert engine.prefix_cache == (arch in ("tinyllama-1.1b",
-                                            "qwen2-vl-72b"))
+                                            "qwen2-vl-72b",
+                                            "moonshot-v1-16b-a3b"))
     # every slot released; only trie-resident pages may stay allocated
     assert engine.pool.free_count == engine.pool.n_slots
     assert engine.pool.blocks.used_blocks == engine.pool.blocks.trie_blocks
@@ -502,6 +505,36 @@ def test_narrow_prefill_matches_every_slot_prefill(case):
         assert rep.prefix_hit_tokens == wide.prefix_hit_tokens > 0
     if case == "preempted":
         assert rep.preemptions == wide.preemptions >= 1
+
+
+def test_latent_pool_prefix_hit_and_narrow_prefill():
+    """Latent attention's one-entry-per-token pool (``pl``): narrow
+    prefills and radix prefix hits (full pages pinned, the partial tail
+    copied on write) serve the tokens a prefill over every slot without
+    prefix reuse serves, and the expert counts reach the report and the
+    admission spans."""
+    cfg, model, params = _build("moonshot-v1-16b-a3b")
+    # the first prompt publishes two full pages; the later two share its
+    # first page and half of its second
+    shape = [(9, 0.0, 0), (7, 1.0, 0), (7, 1.0, 0)]
+    runs = {}
+    for name, kw in (("reuse", {"prefix_cache": "force"}),
+                     ("wide", {"prefix_cache": False})):
+        engine = _engine(model, params, n_slots=8, eos_id=-1, paged=True,
+                         block_size=BLOCK, kv_blocks=64, max_len=20, **kw)
+        if name == "wide":
+            engine._row_buckets = [8]
+        reqs = _trace(cfg, shape)
+        for r in reqs[1:]:
+            r.prompt[:6] = reqs[0].prompt[:6]
+        runs[name] = _admissions(engine, reqs, lambda: 0.0)
+    (rep, adm), (wide, _) = runs["reuse"], runs["wide"]
+    assert _tokens(rep) == _tokens(wide)
+    assert [(g, r) for g, r, _ in adm] == [(1, 1), (2, 2)]
+    assert rep.prefix_hit_tokens > 0 and rep.cow_count > 0
+    assert wide.prefix_hit_tokens == 0
+    assert rep.held_assignments > 0 and rep.experts_hit > 0
+    assert wide.held_assignments > rep.held_assignments  # fewer prefilled
 
 
 def test_narrow_prefill_leaves_other_slots_alone():

@@ -155,3 +155,45 @@ def test_phi3_serve_program_compiles_and_fits(one_chip, program):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
     assert used <= V5E.hbm_bytes, used
+
+
+@pytest.mark.parametrize("program", ["prefill", "macro_step"])
+def test_moonlight_serve_program_compiles_for_one_chip(one_chip, program):
+    """Moonlight-16B-A3B at its published widths, holding 8 of its 64
+    experts (one chip of an 8-chip expert group): the paged prefill over
+    16 slots and the decode macro-step over the latent pool at max_len
+    4096 compile for one chip; the compiler refuses a program that does
+    not fit its HBM."""
+    import dataclasses
+
+    from repro.models import build_model
+    from repro.serving.paging import default_kv_blocks
+    from repro.training.step import (make_batched_prefill,
+                                     make_decode_macro_step)
+
+    cfg = dataclasses.replace(get_config("moonshot-v1-16b-a3b"),
+                              held_expert_count=8)
+    model = build_model(cfg)
+    slots, max_len, page = 16, 4096, 16
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    state = on_chip(jax.eval_shape(lambda: model.init_decode_state(
+        slots, max_len, per_slot=True,
+        paging=(default_kv_blocks(slots, max_len, page), page))))
+    assert {leaf.shape[-1] for leaf in jax.tree.leaves(state["layers"])} \
+        == {576}  # one latent entry per token and layer, no head axis
+    ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+    tables = ints(slots, max_len // page)
+    if program == "prefill":
+        fn = make_batched_prefill(model)
+        args = (ints(12, slots, 256), ints(slots), ints(slots), tables)
+    else:
+        fn = make_decode_macro_step(model, 32, eos_id=-1, pad_id=0)
+        args = (ints(slots), jax.ShapeDtypeStruct((slots,), bool,
+                                                  sharding=one_chip),
+                ints(slots), tables)
+    jax.jit(fn, donate_argnums=(1,)).lower(params, state, *args).compile()
