@@ -255,10 +255,12 @@ def update_kv_cache(k_cache, v_cache, k_new, v_new, pos):
 # ---------------------------------------------------------------------------
 
 
-def _paged_rows(pos, block_tables, b: int, sq: int, block_size: int):
-    """Flat pool rows (B * S_new,) of the logical positions ``pos + i``:
-    block ``pos // block_size`` of each row's table, or block 0 (the null
-    page) past the table's end."""
+def _paged_rows(pos, block_tables, b: int, sq: int, block_size: int,
+                layer=None):
+    """Pool addresses of the logical positions ``pos + i``: a tuple of
+    (B * S_new,) page and in-page indices, led by ``layer`` when the pool
+    is stacked over layers.  The page is block ``pos // block_size`` of
+    each row's table, or block 0 (the null page) past the table's end."""
     max_blocks = block_tables.shape[1]
     pos = jnp.reshape(jnp.asarray(pos), (-1,))  # () or (B,) -> (1,) or (B,)
     pos = jnp.broadcast_to(pos, (b,))
@@ -267,47 +269,53 @@ def _paged_rows(pos, block_tables, b: int, sq: int, block_size: int):
     bidx = jnp.clip(logical // block_size, 0, max_blocks - 1)
     table = jnp.take_along_axis(block_tables, bidx, axis=1)  # (B, S_new)
     table = jnp.where(valid, table, 0)  # out-of-range -> null block
-    phys = table * block_size + logical % block_size  # (B, S_new) flat rows
-    return phys.reshape(-1)
+    rows = (table.reshape(-1), (logical % block_size).reshape(-1))
+    return rows if layer is None else (layer,) + rows
+
+
+def _pages(block_tables, layer=None):
+    """Index of each slot's pages (B, max_blocks) in a pool, at ``layer``
+    of a stacked one."""
+    return block_tables if layer is None else (layer, block_tables)
 
 
 def paged_update_kv_cache(pk, pv, k_new, v_new, pos, block_tables,
-                          block_size: int):
+                          block_size: int, layer=None):
     """Scatter (B, S_new, Hkv, hd) new KV into the shared page pool.
 
     ``pk``/``pv`` are (n_blocks, block_size, Hkv, hd) pools shared by all
-    slots; ``block_tables`` is (B, max_blocks) int32 mapping each slot's
-    logical block index to a physical page; ``pos`` is () or (B,) logical
-    write offsets.  Writes at logical positions past the table (or rows
-    whose table entry is unallocated) land in block 0 — the reserved
-    null/garbage page — so masked-off slots and clamped indices can never
-    corrupt live pages.  Token identity then rests on the attention length
-    limit: garbage is only ever at positions >= a slot's valid length,
-    where the mask zeroes it exactly (exp(NEG_INF) == 0.0 in f32)."""
-    b = k_new.shape[0]
-    sq = k_new.shape[1]
-    flat = _paged_rows(pos, block_tables, b, sq, block_size)
+    slots, or (L, n_blocks, block_size, Hkv, hd) pools stacked over layers,
+    of which ``layer`` is written: only the B * S_new new rows, in place,
+    never the layer's whole slice.  ``block_tables`` is (B, max_blocks)
+    int32 mapping each slot's logical block index to a physical page;
+    ``pos`` is () or (B,) logical write offsets.  Writes at logical
+    positions past the table (or rows whose table entry is unallocated)
+    land in block 0 — the reserved null/garbage page — so masked-off slots
+    and clamped indices can never corrupt live pages.  Token identity then
+    rests on the attention length limit: garbage is only ever at positions
+    >= a slot's valid length, where the mask zeroes it exactly
+    (exp(NEG_INF) == 0.0 in f32)."""
+    b, sq = k_new.shape[:2]
+    at = _paged_rows(pos, block_tables, b, sq, block_size, layer)
     nk = k_new.astype(pk.dtype).reshape((b * sq,) + k_new.shape[2:])
     nv = v_new.astype(pv.dtype).reshape((b * sq,) + v_new.shape[2:])
-    shape = pk.shape
-    pk = pk.reshape((-1,) + shape[2:]).at[flat].set(nk).reshape(shape)
-    pv = pv.reshape((-1,) + shape[2:]).at[flat].set(nv).reshape(shape)
-    return pk, pv
+    return pk.at[at].set(nk), pv.at[at].set(nv)
 
 
-def paged_gather_kv(pk, pv, block_tables, block_size: int):
-    """Gather each slot's logical KV view from the page pool:
-    (n_blocks, bs, Hkv, hd) x (B, max_blocks) -> (B, max_blocks*bs, Hkv, hd).
+def paged_gather_kv(pk, pv, block_tables, block_size: int, layer=None):
+    """Gather each slot's logical KV view from the page pool (at ``layer``
+    of a stacked one): (B, max_blocks) pages -> (B, max_blocks*bs, Hkv, hd).
 
     The result feeds the existing ``decode_attention`` unchanged — its
     length limit masks every position past the slot's fill level, so
     whatever stale/null data the unwritten page tails hold contributes
     exactly zero probability mass."""
     b, max_blocks = block_tables.shape
-    kc = pk[block_tables]  # (B, max_blocks, bs, Hkv, hd)
-    vc = pv[block_tables]
-    kc = kc.reshape((b, max_blocks * block_size) + pk.shape[2:])
-    vc = vc.reshape((b, max_blocks * block_size) + pv.shape[2:])
+    at = _pages(block_tables, layer)
+    kc = pk[at]  # (B, max_blocks, bs, Hkv, hd)
+    vc = pv[at]
+    kc = kc.reshape((b, max_blocks * block_size) + pk.shape[-2:])
+    vc = vc.reshape((b, max_blocks * block_size) + pv.shape[-2:])
     return kc, vc
 
 
@@ -386,22 +394,38 @@ def update_latent_cache(cache, new, pos):
         c, n, (p, 0)))(cache, new, pos)
 
 
-def paged_update_latent(pool, new, pos, block_tables, block_size: int):
+LANES = 128
+
+
+def latent_pool_width(width: int) -> int:
+    """Stored width of a latent pool row: ``width`` rounded up to whole
+    128-lane tiles.  On a TPU an array whose last axis is no such multiple
+    (MLA's 512 + 64 = 576) takes a compact layout at a program's boundary
+    and the tiled one inside it, so every program that took the pool would
+    copy the whole of it into the other layout on entry and back on exit."""
+    return -(-width // LANES) * LANES
+
+
+def paged_update_latent(pool, new, pos, block_tables, block_size: int,
+                        layer=None):
     """Scatter (B, S_new, W) latent entries into a (n_blocks, block_size,
-    W) pool, with the addressing (null page included) of
-    ``paged_update_kv_cache``."""
-    b, sq = new.shape[:2]
-    flat = _paged_rows(pos, block_tables, b, sq, block_size)
-    shape = pool.shape
-    rows = new.astype(pool.dtype).reshape(b * sq, shape[-1])
-    return pool.reshape(-1, shape[-1]).at[flat].set(rows).reshape(shape)
+    ``latent_pool_width(W)``) pool, or into ``layer`` of one stacked over
+    layers, with the addressing (null page included) of
+    ``paged_update_kv_cache``; the pad columns are written with zeros."""
+    b, sq, w = new.shape
+    at = _paged_rows(pos, block_tables, b, sq, block_size, layer)
+    rows = jnp.pad(new.astype(pool.dtype).reshape(b * sq, w),
+                   ((0, 0), (0, pool.shape[-1] - w)))
+    return pool.at[at].set(rows)
 
 
-def paged_gather_latent(pool, block_tables, block_size: int):
-    """Each slot's logical latent view (B, max_blocks * block_size, W)."""
+def paged_gather_latent(pool, block_tables, block_size: int, width: int,
+                        layer=None):
+    """Each slot's logical latent view (B, max_blocks * block_size,
+    ``width``), the pool's pad columns left out."""
     b, max_blocks = block_tables.shape
-    return pool[block_tables].reshape(b, max_blocks * block_size,
-                                      pool.shape[-1])
+    view = pool[_pages(block_tables, layer)]
+    return view.reshape(b, max_blocks * block_size, -1)[..., :width]
 
 
 # ---------------------------------------------------------------------------

@@ -38,6 +38,12 @@ from repro.models.common import (
 # axis): per-head K and V, or one latent entry per token (MLA)
 POOL_LEAVES = ("pk", "pv", "pl")
 
+
+def _is_pool(path) -> bool:
+    """Whether a decode-state leaf at ``path`` is a paged pool."""
+    return getattr(path[-1], "key", None) in POOL_LEAVES
+
+
 # ---------------------------------------------------------------------------
 # Per-layer init
 # ---------------------------------------------------------------------------
@@ -106,9 +112,10 @@ def layer_init(key, cfg: ModelConfig, kind: str, dtype,
 def _attn_apply(
     params, cfg: ModelConfig, x, positions, *, window: int,
     cache=None, cache_pos=None, ctx=None, causal: bool = True,
-    block_tables=None,
+    block_tables=None, layer=None,
 ):
-    """Returns (out, new_cache)."""
+    """Returns (out, new_cache).  ``layer``: the index of this layer in a
+    paged pool stacked over layers (None when the pool is its own)."""
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
     q = (x @ params["wq"].reshape(d, -1)).reshape(b, s, cfg.n_heads, hd)
@@ -136,10 +143,11 @@ def _attn_apply(
             # paged: scatter into the shared page pool by block table, then
             # gather this batch's logical view; the length limit inside
             # decode_attention masks every unwritten/garbage position
-            bs = cache["pk"].shape[1]
+            bs = cache["pk"].shape[-3]
             pk, pv = attn_lib.paged_update_kv_cache(
-                cache["pk"], cache["pv"], k, v, cache_pos, block_tables, bs)
-            kc, vc = attn_lib.paged_gather_kv(pk, pv, block_tables, bs)
+                cache["pk"], cache["pv"], k, v, cache_pos, block_tables, bs,
+                layer)
+            kc, vc = attn_lib.paged_gather_kv(pk, pv, block_tables, bs, layer)
             out = attn_lib.decode_attention(q, kc, vc, cache_pos + s)
             new_cache = {"pk": pk, "pv": pv}
         else:
@@ -167,7 +175,7 @@ def _attn_apply(
 
 
 def _mla_apply(params, cfg: ModelConfig, x, positions, *, cache=None,
-               cache_pos=None, ctx=None, block_tables=None):
+               cache_pos=None, ctx=None, block_tables=None, layer=None):
     """Latent attention (scope ``mla``).  Returns (out, new_cache).
 
     Queries are ``wq`` (d -> H x (nope + rope)); ``wkv_a`` (d -> r + rope)
@@ -177,7 +185,8 @@ def _mla_apply(params, cfg: ModelConfig, x, positions, *, cache=None,
     expands the latent to per-head K and V through ``wkv_b``; with one,
     each token writes one (r + rope) entry per layer (``lat`` per slot, or
     the paged pool ``pl``) and attention runs in the absorbed form against
-    the cached latents (``attention.mla_absorbed_scores``)."""
+    the cached latents (``attention.mla_absorbed_scores``).  ``layer`` as
+    in ``_attn_apply``."""
     b, s, d = x.shape
     h, r = cfg.n_heads, cfg.kv_lora_rank
     nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -201,10 +210,11 @@ def _mla_apply(params, cfg: ModelConfig, x, positions, *, cache=None,
         else:
             entry = jnp.concatenate([latent, k_rope], -1)
             if "pl" in cache:
-                bs = cache["pl"].shape[1]
+                bs = cache["pl"].shape[-2]
                 pool = attn_lib.paged_update_latent(
-                    cache["pl"], entry, cache_pos, block_tables, bs)
-                view = attn_lib.paged_gather_latent(pool, block_tables, bs)
+                    cache["pl"], entry, cache_pos, block_tables, bs, layer)
+                view = attn_lib.paged_gather_latent(
+                    pool, block_tables, bs, entry.shape[-1], layer)
                 new_cache = {"pl": pool}
             else:
                 view = attn_lib.update_latent_cache(cache["lat"], entry,
@@ -247,23 +257,25 @@ def _ffn_apply(params, cfg: ModelConfig, x, ctx, dense: bool = False,
 def layer_apply(
     params, cfg: ModelConfig, kind: str, x, positions, *,
     state=None, cache_pos=None, ctx=None, block_tables=None,
-    dense_ffn: bool = False, token_mask=None,
+    dense_ffn: bool = False, token_mask=None, layer=None,
 ) -> Tuple[jax.Array, Any, Any]:
     """Pre-norm residual block. Returns (x, new_state, aux).
     ``dense_ffn``: a leading dense layer of a MoE stack; ``token_mask``
-    (B, S) marks the tokens an expert layer counts."""
+    (B, S) marks the tokens an expert layer counts; ``layer`` indexes a
+    paged pool in ``state`` that is stacked over layers (``_layer_scan``)."""
     aux = _aux_zero(cfg)
     h = rmsnorm(params["ln1"], x, cfg.norm_eps)
     if kind == "attn" and cfg.is_mla:
         out, new_mix_state = _mla_apply(
             params["attn"], cfg, h, positions, cache=state,
-            cache_pos=cache_pos, ctx=ctx, block_tables=block_tables)
+            cache_pos=cache_pos, ctx=ctx, block_tables=block_tables,
+            layer=layer)
     elif kind in ("attn", "local"):
         window = cfg.window_size if kind == "local" else 0
         out, new_mix_state = _attn_apply(
             params["attn"], cfg, h, positions, window=window,
             cache=state, cache_pos=cache_pos, ctx=ctx,
-            block_tables=block_tables,
+            block_tables=block_tables, layer=layer,
         )
     elif kind == "rglru":
         out, new_mix_state = rglru_lib.rglru_apply(params["rglru"], h, state)
@@ -311,11 +323,12 @@ def layer_init_state(cfg: ModelConfig, kind: str, batch: int, max_len: int, dtyp
     already O(window) / O(1) per slot)."""
     hd = cfg.resolved_head_dim
     if kind == "attn" and cfg.is_mla:
-        # one latent entry per token and layer, no head axis
+        # one latent entry per token and layer, no head axis; a pool row
+        # is padded to whole lane tiles (attention.latent_pool_width)
         if paging is not None:
             n_blocks, block_size = paging
-            return {"pl": jnp.zeros((n_blocks, block_size, cfg.latent_width),
-                                    dtype)}
+            width = attn_lib.latent_pool_width(cfg.latent_width)
+            return {"pl": jnp.zeros((n_blocks, block_size, width), dtype)}
         return {"lat": jnp.zeros((batch, max_len, cfg.latent_width), dtype)}
     if kind == "attn":
         if paging is not None:
@@ -366,9 +379,7 @@ def stack_state_map(cfg: ModelConfig, fn, *states):
     """
     def mapper(axis, *trees):
         return jax.tree_util.tree_map_with_path(
-            lambda path, *ls: (
-                ls[0] if getattr(path[-1], "key", None) in POOL_LEAVES
-                else fn(axis, *ls)),
+            lambda path, *ls: ls[0] if _is_pool(path) else fn(axis, *ls),
             *trees)
 
     if _use_lead_scan(cfg):
@@ -392,8 +403,7 @@ def pages_only(state) -> bool:
     without carrying the rest of their state along."""
     leaves = jax.tree_util.tree_flatten_with_path(
         {k: v for k, v in state.items() if k != "pos"})[0]
-    return all(getattr(path[-1], "key", None) in POOL_LEAVES
-               for path, _ in leaves)
+    return all(_is_pool(path) for path, _ in leaves)
 
 
 # ---------------------------------------------------------------------------
@@ -510,32 +520,79 @@ def stack_init_state(cfg: ModelConfig, batch: int, max_len: int, paging=None):
     }
 
 
+def _split_pools(states):
+    """(pool leaves, other leaves, how to join them) of a decode-state
+    tree; the pools are its ``POOL_LEAVES``."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(states)
+    marks = tuple(_is_pool(path) for path, _ in flat)
+    pools = [leaf for (_, leaf), m in zip(flat, marks) if m]
+    rest = [leaf for (_, leaf), m in zip(flat, marks) if not m]
+    return pools, rest, (treedef, marks)
+
+
+def _join_pools(pools, rest, how):
+    treedef, marks = how
+    pools, rest = iter(pools), iter(rest)
+    return treedef.unflatten([next(pools) if m else next(rest)
+                              for m in marks])
+
+
+def _layer_scan(step, carry, layers, states, remat: bool):
+    """``jax.lax.scan`` of ``step(carry, params, state, layer) -> (carry,
+    new_state)`` over params stacked over layers (or period groups), and
+    over their decode states unless ``states`` is None (a full forward,
+    rematerialised under ``remat``; ``state`` and ``layer`` None).
+    Returns (carry, new_states or None).
+
+    A paged pool (``POOL_LEAVES``) is stacked whole, with no slot axis.
+    Scanned as ``xs``/``ys`` it would be sliced per layer and written back
+    into a fresh stacked buffer: a copy of the whole pool per step, and
+    another where a loop carries the state around the scan.  So the pools
+    ride in the scan's carry, and each layer scatters its new rows into,
+    and gathers its pages from, index ``layer`` (the scan's) in place.
+    Every other leaf is sliced per layer as ``xs``/``ys``."""
+    if states is None:
+        def forward(c, params):
+            return step(c, params, None, None)[0], None
+
+        if remat:
+            forward = jax.checkpoint(forward, prevent_cse=False)
+        return jax.lax.scan(forward, carry, layers)[0], None
+
+    pools, rest, how = _split_pools(states)
+
+    def body(c, xs):
+        inner, pools, layer = c
+        params, rest = xs
+        inner, new_state = step(inner, params, _join_pools(pools, rest, how),
+                                layer)
+        pools, rest, _ = _split_pools(new_state)
+        return (inner, pools, layer + 1), rest
+
+    (carry, pools, _), rest = jax.lax.scan(
+        body, (carry, pools, jnp.int32(0)), (layers, rest))
+    return carry, _join_pools(pools, rest, how)
+
+
 def _scan_layers(layers, states, cfg: ModelConfig, kind: str, x, aux,
                  positions, *, cache_pos, ctx, remat: bool, block_tables,
                  token_mask):
-    """``jax.lax.scan`` of ``layer_apply`` over stacked layer params (and
-    decode states).  Returns (x, new_states or None, aux)."""
-    decode = states is not None
-
-    def body(carry, xs):
+    """``layer_apply`` over stacked layer params (and decode states), by
+    ``_layer_scan``.  Returns (x, new_states or None, aux)."""
+    def step(carry, lp, st, layer):
         h, aux = carry
-        if decode:
-            lp, st = xs
-        else:
-            lp, st = xs, None
         # block_tables is closed over: a scan constant, identical for
         # every layer (block ids are shared across the stack)
         h, new_st, a = layer_apply(
             lp, cfg, kind, h, positions, state=st, cache_pos=cache_pos,
-            ctx=ctx, block_tables=block_tables, token_mask=token_mask
+            ctx=ctx, block_tables=block_tables, token_mask=token_mask,
+            layer=layer,
         )
         return (h, _aux_add(aux, a)), new_st
 
-    if remat and not decode:
-        body = jax.checkpoint(body, prevent_cse=False)
-    xs = (layers, states) if decode else layers
-    (x, aux), new_states = jax.lax.scan(body, (x, aux), xs)
-    return x, (new_states if decode else None), aux
+    (x, aux), new_states = _layer_scan(step, (x, aux), layers, states,
+                                       remat)
+    return x, new_states, aux
 
 
 def stack_apply(
@@ -589,33 +646,23 @@ def stack_apply(
         p = len(cfg.block_pattern)
         n_groups, rest = _period_split(cfg)
 
-        def period_body(carry, xs):
+        def period_step(carry, lps, sts, group):
             h, aux = carry
-            if decode:
-                lps, sts = xs
-            else:
-                lps, sts = xs, None
             new_sts = {}
             for pos in range(p):
-                st = sts[str(pos)] if decode else None
-                h, new_st, a = layer_apply(
+                h, new_sts[str(pos)], a = layer_apply(
                     lps[str(pos)], cfg, cfg.block_pattern[pos], h, positions,
-                    state=st, cache_pos=cache_pos, ctx=ctx,
-                    block_tables=block_tables, token_mask=token_mask,
+                    state=sts[str(pos)] if decode else None,
+                    cache_pos=cache_pos, ctx=ctx, block_tables=block_tables,
+                    token_mask=token_mask, layer=group,
                 )
                 aux = _aux_add(aux, a)
-                if decode:
-                    new_sts[str(pos)] = new_st
-            return (h, aux), (new_sts if decode else None)
+            return (h, aux), new_sts
 
-        body = period_body
-        if remat and not decode:
-            body = jax.checkpoint(period_body, prevent_cse=False)
-        xs = (layers["groups"], states["groups"]) if decode else layers["groups"]
-        (x, aux), new_group_states = jax.lax.scan(
-            body, (x, _aux_zero(cfg)), xs
-        )
-        new_states = {"groups": new_group_states} if decode else None
+        (x, aux), group_states = _layer_scan(
+            period_step, (x, _aux_zero(cfg)), layers["groups"],
+            states["groups"] if decode else None, remat)
+        new_states = {"groups": group_states} if decode else None
         for j in range(rest):
             i = n_groups * p + j
             st = states[f"rest_{j}"] if decode else None

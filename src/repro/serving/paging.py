@@ -4,8 +4,9 @@ cache pages plus a radix-style prefix cache over full blocks.
 All state here is HOST-side (numpy mirrors / python dicts) — the physical
 pages live in the pooled decode state as ``pk``/``pv`` leaves of shape
 ``(n_blocks, block_size, kv_heads, head_dim)`` per attention layer (under
-latent attention, one ``pl`` leaf ``(n_blocks, block_size, kv_lora_rank +
-qk_rope_head_dim)``, no head axis), and the
+latent attention, one ``pl`` leaf ``(n_blocks, block_size, width)``, no
+head axis: ``kv_lora_rank + qk_rope_head_dim`` padded to whole 128-lane
+tiles), and the
 per-slot block tables are threaded into the jitted macro-step / prefill
 programs as plain device arrays of block indices.  Nothing in this module
 touches a device or triggers a host sync.

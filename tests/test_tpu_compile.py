@@ -17,6 +17,7 @@ chip compiles cannot be read back without one.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -162,8 +163,8 @@ def test_moonlight_serve_program_compiles_for_one_chip(one_chip, program):
     """Moonlight-16B-A3B at its published widths, holding 8 of its 64
     experts (one chip of an 8-chip expert group): the paged prefill over
     16 slots and the decode macro-step over the latent pool at max_len
-    4096 compile for one chip; the compiler refuses a program that does
-    not fit its HBM."""
+    4096 compile for one chip, with no copy of the latent pool; the
+    compiler refuses a program that does not fit its HBM."""
     import dataclasses
 
     from repro.models import build_model
@@ -184,8 +185,10 @@ def test_moonlight_serve_program_compiles_for_one_chip(one_chip, program):
     state = on_chip(jax.eval_shape(lambda: model.init_decode_state(
         slots, max_len, per_slot=True,
         paging=(default_kv_blocks(slots, max_len, page), page))))
+    # one latent entry per token and layer, no head axis; its 576 values
+    # padded to whole 128-lane tiles
     assert {leaf.shape[-1] for leaf in jax.tree.leaves(state["layers"])} \
-        == {576}  # one latent entry per token and layer, no head axis
+        == {640}
     ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
     tables = ints(slots, max_len // page)
     if program == "prefill":
@@ -196,4 +199,9 @@ def test_moonlight_serve_program_compiles_for_one_chip(one_chip, program):
         args = (ints(slots), jax.ShapeDtypeStruct((slots,), bool,
                                                   sharding=one_chip),
                 ints(slots), tables)
-    jax.jit(fn, donate_argnums=(1,)).lower(params, state, *args).compile()
+    text = jax.jit(fn, donate_argnums=(1,)).lower(
+        params, state, *args).compile().as_text()
+    # the expert layers' stacked pool is written in place and keeps one
+    # layout: no copy of it, on entry, inside the scan or on exit
+    shape = ",".join(map(str, state["layers"]["scan"]["pl"].shape))
+    assert not re.findall(rf"= \w+\[{shape}\]\S* copy\(", text)
