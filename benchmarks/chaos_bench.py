@@ -30,7 +30,7 @@ comparison, or a ratio of same-run numbers; never a wall-clock constant):
   rollback   — a harmful factor planted on a healthy site (3 rows at 4x)
                followed by accurate rows must ROLL BACK once a full
                regret window shows the correction hurting
-  serve      — dense / paged / sharded (forced-mesh subprocess) /
+  serve      — two KV page sizes / sharded (forced-mesh subprocess) /
                front-end serves with the correction loop live: all
                token-identical to the static baseline, every request
                terminal
@@ -478,9 +478,9 @@ def run(csv=True, runtime=None, smoke: bool = True,
                   eos_id=0, slots=SLOTS)
     static = rt.serve(cfg, _trace(cfg), mode="static", **common)
     runs = {
-        "dense": rt.serve(cfg, _trace(cfg), mode="continuous", **common),
-        "paged": rt.serve(cfg, _trace(cfg), mode="continuous", paged=True,
-                          block_size=4, **common),
+        "pages16": rt.serve(cfg, _trace(cfg), mode="continuous", **common),
+        "pages4": rt.serve(cfg, _trace(cfg), mode="continuous",
+                           block_size=4, **common),
         "frontend": rt.serve(cfg, _trace(cfg), mode="continuous",
                              frontend=2, stream=True, **common),
     }

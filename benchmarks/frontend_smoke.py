@@ -10,9 +10,9 @@ Three serves over the same full-load trace:
                stream=True``: request validation happens in spawned intake
                workers, token bursts are detokenized in a pinned emission
                worker, and the engine thread never blocks on either
-  paged      — the frontend again, over the paged-KV engine family
-               (block tables + copy-on-write), proving the front end is
-               engine-family agnostic
+  small pages — the frontend again, over 4-token KV pages instead of the
+               engine's default 16, proving the front end does not depend
+               on the page layout
 
 Hard checks (this suite is a gate, not a report): every run terminal and
 fully COMPLETED; both frontend runs token-identical to the in-process
@@ -118,12 +118,11 @@ def run(csv=True, runtime=None) -> None:
 
     fe = rt.serve(cfg, _trace(cfg), frontend=WORKERS, pin=True,
                   stream=True, **common)
-    _check_frontend_run(fe, base_outputs, "frontend (dense)")
+    _check_frontend_run(fe, base_outputs, "frontend (16-token pages)")
 
-    fe_paged = rt.serve(cfg, _trace(cfg), frontend=WORKERS, pin=True,
-                        stream=True, paged=True, block_size=BLOCK_SIZE,
-                        **common)
-    _check_frontend_run(fe_paged, base_outputs, "frontend (paged)")
+    fe_small = rt.serve(cfg, _trace(cfg), frontend=WORKERS, pin=True,
+                        stream=True, block_size=BLOCK_SIZE, **common)
+    _check_frontend_run(fe_small, base_outputs, "frontend (4-token pages)")
 
     # --- the eleventh cost site must have ledgered, for BOTH ops, a
     # decision row (predicted) AND an appended measured row ---
@@ -140,7 +139,7 @@ def run(csv=True, runtime=None) -> None:
                 f"serve_ipc op={op!r}: a ledger row has no positive "
                 f"predicted cost")
 
-    for label, res in (("dense", fe), ("paged", fe_paged)):
+    for label, res in (("pages16", fe), ("pages4", fe_small)):
         rep = res.report
         ttft = rep.ttft_percentiles()
         print(f"frontend_smoke,engine={label},workers={rep.frontend_workers},"

@@ -14,8 +14,8 @@
   serving_bench    — static-batch vs continuous-batching serving under a
                      staggered arrival trace (tok/s + p50/p95 latency,
                      token-equivalence anchor, site=serve ledger rows),
-                     plus sharded / paged-KV / shared-prefix full-load
-                     rows; writes the machine-readable BENCH_serving.json
+                     plus sharded and shared-prefix full-load rows;
+                     writes the machine-readable BENCH_serving.json
   stress_bench     — overload (2x Poisson) + fault-injection drills
                      (raise | nan | stall) against the request lifecycle:
                      every request terminal, transient faults retry to a

@@ -1,7 +1,8 @@
 """Serving benchmark: static-batch vs continuous batching (CPU-reduced
 config) — a thin adapter over ``Runtime.serve``.
 
-Two traces over the same request set:
+Two traces over the same request set (the continuous engine keeps its KV
+in the paged pool, at its default page size):
 
   staggered   — arrivals every GAP_MS; the latency story (continuous
                 batching wins p50/p95 because nobody waits for the batch)
@@ -16,14 +17,9 @@ the reduced config sits below the serve_shard crossover), token-checked
 against the single-device static baseline, with per-trace collective
 counts and the serve_shard ledger rows reported,
 
-plus a PAGED full-load row: the same trace with the KV cache stored as
-fixed-size pages behind per-slot block tables (block_size=4 so the
-8-token prompts span multiple pages), token-checked against the dense
-continuous run and reported as a machine-normalized paged/dense
-throughput ratio,
-
 plus a SHARED-PREFIX row: every request opens with the same 6-token
-prefix (system-prompt traffic); with the radix prefix cache pinned on
+prefix (system-prompt traffic), over 4-token pages so the 8-token prompts
+span several of them; with the radix prefix cache pinned on
 (``prefix_cache="force"`` — the reduced config sits below the
 serve_prefix crossover, so 'auto' would honestly full-prefill) only the
 first request prefills the prefix and the rest reuse its pages, cutting
@@ -64,9 +60,6 @@ from repro.runtime import Runtime, RuntimeConfig, synthetic_trace
 BENCH_JSON = "BENCH_serving.json"
 TRAJECTORY_TAG = "pr9-frontend-ipc"
 REGRESSION_FRACTION = 0.8  # fail below 80% of the committed baseline
-# the paged/dense ratio divides two ~10ms walls, so runner noise moves it
-# far more than the static-normalized ratio — wider guard, same idea
-PAGED_REGRESSION_FRACTION = 0.5
 
 ARCH = "tinyllama-1.1b"
 REQUESTS = 6
@@ -78,9 +71,9 @@ GAP_MS = 10.0
 # mesh (jax pins its device count at first init, so the parent process
 # cannot host it)
 SHARD_DEVICES = 8
-# paged rows: small pages so the 8-token prompts span several of them,
-# and a shared 6-token prefix = one full page + a 2-token copy-on-write
-# tail at block_size=4
+# shared-prefix row: small pages so the 8-token prompts span several of
+# them, and a shared 6-token prefix = one full page + a 2-token
+# copy-on-write tail at block_size=4
 BLOCK_SIZE = 4
 PREFIX_LEN = 6
 # the shared-prefix row used to serialize admission (1 slot): group
@@ -271,42 +264,13 @@ def run(csv=True, runtime=None, check_regression: bool = False) -> None:
     # single-device static baseline ---
     sharded = _sharded_row(static_out)
 
-    # --- paged full-load row: same trace, KV stored as fixed-size pages
-    # behind per-slot block tables; must be token-identical to dense ---
-    paged_fl = rt.serve(cfg, _trace(cfg, arrival="all"), mode="continuous",
-                        slots=SLOTS, paged=True, block_size=BLOCK_SIZE,
-                        **common)
-    paged_report = paged_fl.report
-    for _ in range(4):  # best-of-5: the ratio below divides two tiny walls
-        rep = paged_fl.engine.run(_trace(cfg, arrival="all"))
-        if rep.tok_per_s > paged_report.tok_per_s:
-            paged_report = rep
-    dense_best = fl_report.tok_per_s
-    for _ in range(2):  # top the dense side up to best-of-5 as well
-        rep = cont_fl.engine.run(_trace(cfg, arrival="all"))
-        dense_best = max(dense_best, rep.tok_per_s)
-    paged_out = np.stack([paged_report.output(f"r{i}", MAX_NEW)
-                          for i in range(REQUESTS)])
-    paged_identical = bool(np.array_equal(paged_out, static_out))
-    paged_row = _report_dict(paged_report)
-    paged_row.update({
-        "block_size": BLOCK_SIZE,
-        "live_tokens": paged_report.live_tokens,
-        "reserved_blocks": paged_report.reserved_blocks,
-        "token_identical": paged_identical,
-        # normalized by the dense continuous run on the same machine, so
-        # the regression gate below is robust to runner speed
-        "paged_over_dense": (paged_report.tok_per_s / dense_best
-                             if dense_best > 0 else None),
-    })
-
     # --- shared-prefix row: every request opens with the same PREFIX_LEN
     # tokens; with reuse pinned on, only the first request prefills the
     # prefix — the rest pin its pages and prefill just their suffix ---
     static_px = rt.serve(cfg, _trace(cfg, arrival="all", prefix_share=1.0),
                          mode="static", **common)
     prefix_fl = rt.serve(cfg, _trace(cfg, arrival="all", prefix_share=1.0),
-                         mode="continuous", slots=PREFIX_SLOTS, paged=True,
+                         mode="continuous", slots=PREFIX_SLOTS,
                          block_size=BLOCK_SIZE, prefix_cache="force",
                          **common)
     px_report = prefix_fl.report
@@ -353,7 +317,6 @@ def run(csv=True, runtime=None, check_regression: bool = False) -> None:
                 fl_report.tok_per_s / static_fl.tok_per_s
                 if static_fl.tok_per_s > 0 else None,
             "sharded": sharded,
-            "paged": paged_row,
         },
         "shared_prefix": prefix_row,
         "p50_speedup": (static_st.p50_s / cont_st.p50_s
@@ -372,7 +335,6 @@ def run(csv=True, runtime=None, check_regression: bool = False) -> None:
         "full_load_continuous_tok_per_s": fl_report.tok_per_s,
         "host_syncs_per_token": fl_report.host_syncs_per_token,
         "sharded_full_load_tok_per_s": sharded["tok_per_s"],
-        "paged_full_load_tok_per_s": paged_report.tok_per_s,
         "prefix_hit_rate": px_report.prefix_hit_rate,
     })
     with open(BENCH_JSON, "w") as f:
@@ -395,12 +357,6 @@ def run(csv=True, runtime=None, check_regression: bool = False) -> None:
           f"shard_rows={sharded['serve_shard_rows']},"
           f"shard_measured={sharded['serve_shard_measured']},"
           f"token_identical={sharded['token_identical']}")
-    print(f"serving_bench,trace=full_load,engine=paged,"
-          f"block_size={BLOCK_SIZE},tok_s={paged_report.tok_per_s:.1f},"
-          f"paged_over_dense={paged_row['paged_over_dense']:.2f},"
-          f"live_tokens={paged_report.live_tokens},"
-          f"blocks={paged_report.reserved_blocks},"
-          f"token_identical={paged_identical}")
     print(f"serving_bench,trace=shared_prefix,engine=paged,"
           f"prefix_len={PREFIX_LEN},"
           f"hit_tokens={px_report.prefix_hit_tokens},"
@@ -421,9 +377,6 @@ def run(csv=True, runtime=None, check_regression: bool = False) -> None:
         raise AssertionError(
             "sharded continuous engine diverged from the single-device "
             "static baseline")
-    if not paged_identical:
-        raise AssertionError(
-            "paged continuous engine diverged from the dense baseline")
     if not px_identical:
         raise AssertionError(
             "shared-prefix paged run diverged from the static baseline "
@@ -450,7 +403,7 @@ def run(csv=True, runtime=None, check_regression: bool = False) -> None:
 
 def _check_regression(previous: dict, full_load: dict,
                       shared_prefix: dict) -> None:
-    """CI smoke gate, three metrics against the committed baseline:
+    """CI smoke gate, two metrics against the committed baseline:
 
       continuous_over_static — full-load continuous throughput RELATIVE to
         the static lockstep bound on the same machine.  Normalizing by the
@@ -458,9 +411,6 @@ def _check_regression(previous: dict, full_load: dict,
         than the machine that committed the baseline slows both engines
         alike), so the gate trips on real serve-path regressions, not
         runner lottery.
-      paged_over_dense — paged continuous throughput relative to the dense
-        continuous run, machine-normalized the same way: the cost of the
-        block-table indirection must not creep.
       prefix_hit_rate — fraction of prompt tokens served from the radix
         prefix cache on the shared-prefix trace.  Deterministic for a
         fixed trace, but held to the same 80% floor so a benign change in
@@ -471,10 +421,6 @@ def _check_regression(previous: dict, full_load: dict,
         ("continuous_over_static", REGRESSION_FRACTION,
          previous.get("full_load", {}).get("continuous_over_static"),
          full_load.get("continuous_over_static")),
-        ("paged_over_dense", PAGED_REGRESSION_FRACTION,
-         previous.get("full_load", {}).get("paged", {}).get(
-             "paged_over_dense"),
-         full_load.get("paged", {}).get("paged_over_dense")),
         ("prefix_hit_rate", REGRESSION_FRACTION,
          previous.get("shared_prefix", {}).get("prefix_hit_rate"),
          shared_prefix.get("prefix_hit_rate")),
@@ -501,8 +447,8 @@ if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--check-regression", action="store_true",
                     help="fail if token equivalence breaks or any gated "
-                         "metric (continuous/static ratio, paged/dense "
-                         "ratio, prefix hit rate) drops >20%% below the "
+                         "metric (continuous/static ratio, prefix hit "
+                         "rate) drops >20%% below the "
                          f"committed {BENCH_JSON}")
     args = ap.parse_args()
     run(check_regression=args.check_regression)

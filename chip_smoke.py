@@ -60,7 +60,7 @@ SERVE_ARGS = [
     # random weights make any end-of-sequence token meaningless: with none,
     # every request must generate exactly MAX_NEW tokens
     "--eos-id", "-1",
-    "--paged", "--block-size", "16",
+    "--block-size", "16",
 ]
 MATMUL_MNK = (4096, 8192, 3072)
 SORT_KEYS = 1 << 20

@@ -12,11 +12,12 @@ Usage:
       --requests 8 --prompt-len 8 --max-new 16 --slots 4 \
       --arrival staggered --gap-ms 20 --engine both
 
-  # paged KV + shared-prefix traffic (system-prompt shape): every request
-  # opens with the same 6 tokens, prefilled once and reused from the trie
+  # shared-prefix traffic (system-prompt shape) over 4-token KV pages:
+  # every request opens with the same 6 tokens, prefilled once and reused
+  # from the trie
   PYTHONPATH=src python -m repro.launch.serve --arch tinyllama-1.1b --reduced \
       --requests 8 --prompt-len 8 --max-new 8 --slots 1 --arrival all \
-      --engine continuous --paged --block-size 4 --prefix-cache force \
+      --engine continuous --block-size 4 --prefix-cache force \
       --prefix-share 1.0 --prefix-len 6
 """
 
@@ -95,12 +96,9 @@ def run(argv=None) -> Tuple[Runtime, List[ServeResult]]:
     ap.add_argument("--watchdog-ms", type=float, default=None,
                     help="abort any single device step exceeding this "
                          "(bounded retries, then in-flight requests FAIL)")
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV: store full-attention caches in a shared "
-                         "BlockPool of fixed-size pages with per-slot block "
-                         "tables (continuous engine only)")
     ap.add_argument("--block-size", type=int, default=16,
-                    help="paged KV page size in tokens")
+                    help="KV page size in tokens of the continuous engine's "
+                         "paged pool")
     ap.add_argument("--prefix-cache", choices=("auto", "force", "off"),
                     default="auto",
                     help="radix prefix reuse at admission: 'auto' asks the "
@@ -151,9 +149,7 @@ def run(argv=None) -> Tuple[Runtime, List[ServeResult]]:
     if args.inject_fault == "stall" and args.watchdog_ms is None:
         ap.error("--inject-fault stall without --watchdog-ms would hang "
                  "the trace; pass --watchdog-ms")
-    if args.paged and args.engine == "static":
-        ap.error("--paged needs the slot pool of --engine continuous")
-    if args.paged and args.block_size < 1:
+    if args.block_size < 1:
         ap.error(f"--block-size must be >= 1, got {args.block_size}")
     if args.prefix_share:
         if not 0.0 < args.prefix_share <= 1.0:
@@ -253,7 +249,6 @@ def run(argv=None) -> Tuple[Runtime, List[ServeResult]]:
                 shard_params=args.serve_shard,
                 queue_limit=args.queue_limit, deadline_ms=args.deadline_ms,
                 inject_fault=args.inject_fault, watchdog_ms=args.watchdog_ms,
-                paged=args.paged and mode == "continuous",
                 block_size=args.block_size, prefix_cache=prefix_cache,
                 frontend=frontend if mode == "continuous" else None,
                 pin=args.pin,
@@ -286,14 +281,13 @@ def run(argv=None) -> Tuple[Runtime, List[ServeResult]]:
                   f"({res.report.narrow_admissions} admissions on fewer "
                   f"rows than slots), "
                   f"decode slot-steps {res.report.decode_slot_steps}")
-            if args.paged:
-                print(f"    paged KV: peak live tokens "
-                      f"{res.report.live_tokens}, reserved blocks "
-                      f"{res.report.reserved_blocks}, prefix hits "
-                      f"{res.report.prefix_hit_tokens} tokens "
-                      f"(rate {res.report.prefix_hit_rate:.2f}), "
-                      f"prefilled {res.report.prefilled_tokens}, "
-                      f"CoW {res.report.cow_count}")
+            print(f"    paged KV: peak live tokens "
+                  f"{res.report.live_tokens}, reserved blocks "
+                  f"{res.report.reserved_blocks}, prefix hits "
+                  f"{res.report.prefix_hit_tokens} tokens "
+                  f"(rate {res.report.prefix_hit_rate:.2f}), "
+                  f"prefilled {res.report.prefilled_tokens}, "
+                  f"CoW {res.report.cow_count}")
             if res.report.mesh_shape is not None:
                 print(f"    mesh {res.report.mesh_shape} "
                       f"({res.report.device_count} devices), "

@@ -65,12 +65,6 @@ def _select_slots(mask, axis, new, old):
     return jnp.where(mask.reshape(shape), new, old)
 
 
-def _insert_slot_leaf(axis, dst, src, slot):
-    """Copy the single slot of ``src`` (slot-dim 1) into ``dst`` at ``slot``."""
-    return jax.lax.dynamic_update_index_in_dim(
-        dst, jax.lax.index_in_dim(src, 0, axis, keepdims=False), slot, axis)
-
-
 # ---------------------------------------------------------------------------
 
 
@@ -87,7 +81,6 @@ class Model:
     # slot-indexed decode-state surgery (continuous-batching slot pool);
     # all take/return per-slot (per_slot=True) states
     reset_decode_slots: Callable[..., Any] = None  # (state, slot_mask) -> state
-    insert_decode_slot: Callable[..., Any] = None  # (state, src, slot) -> state
     merge_decode_state: Callable[..., Any] = None  # (new, old, active) -> state
     # models with experts: decode_step that also returns the (2,) int32
     # [held_assignments, experts_hit] of the tokens where the batch's
@@ -229,16 +222,6 @@ def _build_decoder_only(cfg: ModelConfig) -> Model:
             cfg, lambda ax, leaf: _zero_slots(leaf, mask, ax), state["layers"])
         return {"layers": layers, "pos": jnp.where(mask, 0, state["pos"])}
 
-    def insert_decode_slot(state, src, slot):
-        """Copy a freshly-prefilled single-slot state ``src`` (batch 1,
-        per-slot) into slot ``slot`` of a pooled state."""
-        layers = tfm.stack_state_map(
-            cfg, functools.partial(_insert_slot_leaf, slot=slot),
-            state["layers"], src["layers"])
-        pos = jax.lax.dynamic_update_index_in_dim(
-            state["pos"], src["pos"][0], slot, 0)
-        return {"layers": layers, "pos": pos}
-
     def merge_decode_state(new_state, old_state, active):
         """Per-slot select: slots where ``active`` is True take the stepped
         state, the rest stay EXACTLY frozen (positions AND layer state —
@@ -255,7 +238,6 @@ def _build_decoder_only(cfg: ModelConfig) -> Model:
         init_decode_state=init_decode_state, forward_logits=forward_logits,
         prefill=prefill, vlm_patches=functools.partial(_vlm_patches, cfg),
         reset_decode_slots=reset_decode_slots,
-        insert_decode_slot=insert_decode_slot,
         merge_decode_state=merge_decode_state,
         decode_step_counted=decode_step_counted if cfg.is_moe else None,
     )
@@ -373,16 +355,6 @@ def _build_encdec(cfg: ModelConfig) -> Model:
         x = rmsnorm(params["final_norm"], x[:, -1:], cfg.norm_eps)
         return unembed(params["unembed"], x)
 
-    def reset_decode_slots(state, slot_mask):
-        """Enc-dec decode state keeps every leaf's slot axis at 0 (including
-        per-slot ``pos``), so one uniform tree map suffices."""
-        mask = jnp.asarray(slot_mask, bool)
-        return jax.tree.map(lambda leaf: _zero_slots(leaf, mask, 0), state)
-
-    def insert_decode_slot(state, src, slot):
-        return jax.tree.map(
-            lambda dst, s: _insert_slot_leaf(0, dst, s, slot), state, src)
-
     def merge_decode_state(new_state, old_state, active):
         """Enc-dec decode state keeps every leaf's slot axis at 0, so one
         uniform per-slot select suffices."""
@@ -393,8 +365,6 @@ def _build_encdec(cfg: ModelConfig) -> Model:
     return Model(
         cfg=cfg, init=init, loss=loss, decode_step=decode_step,
         init_decode_state=init_decode_state, prefill=prefill,
-        reset_decode_slots=reset_decode_slots,
-        insert_decode_slot=insert_decode_slot,
         merge_decode_state=merge_decode_state,
     )
 

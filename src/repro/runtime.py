@@ -379,7 +379,7 @@ class Runtime:
               ttft_deadline_ms: Optional[float] = None,
               inject_fault: Optional[str] = None,
               watchdog_ms: Optional[float] = None, max_retries: int = 2,
-              paged: bool = False, block_size: int = 16,
+              paged: bool = True, block_size: int = 16,
               kv_blocks: Optional[int] = None, prefix_cache="auto",
               frontend=None, stream="auto", pin: bool = False,
               stop_event=None, now_fn=time.perf_counter,
@@ -410,13 +410,16 @@ class Runtime:
         (required for ``stall``), with up to ``max_retries`` backoff
         retries before in-flight requests FAIL.
 
-        Paged KV (continuous mode only; DESIGN.md §5): ``paged=True``
-        stores full-attention KV in a shared BlockPool of
+        Paged KV (continuous mode; DESIGN.md §5): the continuous engine
+        stores full-attention KV in one shared BlockPool of
         ``block_size``-token pages (``kv_blocks`` overrides the
         can-never-OOM default) with per-slot block tables, and
         ``prefix_cache`` controls radix prefix reuse at admission
         (``'auto'`` = the serve_prefix CostQuery decides per prompt,
-        ``'force'`` pins reuse on, ``False`` disables the trie).
+        ``'force'`` pins reuse on, ``False`` disables the trie).  That is
+        its only KV layout: ``paged`` takes the one value ``True``
+        (``False`` raises) and is never forwarded to the engine.  The
+        static baseline keeps dense per-row caches.
 
         Front end + streaming (continuous mode only; DESIGN.md §9):
         ``frontend`` moves request intake (validation + pre-processing)
@@ -489,10 +492,10 @@ class Runtime:
                 "queue_limit/deadline/fault/watchdog options need the "
                 "request lifecycle of mode='continuous'; the static "
                 "lockstep baseline has no per-request scheduling")
-        if mode == "static" and paged:
+        if not paged:
             raise ValueError(
-                "paged KV needs the slot pool of mode='continuous'; the "
-                "static lockstep baseline keeps dense per-row caches")
+                "paged=False: the continuous engine has one KV layout, the "
+                "paged pool")
         if mode == "static" and frontend is not None:
             raise ValueError(
                 "the multi-process front end feeds the continuous engine's "
@@ -515,7 +518,7 @@ class Runtime:
             raise ValueError(
                 "the span recorder instruments the continuous engine's "
                 "loop; mode='static' has none")
-        if paged and block_size < 1:
+        if block_size < 1:
             raise ValueError(f"block_size must be >= 1, got {block_size}")
         setup = None if tracer is None else tracer.open("serve/setup")
         mesh = None
@@ -603,7 +606,7 @@ class Runtime:
                     prefill_chunk=prefill_chunk, macro_step=macro_step,
                     mesh=mesh, shard_params=shard_params,
                     queue_limit=queue_limit, max_retries=max_retries,
-                    paged=paged, block_size=block_size, kv_blocks=kv_blocks,
+                    block_size=block_size, kv_blocks=kv_blocks,
                     prefix_cache=(True if prefix_cache == "auto"
                                   else prefix_cache),
                     tracer=tracer)

@@ -5,9 +5,9 @@ engine.py    — ServeEngine (fixed-batch anchor, one-call batched prefill)
                decode, group-batched prefill, CostEngine-scheduled,
                host-sync/dispatch accounted, fault-tolerant: deadlines,
                preemption, bounded queue, watchdogged retries)
-slots.py     — SlotPool: per-slot insert/reset/evict of pooled decode state
-               (donated buffers, host occupancy/position mirrors, drain()
-               failure-path reset; optional paged KV block tables + jitted
+slots.py     — SlotPool: per-slot reset/evict of pooled decode state over
+               one paged KV pool (donated buffers, host occupancy/position
+               and block-table mirrors, drain() failure-path reset, jitted
                copy-on-write page duplication)
 paging.py    — BlockPool: refcounted fixed-size KV pages + the radix prefix
                trie over full blocks (lookup/insert/LRU-evict/drain; pure

@@ -197,9 +197,8 @@ class ServeReport:
     # failure-path accounting (all zero on an unperturbed trace)
     step_retries: int = 0
     watchdog_fires: int = 0
-    # paged-KV memory accounting (all zero on a dense engine).  Every
-    # number comes from HOST MIRRORS the engine already maintains —
-    # reading them costs no device sync.
+    # paged-KV memory accounting.  Every number comes from HOST MIRRORS
+    # the engine already maintains — reading them costs no device sync.
     live_tokens: int = 0        # peak sum of per-slot cache positions
     reserved_blocks: int = 0    # peak BlockPool pages in use (slots + trie)
     prefix_hit_tokens: int = 0  # prompt tokens served from the radix cache
@@ -270,7 +269,7 @@ class ServeReport:
     @property
     def prefix_hit_rate(self) -> float:
         """Fraction of prompt tokens served from the radix prefix cache
-        instead of being prefilled (0.0 on a dense engine)."""
+        instead of being prefilled."""
         total = self.prefix_hit_tokens + self.prefilled_tokens
         return self.prefix_hit_tokens / total if total else 0.0
 
@@ -354,6 +353,13 @@ class ContinuousServeEngine:
     (``macro_step="auto"`` lets the scheduler pick K; an int pins it;
     K=1 degenerates exactly to the per-token loop).
 
+    Full-attention KV lives in one paged pool (DESIGN.md §5):
+    ``block_size``-token pages shared by every slot through per-slot block
+    tables (``kv_blocks`` overrides the can-never-run-out default), with a
+    radix prefix trie that ``prefix_cache`` arms.  Window rings and
+    recurrent states stay per slot.  Encoder-decoder models are refused:
+    their decode state has no paged layout.
+
     Passing ``mesh`` puts the engine on a device mesh.  Whether serve state
     actually SHARDS over the mesh's model axis or stays replicated is the
     eighth CostEngine decision site (``CostQuery(kind=serve_shard)``;
@@ -379,7 +385,7 @@ class ContinuousServeEngine:
                  watchdog_s: Optional[float] = None,
                  max_retries: int = 2, retry_backoff_s: float = 0.01,
                  injector: Optional[FaultInjector] = None,
-                 paged: bool = False, block_size: int = 16,
+                 block_size: int = 16,
                  kv_blocks: Optional[int] = None,
                  prefix_cache: bool = True,
                  stream: Optional[TokenStream] = None,
@@ -412,21 +418,16 @@ class ContinuousServeEngine:
             macro_step = max(int(macro_step), 1)
         self.macro_step = macro_step
         # --- paged KV pool + radix prefix cache (DESIGN.md §5) ---
-        self.paged = bool(paged)
+        if model.cfg.is_encdec:
+            raise ValueError(
+                "the continuous engine serves decoder-only models (enc-dec "
+                "decode state has no paged layout)")
         self.block_size = int(block_size)
-        self.kv_blocks: Optional[int] = None
-        if self.paged:
-            if model.cfg.is_encdec:
-                raise ValueError(
-                    "paged=True supports decoder-only models (enc-dec decode "
-                    "state has no paged layout)")
-            if self.block_size < 1:
-                raise ValueError(
-                    f"block_size must be >= 1, got {block_size}")
-            if kv_blocks is None:
-                kv_blocks = default_kv_blocks(n_slots, max_len,
-                                              self.block_size)
-            self.kv_blocks = int(kv_blocks)
+        if self.block_size < 1:
+            raise ValueError(f"block_size must be >= 1, got {block_size}")
+        if kv_blocks is None:
+            kv_blocks = default_kv_blocks(n_slots, max_len, self.block_size)
+        self.kv_blocks = int(kv_blocks)
         # prefix reuse skips prefilling matched prompt tokens, which is
         # only sound when EVERY layer's prompt state lives in the paged
         # pool: window ring buffers and recurrent states stay per-slot
@@ -441,8 +442,7 @@ class ContinuousServeEngine:
                 f"got {prefix_cache!r}")
         all_attn = all(model.cfg.block_kind(i) == "attn"
                        for i in range(model.cfg.n_layers))
-        self.prefix_cache = (prefix_cache is not False
-                             and self.paged and all_attn)
+        self.prefix_cache = prefix_cache is not False and all_attn
         self._prefix_override = ("use_prefix" if prefix_cache == "force"
                                  else None)
         # --- incremental token stream (frontend or in-process).  The
@@ -500,15 +500,13 @@ class ContinuousServeEngine:
                     params,
                     param_shardings(jax.eval_shape(lambda: params), mesh,
                                     data_axes=()))
-                pkw = ({"paging": (self.kv_blocks, self.block_size)}
-                       if self.paged else {})
                 self._state_shardings = serve_state_sharding(
                     jax.eval_shape(lambda: model.init_decode_state(
-                        n_slots, max_len, per_slot=True, **pkw)), mesh)
+                        n_slots, max_len, per_slot=True,
+                        paging=(self.kv_blocks, self.block_size))), mesh)
         self.pool = SlotPool(model, n_slots, max_len,
                              shardings=self._state_shardings,
-                             block_size=(self.block_size if self.paged
-                                         else None),
+                             block_size=self.block_size,
                              kv_blocks=self.kv_blocks)
         # pooled decode state is donated through both hot-path programs:
         # cache updates run in place, never copy-on-write.  Under sharding,
@@ -527,13 +525,13 @@ class ContinuousServeEngine:
         # an admission prefills only its group's rows, rounded up to a row
         # bucket (a power of two, capped at n_slots), when positions are the
         # only per-slot state: the paged pool's pages are shared, so nothing
-        # else of the idle slots has to ride along.  Window rings, recurrent
-        # states and dense caches are per-slot, so those engines keep
-        # prefilling every slot.
+        # else of the idle slots has to ride along.  Window rings and
+        # recurrent states are per-slot, so those engines keep prefilling
+        # every slot.
         self._row_buckets = (
             sorted({min(1 << i, n_slots)
                     for i in range(n_slots.bit_length() + 1)})
-            if self.paged and pages_only(self.pool.state) else [n_slots])
+            if pages_only(self.pool.state) else [n_slots])
         # (n_chunks, rows, chunk) of every prefill compiled so far
         self._prefill_shapes: set = set()
         # host mirrors of per-slot last token / remaining token budget
@@ -753,7 +751,7 @@ class ContinuousServeEngine:
         the continuation is token-identical to an uninterrupted run (its
         original ``admitted_s`` / ``first_token_s`` stamps are kept).
 
-        PAGED admission adds the radix prefix cache (the tenth cost site,
+        Admission consults the radix prefix cache (the tenth cost site,
         ``CostQuery(kind=serve_prefix)``): each request's prompt is looked
         up in the block trie, a ``use_prefix`` verdict pins the matched
         pages into the slot's table (partial-tail matches copy-on-write
@@ -764,7 +762,7 @@ class ContinuousServeEngine:
 
         The prefill runs on the smallest row bucket that holds the group:
         its slots padded to a power of two where only positions are
-        per-slot (paged, all full attention), every slot otherwise.
+        per-slot (every layer full attention), every slot otherwise.
 
         ``span`` is the enclosing ``serve/admit`` span, if a recorder is
         attached: its phases nest under it and it takes the group's
@@ -777,38 +775,35 @@ class ContinuousServeEngine:
         starts = np.zeros((self.pool.n_slots,), np.int32)
         prefix_decs = []  # (decision, prompt_len, applied) per request
         any_hit = False
-        if self.paged:
-            with maybe_span(self.tracer, "serve/admit/prefix"):
-                bs = self.block_size
-                for r, s, p in zip(reqs, slots, prompts):
-                    plen = int(p.shape[-1])
-                    toks = tuple(int(t) for t in p)
-                    match = (self.pool.blocks.lookup(toks)
-                             if self.prefix_cache else None)
-                    hit = match.hit_tokens(bs) if match is not None else 0
-                    cow = 1 if (match is not None
-                                and match.tail_donor is not None) else 0
-                    applied, dec_p = self.scheduler.serve_prefix(
-                        plen, hit_tokens=hit, cow_blocks=cow, block_size=bs,
-                        override=self._prefix_override)
-                    if applied > 0:
-                        self.pool.assign_prefix(s, match.block_ids)
-                        if match.tail_donor is not None:
-                            self.pool.cow_block(s, match.tail_donor)
-                            self.cow_count += 1
-                        starts[s] = applied
-                        any_hit = True
-                    elif match is not None:
-                        # full-prefill verdict: drop the lookup's pins
-                        self.pool.blocks.release(match.block_ids)
-                        if match.tail_donor is not None:
-                            self.pool.blocks.decref(match.tail_donor)
-                    self.pool.ensure_blocks(s, plen)
-                    self.prefix_hit_tokens += applied
-                    self.prefilled_tokens += plen - applied
-                    prefix_decs.append((dec_p, plen, applied))
-        else:
-            self.prefilled_tokens += sum(int(p.shape[-1]) for p in prompts)
+        with maybe_span(self.tracer, "serve/admit/prefix"):
+            bs = self.block_size
+            for r, s, p in zip(reqs, slots, prompts):
+                plen = int(p.shape[-1])
+                toks = tuple(int(t) for t in p)
+                match = (self.pool.blocks.lookup(toks)
+                         if self.prefix_cache else None)
+                hit = match.hit_tokens(bs) if match is not None else 0
+                cow = 1 if (match is not None
+                            and match.tail_donor is not None) else 0
+                applied, dec_p = self.scheduler.serve_prefix(
+                    plen, hit_tokens=hit, cow_blocks=cow, block_size=bs,
+                    override=self._prefix_override)
+                if applied > 0:
+                    self.pool.assign_prefix(s, match.block_ids)
+                    if match.tail_donor is not None:
+                        self.pool.cow_block(s, match.tail_donor)
+                        self.cow_count += 1
+                    starts[s] = applied
+                    any_hit = True
+                elif match is not None:
+                    # full-prefill verdict: drop the lookup's pins
+                    self.pool.blocks.release(match.block_ids)
+                    if match.tail_donor is not None:
+                        self.pool.blocks.decref(match.tail_donor)
+                self.pool.ensure_blocks(s, plen)
+                self.prefix_hit_tokens += applied
+                self.prefilled_tokens += plen - applied
+                prefix_decs.append((dec_p, plen, applied))
         with maybe_span(self.tracer, "serve/admit/prepare"):
             # prefix-hit rows prefill SUFFIX tokens only (never empty: the
             # lookup caps hits at prompt_len - 1 so the first generated
@@ -842,10 +837,7 @@ class ContinuousServeEngine:
                 lengths[s] = sfx.shape[-1]
             chunks = jnp.asarray(_prefill_chunks(tokens, chunk))
             lens = jnp.asarray(lengths)
-            if self.paged:
-                extra = (jnp.asarray(starts), self.pool.block_tables())
-            else:
-                extra = ()
+            extra = (jnp.asarray(starts), self.pool.block_tables())
             self.collective_ops += self._count_collectives(
                 ("prefill", chunks.shape), self._prefill,
                 self.params, self.pool.state, chunks, lens, *extra)
@@ -897,9 +889,8 @@ class ContinuousServeEngine:
                     self._publish(r, (tk,), done=False, t=t_first)
             self._peak_live_tokens = max(self._peak_live_tokens,
                                          int(self.pool.positions().sum()))
-            if self.paged:
-                self._peak_blocks = max(self._peak_blocks,
-                                        self.pool.blocks.used_blocks)
+            self._peak_blocks = max(self._peak_blocks,
+                                    self.pool.blocks.used_blocks)
         if span is not None:
             span.attrs.update(
                 rids=[r.rid for r in reqs], rows=n_rows,
@@ -935,31 +926,28 @@ class ContinuousServeEngine:
             tok_in = jnp.asarray(self._last_tok)
             mask_in = jnp.asarray(mask)
             budget_in = jnp.asarray(self._budget)
-            if self.paged:
-                # grow each live slot's table to cover this macro-step's K
-                # cache writes, then upload the tables (fixed shape — no
-                # recompile; async — no host sync; NOT donated)
-                pos = self.pool.positions()
-                for s in active:
-                    self.pool.ensure_blocks(s, int(pos[s]) + horizon)
-                mextra = (self.pool.block_tables(),)
-                self._peak_live_tokens = max(self._peak_live_tokens,
-                                             int(pos.sum()))
-                self._peak_blocks = max(self._peak_blocks,
-                                        self.pool.blocks.used_blocks)
-            else:
-                mextra = ()
+            # grow each live slot's table to cover this macro-step's K
+            # cache writes, then upload the tables (fixed shape — no
+            # recompile; async — no host sync; NOT donated)
+            pos = self.pool.positions()
+            for s in active:
+                self.pool.ensure_blocks(s, int(pos[s]) + horizon)
+            tables = self.pool.block_tables()
+            self._peak_live_tokens = max(self._peak_live_tokens,
+                                         int(pos.sum()))
+            self._peak_blocks = max(self._peak_blocks,
+                                    self.pool.blocks.used_blocks)
             self.collective_ops += self._count_collectives(
                 ("macro", horizon), macro_fn,
                 self.params, self.pool.state, tok_in, mask_in, budget_in,
-                *mextra)
+                tables)
 
         touched = list(active.values())
         try:
             em, self.pool.state, dt_step = self._step(
                 "macro", _MACRO_STEP_SPANS,
                 lambda: macro_fn(self.params, self.pool.state, tok_in,
-                                 mask_in, budget_in, *mextra),
+                                 mask_in, budget_in, tables),
                 touched, span)
         except StepFailed as e:
             self._fail_inflight(touched, now(),
@@ -1340,7 +1328,7 @@ class ContinuousServeEngine:
                         if k <= max(max_new_tokens - 1, 1)]
             if self.macro_step != "auto":
                 horizons = [self.macro_step]
-            idle_extra = (self.pool.block_tables(),) if self.paged else ()
+            idle_tables = self.pool.block_tables()
             for k in horizons:
                 with maybe_span(self.tracer,
                                 "serve/setup/warmup_macro") as sp:
@@ -1348,7 +1336,7 @@ class ContinuousServeEngine:
                         sp.attrs["k"] = k
                     emitted, self.pool.state, *_ = self._macro(k)(
                         self.params, self.pool.state, idle_tok, idle_mask,
-                        idle_budget, *idle_extra)
+                        idle_budget, idle_tables)
                     np.asarray(emitted)
         self._last_macro_key = None
 
@@ -1357,8 +1345,8 @@ class ContinuousServeEngine:
         each chunk grid a prefill has used so far; a later call of the
         same shapes finds the program compiled."""
         n_slots = self.pool.n_slots
-        lens = jnp.zeros((n_slots,), jnp.int32)
-        extra = (lens, self.pool.block_tables()) if self.paged else ()
+        zeros = jnp.zeros((n_slots,), jnp.int32)  # lengths and starts
+        tables = self.pool.block_tables()
         for n_chunks, _, chunk in sorted(self._prefill_shapes):
             for rows in self._row_buckets:
                 shape = (n_chunks, rows, chunk)
@@ -1370,5 +1358,6 @@ class ContinuousServeEngine:
                         sp.attrs["rows"] = rows
                     self._prefill.lower(
                         self.params, self.pool.state,
-                        jnp.zeros(shape, jnp.int32), lens, *extra).compile()
+                        jnp.zeros(shape, jnp.int32), zeros, zeros,
+                        tables).compile()
                 self._prefill_shapes.add(shape)

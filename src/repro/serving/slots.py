@@ -2,25 +2,28 @@
 
 The pool owns ONE device-resident decode state sized for ``n_slots``
 concurrent requests, with a per-slot cache position (``per_slot=True``
-states).  Requests borrow a slot for their lifetime:
+states).  Full-attention KV lives in a PAGED pool of ``block_size``-token
+pages shared by every slot (a ``BlockPool`` with a radix prefix trie), each
+slot reading its pages through a block table; window rings and recurrent
+states stay per slot.  Requests borrow a slot for their lifetime:
 
     acquire() -> slot          take the lowest free slot (deterministic)
-    insert(slot, src_state)    splice a freshly-prefilled single-request
-                               state into the pooled caches
-    release(slot)              zero the slot and return it to the free list
+    release(slot)              zero the slot, drop its page references and
+                               return it to the free list
 
-``insert`` and ``release`` are jitted once with the slot index / slot mask
-as traced arguments, so admitting or evicting a request never recompiles —
-the fixed-shape decode step keeps running over the whole pool while slots
-turn over underneath it.
+Admission prefills straight into the pooled slots (the engine's batched
+prefill program), so the pool itself only resets slots and copies pages.
+``release`` is jitted once with the slot mask as a traced argument, so
+evicting a request never recompiles — the fixed-shape decode step keeps
+running over the whole pool while slots turn over underneath it.
 
-The pooled state buffers are DONATED through insert/reset (and through the
-engine's prefill/macro-step programs): cache updates are in-place on
-device, never copy-on-write, and a stale reference to a pre-donation buffer
-raises instead of silently reading freed memory.  Occupancy (``active_mask``)
-and per-slot positions are HOST MIRRORS maintained by acquire/release/
-``advance`` — reading them never synchronizes with the device (the old
-``np.asarray(self.state["pos"])`` per call was one hidden host sync each).
+The pooled state buffers are DONATED through reset and copy-on-write (and
+through the engine's prefill/macro-step programs): cache updates are
+in-place on device, and a stale reference to a pre-donation buffer raises
+instead of silently reading freed memory.  Occupancy (``active_mask``),
+per-slot positions and block tables are HOST MIRRORS maintained by
+acquire/release/``advance`` — reading them never synchronizes with the
+device.
 """
 
 from __future__ import annotations
@@ -31,10 +34,11 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.models.model import Model
 from repro.models.transformer import POOL_LEAVES
-from repro.serving.paging import BlockPool
+from repro.serving.paging import BlockPool, default_kv_blocks
 
 
 def _cow_copy_fn(state, src, dst):
@@ -60,78 +64,58 @@ def _cow_copy_fn(state, src, dst):
 
 class SlotPool:
     def __init__(self, model: Model, n_slots: int, max_len: int,
-                 shardings=None, block_size: Optional[int] = None,
+                 shardings=None, *, block_size: int,
                  kv_blocks: Optional[int] = None):
-        """``shardings`` (optional) is a pytree of NamedShardings matching the
+        """Full-attention KV is stored in ``kv_blocks`` shared pages of
+        ``block_size`` tokens (default: enough that every slot can run to
+        ``max_len``, so allocation never fails), with per-slot block tables
+        kept as a host mirror and handed to the jitted programs as a fresh
+        (non-donated) device array per dispatch — fixed shape, so slot
+        turnover stays recompile-free, and the transfer is async, so no
+        host sync.
+
+        ``shardings`` (optional) is a pytree of NamedShardings matching the
         pooled state: the state is placed onto the mesh up front and every
         slot-surgery program pins its output to the same layout
         (``out_shardings``), so donation stays in-place across shards and no
-        resharding copy sneaks in between insert/reset and the decode step.
-
-        ``block_size`` switches full-attention KV storage to a PAGED pool:
-        ``kv_blocks`` shared pages (default: enough that every slot can run
-        to ``max_len``, so allocation never fails) with per-slot block
-        tables kept as a host mirror and handed to the jitted programs as a
-        fresh (non-donated) device array per dispatch — fixed shape, so
-        slot turnover stays recompile-free, and the transfer is async, so
-        no host sync."""
+        resharding copy sneaks in between reset and the decode step."""
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         self.model = model
         self.n_slots = n_slots
         self.max_len = max_len
-        self.paged = block_size is not None
-        paging = None
-        if self.paged:
-            if kv_blocks is None:
-                from repro.serving.paging import default_kv_blocks
-                kv_blocks = default_kv_blocks(n_slots, max_len, block_size)
-            self.block_size = int(block_size)
-            self.max_blocks = math.ceil(max_len / self.block_size)
-            self.blocks = BlockPool(kv_blocks, self.block_size)
-            # host mirror of the per-slot block tables; entry 0 = null block
-            self._table = np.zeros((n_slots, self.max_blocks), np.int32)
-            self._slot_nblocks = np.zeros((n_slots,), np.int32)
-            paging = (kv_blocks, self.block_size)
-        else:
-            self.blocks = None
-        if paging is not None:
-            self.state = model.init_decode_state(
-                n_slots, max_len, per_slot=True, paging=paging)
-        else:  # enc-dec models' init_decode_state has no paging parameter
-            self.state = model.init_decode_state(
-                n_slots, max_len, per_slot=True)
+        if kv_blocks is None:
+            kv_blocks = default_kv_blocks(n_slots, max_len, block_size)
+        self.block_size = int(block_size)
+        self.max_blocks = math.ceil(max_len / self.block_size)
+        self.blocks = BlockPool(kv_blocks, self.block_size)
+        # host mirror of the per-slot block tables; entry 0 = null block
+        self._table = np.zeros((n_slots, self.max_blocks), np.int32)
+        self._slot_nblocks = np.zeros((n_slots,), np.int32)
+        self.state = self._fresh_state()
         self._shardings = shardings
         self._bt_sharding = None
         # donate the pooled state: slot surgery updates buffers in place
+        kw = {}
         if shardings is not None:
             self.state = jax.device_put(self.state, shardings)
-            self._insert = jax.jit(model.insert_decode_slot,
-                                   donate_argnums=(0,),
-                                   out_shardings=shardings)
-            self._reset = jax.jit(model.reset_decode_slots,
-                                  donate_argnums=(0,),
-                                  out_shardings=shardings)
-            if self.paged:
-                from jax.sharding import NamedSharding, PartitionSpec
-                mesh = jax.tree.leaves(shardings)[0].mesh
-                self._bt_sharding = NamedSharding(
-                    mesh, PartitionSpec(None, None))
-                self._cow = jax.jit(_cow_copy_fn, donate_argnums=(0,),
-                                    out_shardings=shardings)
-        else:
-            self._insert = jax.jit(model.insert_decode_slot,
-                                   donate_argnums=(0,))
-            self._reset = jax.jit(model.reset_decode_slots,
-                                  donate_argnums=(0,))
-            if self.paged:
-                self._cow = jax.jit(_cow_copy_fn, donate_argnums=(0,))
+            kw["out_shardings"] = shardings
+            mesh = jax.tree.leaves(shardings)[0].mesh
+            self._bt_sharding = NamedSharding(mesh, PartitionSpec(None, None))
+        self._reset = jax.jit(model.reset_decode_slots, donate_argnums=(0,),
+                              **kw)
+        self._cow = jax.jit(_cow_copy_fn, donate_argnums=(0,), **kw)
         self._free: List[int] = list(range(n_slots))
         self._owner: List[Optional[object]] = [None] * n_slots
         # host mirrors: no device sync to inspect occupancy or positions
         self._active = np.zeros((n_slots,), bool)
         self._host_pos = np.zeros((n_slots,), np.int64)
-        self.dispatch_count = 0  # insert/reset programs launched
+        self.dispatch_count = 0  # reset/copy-on-write programs launched
+
+    def _fresh_state(self):
+        return self.model.init_decode_state(
+            self.n_slots, self.max_len, per_slot=True,
+            paging=(self.blocks.n_blocks, self.block_size))
 
     # ------------------------------------------------------------------
 
@@ -164,31 +148,22 @@ class SlotPool:
         self._active[slot] = True
         return slot
 
-    def insert(self, slot: int, src_state) -> None:
-        """Overwrite slot ``slot`` with a single-request per-slot state."""
-        pos = int(np.asarray(src_state["pos"]).reshape(-1)[0])
-        self.state = self._insert(self.state, src_state, jnp.int32(slot))
-        self.dispatch_count += 1
-        self._host_pos[slot] = pos
-
     def release(self, slot: int) -> None:
         """Evict the slot's request: zero its decode state (position 0,
-        empty caches) and return it to the free list.  Paged mode also
-        drops the slot's block references (shared prefix blocks survive in
-        the trie; private blocks return to the free list) and zeroes the
-        table row so any still-inflight masked write self-redirects to the
-        null block."""
+        empty caches), drop its block references (shared prefix blocks
+        survive in the trie; private blocks return to the free list), zero
+        its table row so any still-inflight masked write self-redirects to
+        the null block, and return it to the free list."""
         if self._owner[slot] is None:
             raise ValueError(f"slot {slot} is not in use")
         mask = np.zeros((self.n_slots,), bool)
         mask[slot] = True
         self.state = self._reset(self.state, jnp.asarray(mask))
         self.dispatch_count += 1
-        if self.paged:
-            n = int(self._slot_nblocks[slot])
-            self.blocks.release(self._table[slot, :n])
-            self._table[slot] = 0
-            self._slot_nblocks[slot] = 0
+        n = int(self._slot_nblocks[slot])
+        self.blocks.release(self._table[slot, :n])
+        self._table[slot] = 0
+        self._slot_nblocks[slot] = 0
         self._owner[slot] = None
         self._active[slot] = False
         self._host_pos[slot] = 0
@@ -200,34 +175,27 @@ class SlotPool:
         behind.  The happy path is the jitted reset-all program over the
         existing buffers; if an abandoned step consumed them (donation
         means a stale reference RAISES, by design), fall back to a fresh
-        ``init_decode_state`` so the engine is reusable either way.  Paged
-        mode reclaims the WHOLE BlockPool, trie included — the drain
-        invariant extends to block references."""
+        ``init_decode_state`` so the engine is reusable either way.  The
+        WHOLE BlockPool is reclaimed, trie included — the drain invariant
+        extends to block references."""
         try:
             mask = np.ones((self.n_slots,), bool)
             self.state = self._reset(self.state, jnp.asarray(mask))
             self.dispatch_count += 1
         except RuntimeError:
-            if self.paged:
-                self.state = self.model.init_decode_state(
-                    self.n_slots, self.max_len, per_slot=True,
-                    paging=(self.blocks.n_blocks, self.block_size))
-            else:
-                self.state = self.model.init_decode_state(
-                    self.n_slots, self.max_len, per_slot=True)
+            self.state = self._fresh_state()
             if self._shardings is not None:
                 self.state = jax.device_put(self.state, self._shardings)
-        if self.paged:
-            self.blocks.drain()
-            self._table[:] = 0
-            self._slot_nblocks[:] = 0
+        self.blocks.drain()
+        self._table[:] = 0
+        self._slot_nblocks[:] = 0
         self._free = list(range(self.n_slots))
         self._owner = [None] * self.n_slots
         self._active[:] = False
         self._host_pos[:] = 0
 
     # ------------------------------------------------------------------
-    # Paged block tables (host mirrors + per-dispatch device upload)
+    # Block tables (host mirrors + per-dispatch device upload)
     # ------------------------------------------------------------------
 
     def block_tables(self):

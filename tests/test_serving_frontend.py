@@ -6,9 +6,9 @@
   engine publishes only at boundaries it already synchronized on)
 * serve_ipc is a real decision site: both ops (workers, coalesce) ledger
   predicted rows, overrides pin verdicts, measurements attach
-* one multi-process equivalence run — dense AND paged — token-identical
-  to the in-process engine, with the emission transcript detokenizing
-  exactly the engine's tokens
+* one multi-process equivalence run — over two KV page sizes —
+  token-identical to the in-process engine, with the emission transcript
+  detokenizing exactly the engine's tokens
 * crash drills: a dead emission worker fails in-flight requests typed and
   leaves the engine drained + reusable; dead intake workers turn routed
   submissions into typed failures, never a crashed serve
@@ -165,11 +165,11 @@ def test_static_mode_rejects_frontend_and_bad_worker_counts():
 
 
 # ---------------------------------------------------------------------------
-# Multi-process equivalence (dense + paged) and the emission transcript
+# Multi-process equivalence (two page sizes) and the emission transcript
 # ---------------------------------------------------------------------------
 
 
-def test_frontend_serve_token_identical_dense_and_paged():
+def test_frontend_serve_token_identical_over_two_page_sizes():
     rt = Runtime()
     cfg, model, params = _build()
     common = dict(model=model, params=params, max_len=MAX_LEN, eos_id=0,
@@ -180,13 +180,14 @@ def test_frontend_serve_token_identical_dense_and_paged():
                                vocab_size=cfg.vocab_size, arrival="all",
                                seed=0)
 
+    # base and fe run the default 16-token pages, fe_small 4-token pages
     base = rt.serve(cfg, trace(), **common)
     fe = rt.serve(cfg, trace(), frontend=2, stream=True, **common)
-    fe_paged = rt.serve(cfg, trace(),
+    fe_small = rt.serve(cfg, trace(),
                         frontend=FrontendConfig(workers=1, coalesce=2),
-                        stream=True, paged=True, block_size=4, **common)
+                        stream=True, block_size=4, **common)
 
-    for res in (fe, fe_paged):
+    for res in (fe, fe_small):
         assert res.report.state_counts().get("COMPLETED") == 4
         for rid, ref in base.outputs.items():
             assert np.array_equal(res.outputs[rid], ref)
@@ -200,7 +201,7 @@ def test_frontend_serve_token_identical_dense_and_paged():
         assert res.report.streamed_tokens == sum(len(t)
                                                  for t in toks.values())
     assert fe.report.frontend_workers == 2
-    assert fe_paged.report.frontend_workers == 1
+    assert fe_small.report.frontend_workers == 1
 
     rows = [e for e in rt.ledger.entries if e.site == "serve_ipc"]
     assert {e.query.get("op") for e in rows} == {"workers", "coalesce"}
@@ -296,10 +297,10 @@ def test_within_group_prefix_sharing_matches_serialized_hit_rate():
                                vocab_size=cfg.vocab_size, arrival="all",
                                seed=0, prefix_share=1.0, prefix_len=4)
 
-    dense = rt.serve(cfg, trace(), slots=3, **common)
-    paged_kw = dict(paged=True, block_size=2, prefix_cache="force")
-    shared = rt.serve(cfg, trace(), slots=3, **paged_kw, **common)
-    serialized = rt.serve(cfg, trace(), slots=1, **paged_kw, **common)
+    base = rt.serve(cfg, trace(), slots=3, **common)
+    reuse_kw = dict(block_size=2, prefix_cache="force")
+    shared = rt.serve(cfg, trace(), slots=3, **reuse_kw, **common)
+    serialized = rt.serve(cfg, trace(), slots=1, **reuse_kw, **common)
 
     rep = shared.report
     # the admission group was SPLIT: the donor prefilled the shared prefix
@@ -309,7 +310,7 @@ def test_within_group_prefix_sharing_matches_serialized_hit_rate():
     assert rep.prefix_hit_tokens == serialized.report.prefix_hit_tokens
     assert rep.prefilled_tokens == serialized.report.prefilled_tokens
     assert rep.prefilled_tokens < 4 * PROMPT_LEN
-    for rid, ref in dense.outputs.items():
+    for rid, ref in base.outputs.items():
         assert np.array_equal(shared.outputs[rid], ref)
         assert np.array_equal(serialized.outputs[rid], ref)
 

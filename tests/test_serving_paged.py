@@ -238,7 +238,7 @@ def test_paged_matches_static_token_identical(arch):
                             for i in range(6)],
                       mode="static", model=model, params=params,
                       max_len=MAX_LEN, eos_id=0)
-    engine = _engine(model, params, paged=True, block_size=BLOCK)
+    engine = _engine(model, params, block_size=BLOCK)
     rep = _run(engine, prompts)
     assert rep.state_counts() == {"COMPLETED": 6}
     for i in range(6):
@@ -270,7 +270,7 @@ def test_paged_scan_layer_layout():
                             for i in range(3)],
                       mode="static", model=model, params=params,
                       max_len=MAX_LEN, eos_id=0)
-    engine = _engine(model, params, paged=True, block_size=BLOCK)
+    engine = _engine(model, params, block_size=BLOCK)
     rep = _run(engine, prompts)
     for i in range(3):
         np.testing.assert_array_equal(
@@ -280,13 +280,23 @@ def test_paged_scan_layer_layout():
 def test_paged_engine_rejects_bad_configs():
     cfg, model, params = _build()
     with pytest.raises(ValueError, match="block_size"):
-        _engine(model, params, paged=True, block_size=0)
+        _engine(model, params, block_size=0)
     rt = Runtime()
     trace = synthetic_trace(1, prompt_len=4, max_new=2,
                             vocab_size=cfg.vocab_size, seed=0)
-    with pytest.raises(ValueError, match="static"):
-        rt.serve(cfg, trace, mode="static", model=model, params=params,
-                 paged=True)
+    # the paged pool is the one layout: paged=False names it and raises
+    for mode in ("continuous", "static"):
+        with pytest.raises(ValueError, match="one KV layout, the paged pool"):
+            rt.serve(cfg, trace, mode=mode, model=model, params=params,
+                     paged=False)
+
+
+def test_engine_refuses_encdec():
+    """Enc-dec decode state has no paged layout, so the continuous engine
+    refuses the model at construction."""
+    cfg, model, params = _build("seamless-m4t-medium")
+    with pytest.raises(ValueError, match="enc-dec decode state has no paged"):
+        _engine(model, params)
 
 
 # ---------------------------------------------------------------------------
@@ -313,7 +323,7 @@ def test_shared_prefix_prefills_once_and_ledgers_tenth_site():
                             for i in range(6)],
                       mode="static", model=model, params=params,
                       max_len=MAX_LEN, eos_id=0)
-    engine = _engine(model, params, n_slots=1, paged=True, block_size=BLOCK,
+    engine = _engine(model, params, n_slots=1, block_size=BLOCK,
                      prefix_cache="force")
     rep = engine.run(reqs, now_fn=lambda: 0.0)
     for i in range(6):
@@ -341,7 +351,7 @@ def test_prefix_auto_verdict_is_costed_not_forced():
     set_default_runtime(rt)
     prompts = _prompts(cfg, 3, seed=7)
     prompts[:, :6] = prompts[0, :6]
-    engine = _engine(model, params, n_slots=1, paged=True, block_size=BLOCK)
+    engine = _engine(model, params, n_slots=1, block_size=BLOCK)
     rep = _run(engine, prompts)
     assert rep.state_counts() == {"COMPLETED": 3}
     rows = [e for e in rt.ledger.entries if e.site == "serve_prefix"]
@@ -373,7 +383,7 @@ def test_preemption_releases_blocks_and_resume_repins_prefix():
     # pages while high decodes to max_len; size the pool so the published
     # prefix survives for the resume to re-pin
     engine = _engine(model, params, n_slots=1, macro_step=1, eos_id=-1,
-                     paged=True, block_size=BLOCK, kv_blocks=16,
+                     block_size=BLOCK, kv_blocks=16,
                      prefix_cache="force")
     low = Request("low", prompts[0], MAX_NEW, priority=0)
     high = Request("high", prompts[1], MAX_NEW, arrival_s=0.01, priority=5)
@@ -397,7 +407,7 @@ def test_deadline_eviction_releases_paged_slot():
     cfg, model, params = _build()
     prompts = _prompts(cfg, 1)
     engine = _engine(model, params, n_slots=1, macro_step=1, eos_id=-1,
-                     paged=True, block_size=BLOCK)
+                     block_size=BLOCK)
     req = Request("r0", prompts[0], MAX_NEW, deadline_s=0.05)
     rep = engine.run([req], now_fn=_tick_clock(dt=5e-3))
     assert req.state == RequestState.TIMED_OUT
@@ -412,10 +422,10 @@ def test_fatal_abort_drains_whole_block_pool():
     the next trace token-identically."""
     cfg, model, params = _build()
     prompts = _prompts(cfg, 2, seed=11)
-    clean_engine = _engine(model, params, paged=True, block_size=BLOCK)
+    clean_engine = _engine(model, params, block_size=BLOCK)
     clean = _tokens(_run(clean_engine, prompts))
     engine = _engine(
-        model, params, macro_step=1, paged=True, block_size=BLOCK,
+        model, params, macro_step=1, block_size=BLOCK,
         injector=FaultInjector((FaultSpec("raise", site="macro",
                                           after=0, fatal=True),)))
     reqs = [Request(f"r{i}", prompts[i], MAX_NEW) for i in range(2)]
@@ -482,7 +492,7 @@ def test_narrow_prefill_matches_every_slot_prefill(case):
     runs = {}
     for name in ("narrow", "wide"):
         engine = _engine(model, params, n_slots=n_slots, eos_id=-1,
-                         paged=True, block_size=BLOCK, kv_blocks=64, **kw)
+                         block_size=BLOCK, kv_blocks=64, **kw)
         if name == "wide":  # the program over every slot, steered here
             engine._row_buckets = [n_slots]
         reqs = _trace(cfg, shape)
@@ -520,7 +530,7 @@ def test_latent_pool_prefix_hit_and_narrow_prefill():
     runs = {}
     for name, kw in (("reuse", {"prefix_cache": "force"}),
                      ("wide", {"prefix_cache": False})):
-        engine = _engine(model, params, n_slots=8, eos_id=-1, paged=True,
+        engine = _engine(model, params, n_slots=8, eos_id=-1,
                          block_size=BLOCK, kv_blocks=64, max_len=20, **kw)
         if name == "wide":
             engine._row_buckets = [8]
@@ -542,7 +552,7 @@ def test_narrow_prefill_leaves_other_slots_alone():
     dropped) and gives each row the first token the every-slot program
     gives that slot."""
     cfg, model, params = _build()
-    engine = _engine(model, params, n_slots=8, paged=True, block_size=BLOCK)
+    engine = _engine(model, params, n_slots=8, block_size=BLOCK)
     bt = np.zeros((8, -(-MAX_LEN // BLOCK)), np.int32)
     slots = [1, 4, 6]
     for j, s in enumerate(slots):
@@ -578,7 +588,7 @@ def test_warmup_compiles_every_row_bucket():
     take every row bucket (4, 2 and 1 of 4 slots) compiles nothing."""
     cfg, model, params = _build()
     engine = _engine(model, params, n_slots=4, eos_id=-1, macro_step=2,
-                     paged=True, block_size=BLOCK)
+                     block_size=BLOCK)
     shape = ([(PROMPT_LEN, 0.0, 0)] * 4 + [(5, 1.0, 0)] * 2
              + [(6, 2.0, 0)])
     was = jax.config.jax_enable_compilation_cache
@@ -604,18 +614,15 @@ def test_warmup_compiles_every_row_bucket():
     assert compiles == []
 
 
-@pytest.mark.parametrize("arch,paged", [
-    ("tinyllama-1.1b", False),    # dense per-slot caches
-    ("rwkv6-3b", True),           # recurrent state per slot
-    ("recurrentgemma-2b", True),  # rglru state and local window rings
+@pytest.mark.parametrize("arch", [
+    "rwkv6-3b",           # recurrent state per slot
+    "recurrentgemma-2b",  # rglru state and local window rings
 ])
-def test_engines_outside_the_rule_prefill_every_slot(arch, paged):
+def test_engines_outside_the_rule_prefill_every_slot(arch):
     """Engines with per-slot state besides the positions keep the prefill
-    over every slot.  (Enc-dec engines cannot be paged, so they fall under
-    the dense case.)"""
+    over every slot."""
     cfg, model, params = _build(arch)
-    kw = {"paged": True, "block_size": BLOCK} if paged else {}
-    engine = _engine(model, params, n_slots=4, eos_id=-1, **kw)
+    engine = _engine(model, params, n_slots=4, eos_id=-1, block_size=BLOCK)
     rep, adm = _admissions(
         engine, _trace(cfg, [(7, 0.0, 0), (5, 1.0, 0), (6, 1.0, 0)]),
         lambda: 0.0)
@@ -651,7 +658,7 @@ def test_sharded_paged_token_identity():
         static = rt.serve(cfg, trace(), mode="static", **common)
         paged = rt.serve(cfg, trace(), mode="continuous", slots=2,
                          mesh_shape={"data": 1, "model": 8},
-                         shard_params="shard", paged=True, block_size=4,
+                         shard_params="shard", block_size=4,
                          prefix_cache="force", **common)
         s = np.stack([static.outputs[f"r{i}"] for i in range(6)])
         c = np.stack([paged.report.output(f"r{i}", 8) for i in range(6)])

@@ -31,10 +31,10 @@ ADMIT_PHASES = {"serve/admit/prepare", "serve/admit/dispatch",
 MACRO_PHASES = {"serve/macro/plan", "serve/macro/dispatch",
                 "serve/macro/sync", "serve/macro/emit"}
 VARIANTS = {
-    "dense": {},
-    "paged": {"paged": True, "block_size": 4},
+    "pages16": {},  # the engine's default page size, 16 tokens
+    "paged": {"block_size": 4},
     # the watchdog runs each dispatch and sync on a worker thread
-    "guarded": {"paged": True, "block_size": 4, "watchdog_s": 60.0},
+    "guarded": {"block_size": 4, "watchdog_s": 60.0},
 }
 
 
@@ -110,10 +110,8 @@ def test_span_tree_is_well_formed(served):
         "serve/intake", "serve/admit", "serve/macro", "serve/wait_arrival"}
 
 
-def test_span_attributes_add_up_to_the_counters(served, request):
+def test_span_attributes_add_up_to_the_counters(served):
     _, rep, spans = served
-    # paged engines prefill each group on its own rows (1 or 2 of 2 slots)
-    narrow = VARIANTS[request.node.callspec.params["served"]].get("paged")
     admits = [s.attrs for s in spans if s.name == "serve/admit"]
     macros = [s.attrs for s in spans if s.name == "serve/macro"]
     assert sum(a["useful_tokens"] for a in admits) == rep.prefilled_tokens
@@ -126,11 +124,10 @@ def test_span_attributes_add_up_to_the_counters(served, request):
         rep.generated_tokens
     assert all(a["slots"] == SLOTS and a["padded_len"] % a["chunk"] == 0
                for a in admits)
-    assert [a["rows"] for a in admits] == [
-        len(a["rids"]) if narrow else SLOTS for a in admits]
+    # each group prefills on its own rows (1 or 2 of 2 slots)
+    assert [a["rows"] for a in admits] == [len(a["rids"]) for a in admits]
     assert rep.narrow_admissions == sum(a["rows"] < SLOTS for a in admits)
-    assert rep.narrow_admissions > 0 if narrow else \
-        rep.narrow_admissions == 0
+    assert rep.narrow_admissions > 0
     assert all(m["live"] == len(m["rids"]) <= m["rows"] for m in macros)
     assert sum(s.attrs["arrived"] for s in spans
                if s.name == "serve/intake") == len(TRACE)
@@ -141,7 +138,7 @@ def test_no_annotation_without_recorder(tiny, monkeypatch):
         raise AssertionError("TraceAnnotation made with no recorder")
 
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
-    rep = _serve(tiny, paged=True, block_size=4)
+    rep = _serve(tiny, block_size=4)
     assert rep.all_terminal and rep.generated_tokens > 0
     with SpanRecorder() as rec, pytest.raises(AssertionError):
         _serve(tiny, rec)  # the patch is live: a recorder would make one
@@ -153,7 +150,7 @@ def test_runtime_serve_records_set_up(tiny):
         res = Runtime().serve(cfg, _requests(cfg), mode="continuous",
                               model=model, params=params, slots=SLOTS,
                               max_len=MAX_LEN, eos_id=-1, pad_id=0,
-                              paged=True, block_size=4, tracer=rec)
+                              block_size=4, tracer=rec)
     assert res.report.all_terminal
     by_name = {}
     for s in rec.spans:
